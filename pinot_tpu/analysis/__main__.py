@@ -43,8 +43,7 @@ FIX_HINTS = {
                    "genuine 64-bit lanes)",
     "concurrency": "guard both write paths with one lock, or make one "
                    "path the sole writer",
-    "api-compat": "route version-sensitive symbols through "
-                  "pinot_tpu.compat",
+    "api-compat": "use the spelling the installed jax resolves",
     "lock-order": "impose one global acquisition order or collapse "
                   "the locks",
     "lock-blocking": "move the blocking call outside the lock "
@@ -140,6 +139,11 @@ def main(argv=None) -> int:
     ap.add_argument("--write-protocol-model", action="store_true",
                     help="regenerate protocol-model.json from the live "
                          "protocol sources and exit")
+    ap.add_argument("--compile-kernels", action="store_true",
+                    help="compile every registered kernel case for the "
+                         "backend this process gets, execute each once, "
+                         "print one JSON line and exit (takes the "
+                         "device; non-zero if any case fails)")
     ap.add_argument("--rule", action="append", dest="rules", default=None,
                     help="run only this rule id (repeatable)")
     ap.add_argument("--list-rules", action="store_true")
@@ -158,6 +162,14 @@ def main(argv=None) -> int:
         print(f"tpulint: wrote {contracts.WIRE_SCHEMA_FILE} — commit it "
               "and call out the wire-compatibility change in review")
         return 0
+
+    if args.compile_kernels:
+        import json
+
+        from pinot_tpu.analysis import contracts
+        out = contracts.compile_kernel_surface()
+        print(json.dumps(out), flush=True)
+        return 1 if out["failed"] else 0
 
     if args.write_protocol_model:
         from pinot_tpu.analysis import protocol

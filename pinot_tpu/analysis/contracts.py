@@ -313,6 +313,64 @@ def _check_extra_kernels(buckets, x64: bool) -> List[str]:
     return violations
 
 
+def compile_kernel_surface() -> dict:
+    """Lower, COMPILE for the installed backend and execute once every
+    registered kernel case (`contract_cases` + `extra_contract_cases`)
+    at each shape bucket — the step past the jaxpr gates above, which
+    only trace: it answers "does the backend this process runs on
+    accept every kernel family the repo ships".
+
+    Takes the device (call it from a process that may own it). Returns
+    {"device": device_report(), "cases": [{"name", "padded",
+    "compileS", "error"?}], "failed": n}; a failing case carries the
+    compiler's own words.
+    """
+    import time
+
+    import jax
+    import numpy as np
+
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.utils.device import (configure_compile_cache,
+                                        device_report)
+
+    configure_compile_cache()
+    buckets = kernels.CONTRACT_SHAPE_BUCKETS
+    todo = []
+    for (name, filt, aggs, group, select, cols_spec,
+         params_spec) in kernels.contract_cases():
+        for padded in buckets:
+            kernel = kernels.build_segment_kernel(padded, filt, aggs,
+                                                  group, select)
+            cols, params = _materialize(cols_spec, params_spec, padded)
+            todo.append((name, padded, kernel,
+                         (cols, params, np.int32(padded - 3))))
+    for name, builder, static_args, arg_specs in \
+            kernels.extra_contract_cases():
+        for padded in buckets:
+            args = tuple(padded if a == "P" else a for a in static_args)
+            todo.append((name, padded, builder(*args),
+                         _materialize_tree(arg_specs, padded)))
+
+    # one jit per DISTINCT kernel, compiled once each — a function, not
+    # a loop body, so the retrace rule's jit-in-a-loop shape stays a
+    # finding everywhere it is one
+    def compile_and_run(name, padded, kernel, operands) -> dict:
+        entry = {"name": name, "padded": padded}
+        t0 = time.perf_counter()
+        try:
+            compiled = jax.jit(kernel).lower(*operands).compile()
+            entry["compileS"] = round(time.perf_counter() - t0, 3)
+            jax.block_until_ready(compiled(*operands))
+        except Exception as e:  # noqa: BLE001 — the compiler's verdict
+            entry["error"] = f"{type(e).__name__}: {e}"   # IS the result
+        return entry
+
+    cases = [compile_and_run(*case) for case in todo]
+    return {"device": device_report(), "cases": cases,
+            "failed": sum(1 for c in cases if "error" in c)}
+
+
 # ---------------------------------------------------------------------------
 # Wire schema
 # ---------------------------------------------------------------------------

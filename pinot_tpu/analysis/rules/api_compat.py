@@ -1,16 +1,14 @@
 """api-compat: JAX symbols absent from the installed version, or on a
 deprecation denylist.
 
-Exactly the failure class that took out the seed: `jax.shard_map` is
-the JAX ≥ 0.6 spelling; on the pinned 0.4.x it lives at
-`jax.experimental.shard_map.shard_map`, and every call site raised
-AttributeError at query time — 33 tier-1 failures from one symbol.
-The rule resolves every statically-visible `jax.*` dotted chain (and
-every `import`/`from ... import` of a jax module) against the
-INSTALLED jax via importlib/getattr, so version skew is caught at lint
-time, not discovered one bench regression at a time. Version-portable
-call sites go through `pinot_tpu.compat`, which probes with getattr —
-invisible to (and the sanctioned escape from) this rule.
+Exactly the failure class that took out the seed: call sites written
+for one JAX raised AttributeError at query time on another — 33 tier-1
+failures from one symbol. The rule resolves every statically-visible
+`jax.*` dotted chain (and every `import`/`from ... import` of a jax
+module) against the INSTALLED jax via importlib/getattr, so version
+skew is caught at lint time, not discovered one bench regression at a
+time. The code is written for the one installed JAX: the fix for a
+finding is the spelling that JAX resolves, not a version branch.
 """
 from __future__ import annotations
 
@@ -29,8 +27,7 @@ DENYLIST: Dict[str, str] = {
     "jax.tree_util.tree_multimap": "removed — use jax.tree_util.tree_map",
     "jax.experimental.host_callback":
         "removed — use jax.pure_callback / jax.debug.callback",
-    "jax.experimental.maps": "xmap is removed — use jax.shard_map "
-                             "(via pinot_tpu.compat)",
+    "jax.experimental.maps": "xmap is removed — use jax.shard_map",
     "jax.experimental.pjit.pjit": "legacy alias — jax.jit takes shardings",
     "jax.abstract_arrays": "removed module",
     "jax.linear_util": "removed module",

@@ -35,7 +35,4 @@ def debug_transfer_guard():
             f"{ENV_VAR}={mode!r}: expected one of "
             f"{_OFF + _ON + _MODES}")
     import jax
-    guard = getattr(jax, "transfer_guard_device_to_host", None)
-    if guard is None:   # very old jax: fall back to the global guard
-        guard = jax.transfer_guard
-    return guard(mode)
+    return jax.transfer_guard_device_to_host(mode)

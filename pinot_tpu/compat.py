@@ -1,42 +1,13 @@
-"""Version-portability shims over the JAX surface pinot_tpu depends on.
+"""The one sanctioned 64-bit constant constructor.
 
-The engine is written against the modern JAX API; installed versions
-skew in both directions (the seed shipped `jax.shard_map` call sites
-onto jax 0.4.37, where the symbol lives at
-`jax.experimental.shard_map.shard_map` — 33 tier-1 failures from one
-name). Every version-sensitive symbol is resolved HERE, once, by
-probing the installed jax with getattr — which also keeps call sites
-clean under tpulint's api-compat rule: `pinot_tpu.compat.shard_map`
-always resolves, whatever jax is underneath.
+Written against the installed JAX (0.9): version shims do not live
+here — a symbol the installed JAX lacks is tpulint's api-compat finding
+at the call site, not a branch in this module.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
-
-_shard_map_impl = getattr(jax, "shard_map", None)
-if _shard_map_impl is None:
-    # jax < 0.6: experimental spelling, `check_rep` instead of `check_vma`
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` resolved by availability.
-
-    Accepts the modern keyword surface and translates `check_vma` to
-    the pre-0.6 `check_rep` when running on the experimental impl.
-    """
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = check_vma
-    else:
-        kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, **kwargs)
 
 
 def wide_i64(value):

@@ -26,6 +26,8 @@ class QueryEngine:
         queries run the sharded executor (segment DP with ICI combine,
         parallel/sharded.py) and fall back to sequential per-segment
         execution when segments aren't homogeneous enough."""
+        from pinot_tpu.utils.device import configure_compile_cache
+        configure_compile_cache()
         self.segments = list(segments)
         self.executor = ServerQueryExecutor(use_device=use_device)
         self.sharded = None

@@ -5,7 +5,10 @@ recentering — are batched matmuls, so both kernels are MXU work by
 construction (unlike the query-time scoring tree, which trades the MXU
 for bit-exact cross-backend accumulation; training has no such
 contract — the ARTIFACT it produces is what gets pinned, and the
-seeded host loop makes that artifact reproducible per backend).
+seeded host loop makes that artifact reproducible per backend). Both
+matmuls carry an explicit f32 precision: at the TPU's default the
+operands are rounded to bf16, which on a v5e put 25 of 8192 rows in a
+centroid that was not their nearest (PR 21 chip run).
 
 Shapes are static (pow2-padded rows/centroids/dim) with live counts as
 runtime scalars, so Lloyd's whole fixed-iteration loop reuses one
@@ -32,7 +35,8 @@ def build_ivf_assign_kernel(n_pad: int, c_pad: int, dim_pad: int):
     def kernel(data, centroids, n_rows, n_centroids):
         row_n2 = kernels.vec_tree_sum(data * data)            # [n_pad]
         cen_n2 = kernels.vec_tree_sum(centroids * centroids)  # [c_pad]
-        cross = data @ centroids.T                            # MXU [n, c]
+        cross = jnp.matmul(data, centroids.T,                 # MXU [n, c]
+                           precision=jax.lax.Precision.HIGHEST)
         d2 = row_n2[:, None] - 2.0 * cross + cen_n2[None, :]
         cval = jnp.arange(c_pad, dtype=jnp.int32) < n_centroids
         d2 = jnp.where(cval[None, :], d2, jnp.float32(jnp.inf))
@@ -59,7 +63,8 @@ def build_ivf_train_kernel(n_pad: int, c_pad: int, dim_pad: int):
         rval = jnp.arange(n_pad, dtype=jnp.int32) < n_rows
         oh = ((assign[:, None] == jnp.arange(c_pad, dtype=jnp.int32)) &
               rval[:, None]).astype(jnp.float32)              # [n, c]
-        sums = oh.T @ data                                    # MXU [c, d]
+        sums = jnp.matmul(oh.T, data,                         # MXU [c, d]
+                          precision=jax.lax.Precision.HIGHEST)
         counts = kernels.vec_tree_sum(oh.T)                   # f32 [c_pad]
         new_c = jnp.where(counts[:, None] > 0,
                           sums / jnp.maximum(counts[:, None], 1.0),

@@ -304,6 +304,18 @@ SLOT_CHUNK = 1 << 17   # slot-table chunk: 127 * 2^17 < 2^24 (f32-exact)
                    # wide accumulation happens on the MXU instead
 RADIX_LO = 128     # lane width: lo one-hot fills exactly one vreg lane dim
 
+#: precision for contractions whose VALUE side is f32. At its default
+#: precision the TPU rounds f32 operands to bf16 before the MXU, which
+#: voids every "0/1 one-hot times a value is exact" argument below; the
+#: CPU backend, where the parity tests run, never rounds. Measured on a
+#: v5e (PR 21): a one-contributor _block_compact move of two float lanes
+#: came back 3.7e-3 off, float group sums 6e-4..2e-3 off; with this
+#: precision both are f32-exact (tests/test_on_device.py pins it). A
+#: single float lane hid it: XLA lowers that degenerate contraction to a
+#: reduce, not an MXU pass. bf16 x bf16 sites need no argument — their
+#: operands are already exact in bf16.
+_EXACT_F32 = jax.lax.Precision.HIGHEST
+
 
 def _cmp_onehot(idx, width: int, dtype):
     """one_hot(idx, width) via a NARROW-dtype compare.
@@ -348,7 +360,8 @@ def _radix_group_sum(oh_hi, oh_lo, v, g: int, acc):
     carries its own bound. Counts are the v == mask special case
     (sum m * hi * lo == (hi weighted by m)^T lo)."""
     return jnp.matmul(oh_hi.T, oh_lo * v[:, None],
-                      preferred_element_type=acc).reshape(-1)[:g]
+                      preferred_element_type=acc,
+                      precision=_EXACT_F32).reshape(-1)[:g]
 
 
 def _mxu_histogram(ids, mask, card_pad: int):
@@ -519,7 +532,8 @@ def _dense_group_float_sums(vals, key, mask, g_pad: int):
         else:
             onehot = _cmp_onehot(k, g_pad, mm_dtype)
             s = jnp.matmul(c[None, :], onehot,
-                           preferred_element_type=mm_dtype)[0]
+                           preferred_element_type=mm_dtype,
+                           precision=_EXACT_F32)[0]
         return carry + s, None
 
     out, _ = jax.lax.scan(body, jnp.zeros(g_pad, mm_dtype), (key_b, cb))
@@ -825,7 +839,8 @@ def _block_compact(mask, int_lanes, f32_lanes, r: int):
         lf = jnp.stack([v.reshape(t, CBLOCK).astype(facc)
                         for v in f32_lanes], axis=-1)
         floats = jnp.einsum("tbr,tbl->trl", oh.astype(facc), lf,
-                            preferred_element_type=facc
+                            preferred_element_type=facc,
+                            precision=_EXACT_F32
                             ).reshape(t * r, len(f32_lanes))
     valid = (jnp.arange(r, dtype=jnp.int32)[None, :] <
              jnp.minimum(cnt, r)[:, None]).reshape(t * r)
@@ -936,7 +951,7 @@ def _slot_sum_tables(gslot, t_slots: int, int_vals, f32_vals, count_mask):
         if fv is not None:
             cf = cf + jnp.einsum(
                 "kg,kl->lg", oh2.astype(acc), xs[j].astype(acc),
-                preferred_element_type=acc)
+                preferred_element_type=acc, precision=_EXACT_F32)
             j += 1
         if cm is not None:
             cc = cc + jnp.einsum(

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pinot_tpu import compat
 from pinot_tpu.analysis.runtime import debug_transfer_guard
 from pinot_tpu.common.request import BrokerRequest
 from pinot_tpu.obs import residency
@@ -145,11 +144,10 @@ def get_sharded_kernel(mesh: Mesh, padded: int, filter_spec, agg_specs,
 
     # check_vma=False: outputs are replicated by construction (psum/pmin/
     # pmax/all_gather), but the static varying-axis check can't prove it
-    # for the all_gather'd selection lanes. compat.shard_map resolves the
-    # installed spelling (jax.shard_map vs jax.experimental.shard_map).
-    fn = compat.shard_map(local, mesh=mesh,
-                          in_specs=(col_specs, P(), P(SEG_AXIS)),
-                          out_specs=P(), check_vma=False)
+    # for the all_gather'd selection lanes.
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(col_specs, P(), P(SEG_AXIS)),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
 

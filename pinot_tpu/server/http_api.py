@@ -129,11 +129,17 @@ class ServerApiServer(ApiServer):
     async def _debug_health(self, request: HttpRequest) -> HttpResponse:
         """One-scrape leak-gate rollup (obs/health.py): RSS, residency
         ledger, exchange held-bytes, and the leak-sensitive gauges —
-        the curated subset the soak's flatness detectors poll."""
+        the curated subset the soak's flatness detectors poll — plus
+        the `device` block (utils/device.py)."""
         from pinot_tpu.obs.health import health_rollup
-        return HttpResponse.of_json(health_rollup(
+        from pinot_tpu.utils.device import device_report
+        out = health_rollup(
             "server", self.server.metrics,
-            extra={"instanceId": self.server.instance_id}))
+            extra={"instanceId": self.server.instance_id})
+        # what the kernels run on, and the backend's own bytes-in-use
+        # beside the ledger's total above (do the two agree on a chip?)
+        out["device"] = device_report()
+        return HttpResponse.of_json(out)
 
     async def _residency(self, request: HttpRequest) -> HttpResponse:
         """The process-global residency ledger: every accounted device

@@ -66,6 +66,10 @@ class ServerInstance:
                  device_bytes_budget: Optional[int] = None,
                  batch_window_ms: Optional[float] = None):
         self.instance_id = instance_id
+        # before the first kernel is built: every server of this
+        # checkout compiles into (and starts warm from) one cache
+        from pinot_tpu.utils.device import configure_compile_cache
+        configure_compile_cache()
         self.metrics = MetricsRegistry("server")
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)

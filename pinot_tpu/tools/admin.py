@@ -418,7 +418,11 @@ def cmd_start_server(args) -> int:
                             args.deep_store, work_dir=args.dir,
                             port=args.port, scheduler=args.scheduler,
                             controller_http=args.controller_http)
-    boot = {"instanceId": args.instance_id, "queryPort": srv.port}
+    # the boot line says what this server runs on (and takes the device
+    # now: a server that cannot reach its chip fails here, not mid-query)
+    from pinot_tpu.utils.device import device_report
+    boot = {"instanceId": args.instance_id, "queryPort": srv.port,
+            "device": device_report()}
     api = None
     if args.admin_port is not None:
         from pinot_tpu.server.http_api import ServerApiServer

@@ -277,6 +277,7 @@ class MultiprocCluster:
         self._procs: Dict[str, subprocess.Popen] = {}
         self.controllers: Dict[str, dict] = {}    # id -> {httpPort}
         self.server_admin_ports: Dict[str, int] = {}
+        self.server_boots: Dict[str, dict] = {}   # id -> boot line
         self.broker_ports: List[int] = []
         self.minion_ids: List[str] = []
         self._store_client = None
@@ -493,6 +494,7 @@ class MultiprocCluster:
             self.active_controller_http().split("//", 1)[1],
             "--admin-port", "0")
         self.server_admin_ports[target] = boot["adminPort"]
+        self.server_boots[target] = boot
         return target
 
     def kill_server(self, target: str, **params) -> str:
@@ -674,19 +676,37 @@ class MultiprocCluster:
         except Exception:  # noqa: BLE001
             return False
 
-    def stop(self) -> None:
+    def exit_codes(self) -> Dict[str, Optional[int]]:
+        """{process name: exit code, None while it runs}."""
+        return {name: p.poll() for name, p in self._procs.items()}
+
+    def stop(self, wait_s: float = 15.0) -> Dict[str, int]:
+        """Stop every process; returns {process name: exit code}.
+
+        Servers and minions go first and are waited for: SIGTERM is
+        their drain path (exit 0), it needs the controller still up to
+        see the view clear, and a process that holds a chip must be
+        gone before anything else may claim it. Brokers, controllers
+        and the store then take SIGINT, their clean-stop path (exit 0).
+        A process still alive after ``wait_s`` is killed."""
         if self._store_client is not None:
             try:
                 self._store_client.close()
             except Exception:  # noqa: BLE001
                 pass
-        procs = list(self._procs.values())
-        for p in procs:
-            if p.poll() is None:
-                p.terminate()
-        for p in procs:
-            try:
-                p.wait(timeout=15)
-            except subprocess.TimeoutExpired:
-                p.kill()
+        first = [n for n in self._procs
+                 if n.startswith(("server:", "minion:"))]
+        rest = [n for n in self._procs if n not in first]
+        for names, sig in ((first, signal.SIGTERM), (rest, signal.SIGINT)):
+            for n in names:
+                if self._procs[n].poll() is None:
+                    self._procs[n].send_signal(sig)
+            for n in names:
+                try:
+                    self._procs[n].wait(timeout=wait_s)
+                except subprocess.TimeoutExpired:
+                    self._procs[n].kill()
+                    self._procs[n].wait()
+        codes = {n: p.returncode for n, p in self._procs.items()}
         self._procs.clear()
+        return codes

@@ -105,6 +105,16 @@ echo "== batch smoke (cross-query dispatch coalescing) =="
 # shared kernel spec in seconds
 env JAX_PLATFORMS=cpu python scripts/batch_smoke.py
 
+echo "== chip smoke, CPU rehearsal (the served path as OS processes) =="
+# the command the chip tool runs (python chip_smoke.py: controller +
+# broker + ONE server process, SSB without cubes through REST upload and
+# HTTP /query, every answer against the numpy reference, a coalesced
+# burst, the kernel compile sweep) rehearsed at a tiny size on the CPU
+# backend so it cannot rot between chip runs. Its output is marked
+# REHEARSAL: what it proves about a chip is nothing — there is no chip
+# step in this gate.
+python chip_smoke.py --rehearse-cpu
+
 echo "== production soak (short mode: one cluster, every subsystem) =="
 # 120s scaled-down soak of the FULL production shape: multi-process HA
 # cluster (standalone store + lead/standby controller + servers +

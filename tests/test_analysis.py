@@ -344,20 +344,22 @@ def f(tree):
     return jax.tree_map(lambda x: x + 1, tree)
 """
 
+# a spelling the installed jax does not resolve (the class of skew that
+# broke the seed's 33 tier-1 tests)
 API_ABSENT_POS = """
 import jax
 
 def f(fn, mesh, specs):
-    return jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=specs)
+    return jax.sharded_map(fn, mesh=mesh, in_specs=specs, out_specs=specs)
 """
 
 API_NEG = """
 import jax
 import jax.numpy as jnp
-from pinot_tpu.compat import shard_map
 
-def f(x):
-    return jax.jit(jnp.sum)(x)
+def f(fn, mesh, specs, x):
+    g = jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=specs)
+    return g(jax.jit(jnp.sum)(x))
 """
 
 
@@ -368,25 +370,13 @@ def test_api_compat_denylist():
 
 
 def test_api_compat_absent_symbol():
-    import jax
     found = findings_of(API_ABSENT_POS, PLAIN_PATH)
-    if hasattr(jax, "shard_map"):
-        # modern jax: the symbol exists; the seed-breaking skew can't
-        # be reproduced, only the resolution machinery is exercised
-        assert found == []
-    else:
-        # the exact regression that broke the seed's 33 tier-1 tests
-        assert [f.rule for f in found] == ["api-compat"]
-        assert "jax.shard_map" in found[0].message
+    assert [f.rule for f in found] == ["api-compat"]
+    assert "jax.sharded_map" in found[0].message
 
 
 def test_api_compat_negative():
     assert rules_of(API_NEG, PLAIN_PATH) == []
-
-
-def test_compat_shim_resolves_shard_map():
-    from pinot_tpu import compat
-    assert callable(compat.shard_map)
 
 
 # ---------------------------------------------------------------------------
@@ -467,16 +457,13 @@ def test_cli_end_to_end_exits_zero_against_baseline():
 
 @pytest.mark.slow
 def test_cli_catches_injected_regression(tmp_path):
-    """api-compat (not just pytest) must catch a reverted compat shim:
-    a fresh `jax.shard_map` call site is a NEW finding vs the baseline."""
+    """api-compat (not just pytest) must catch a call site the
+    installed jax does not resolve: a NEW finding vs the baseline."""
     bad = tmp_path / "pinot_tpu_query_bad.py"
     bad.write_text("import jax\n\n"
                    "def f(fn, mesh, s):\n"
-                   "    return jax.shard_map(fn, mesh=mesh, in_specs=s, "
+                   "    return jax.sharded_map(fn, mesh=mesh, in_specs=s, "
                    "out_specs=s)\n")
-    import jax
-    if hasattr(jax, "shard_map"):
-        pytest.skip("installed jax has jax.shard_map; skew not reproducible")
     proc = subprocess.run(
         [sys.executable, "-m", "pinot_tpu.analysis", str(bad),
          "--baseline", os.path.join(REPO_ROOT, "tpulint.baseline.json")],

@@ -6,9 +6,11 @@ int8 MXU) are exercised here instead. Run with:
 
     PINOT_TPU_DEVICE_TESTS=1 python -m pytest tests/test_on_device.py
 
-Each test launches a SUBPROCESS with the cpu-forcing env stripped so
-jax initializes on the real accelerator. Skipped by default (the bench
-gate provides per-round device evidence; the chip is exclusive).
+Each test launches ONE subprocess at a time with the test-mode env
+stripped (cpu forcing, virtual devices, x64), so jax initializes on the
+real accelerator in the child, in the mode a server deploys in (x32),
+while the pytest parent stays pinned to the CPU and never holds the
+chip. Skipped by default (the sandbox has no accelerator).
 """
 import json
 import os
@@ -64,11 +66,11 @@ print("DEVICE_RESULT " + json.dumps(out))
 
 
 def _run_driver(driver_src: str) -> dict:
-    """Run a device driver in a subprocess with the cpu-forcing env
+    """Run a device driver in a subprocess with the test-mode env
     stripped; return the parsed DEVICE_RESULT payload."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "JAX_ENABLE_X64")}
     proc = subprocess.run([sys.executable, "-c",
                            driver_src.format(repo=repo)],
                           capture_output=True, text=True, timeout=600,
@@ -76,7 +78,10 @@ def _run_driver(driver_src: str) -> dict:
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("DEVICE_RESULT ")][-1]
-    return json.loads(line[len("DEVICE_RESULT "):])
+    out = json.loads(line[len("DEVICE_RESULT "):])
+    assert out["platform"] == "tpu", \
+        f"device test ran on {out['platform']!r}, not a TPU"
+    return out
 
 
 def test_device_numerics_match_oracle():
@@ -242,3 +247,96 @@ def test_device_consuming_segment_within_2x_of_offline():
         # policy; allow modest slack over the 2x target for host-merge
         # overhead at this scale
         assert q["ratio"] <= 2.5, out["queries"]
+
+
+_DRIVER_F32 = r"""
+import json, sys
+sys.path.insert(0, {repo!r})
+import numpy as np
+import jax, jax.numpy as jnp
+from pinot_tpu.ops import kernels as K
+from pinot_tpu.ops import ivf_kernels
+
+out = {{"platform": jax.devices()[0].platform}}
+rng = np.random.default_rng(0)
+
+def relerr(got, exact):
+    got = np.asarray(got, np.float64)
+    nz = exact != 0
+    return float(np.max(np.abs(got[nz] - exact[nz]) / np.abs(exact[nz])))
+
+def values(*shape):
+    return (rng.random(shape) * 1e5).round(2).astype(np.float32)
+
+# _block_compact: one contributor per output cell -> bit-exact move
+n = 8 * K.CBLOCK
+mask = rng.random(n) < 0.004
+vs = [values(n), values(n)]
+floats, valid = jax.jit(
+    lambda m, a, b: K._block_compact(m, [], [a, b], 16)[1:3])(
+        jnp.asarray(mask), jnp.asarray(vs[0]), jnp.asarray(vs[1]))
+floats, valid = np.asarray(floats), np.asarray(valid)
+out["compact_bitexact"] = bool(np.array_equal(
+    floats[valid], np.stack([v[mask] for v in vs], axis=-1)))
+
+# _slot_sum_tables: direct one-hot and radix-factored float group sums
+k = 1 << 15
+errs = []
+for t_slots in (600, K.SLOT_RADIX_G + 1000):
+    gslot = rng.integers(0, 300, k).astype(np.int32)
+    fv = values(k, 2)
+    got = jax.jit(lambda g, v: K._slot_sum_tables(
+        g, t_slots, None, v, None)[1])(jnp.asarray(gslot), jnp.asarray(fv))
+    exact = np.zeros((2, t_slots), np.float64)
+    for lane in range(2):
+        np.add.at(exact[lane], gslot, fv[:, lane].astype(np.float64))
+    errs.append(relerr(got, exact))
+
+# _radix_group_sum on f32 operands (row-scale float group sums)
+g = 2048
+idx = rng.integers(0, g, k).astype(np.int32)
+v = values(k)
+def radix(i, x):
+    hi, lo = K._radix_onehots(i, K._radix_pad(g), jnp.float32)
+    return K._radix_group_sum(hi, lo, x, g, jnp.float32)
+exact = np.zeros(g, np.float64)
+np.add.at(exact, idx, v.astype(np.float64))
+errs.append(relerr(jax.jit(radix)(jnp.asarray(idx), jnp.asarray(v)), exact))
+
+# _dense_group_float_sums below the radix threshold (direct one-hot)
+n2, g2 = 8 * K.BLOCK, 64
+key = rng.integers(0, g2, n2).astype(np.int32)
+v2 = values(n2)
+exact = np.zeros(g2, np.float64)
+np.add.at(exact, key, v2.astype(np.float64))
+errs.append(relerr(jax.jit(lambda x, kk: K._dense_group_float_sums(
+    x, kk, jnp.ones(n2, bool), g2))(jnp.asarray(v2), jnp.asarray(key)),
+    exact))
+out["sum_relerrs"] = errs
+
+# IVF assignment vs the f64 nearest centroid
+n_pad, c_pad, d = 8192, 64, 128
+data = rng.standard_normal((n_pad, d)).astype(np.float32)
+cent = rng.standard_normal((c_pad, d)).astype(np.float32)
+res = ivf_kernels.get_ivf_assign_kernel(n_pad, c_pad, d)(
+    jnp.asarray(data), jnp.asarray(cent), jnp.int32(n_pad), jnp.int32(c_pad))
+d2 = ((data.astype(np.float64)[:, None, :] -
+       cent.astype(np.float64)[None]) ** 2).sum(-1)
+out["ivf_assign_mismatches"] = int(
+    (np.asarray(res["ivf.assign"]) != d2.argmin(1)).sum())
+print("DEVICE_RESULT " + json.dumps(out))
+"""
+
+
+def test_device_f32_contractions_keep_f32_values():
+    """PR 21 chip finding: at the TPU's default matmul precision an f32
+    value operand is rounded to bf16 before the MXU (measured ~1e-3
+    relative on v5e; the CPU backend never rounds), which broke the
+    compact/slot/radix float-sum paths whenever two or more float lanes
+    made the contraction a real matmul, and mis-assigned ~0.3% of rows
+    at IVF seal. The sites now carry an explicit precision; this pins
+    them on the chip."""
+    out = _run_driver(_DRIVER_F32)
+    assert out["compact_bitexact"], out
+    assert max(out["sum_relerrs"]) < 1e-5, out
+    assert out["ivf_assign_mismatches"] == 0, out

@@ -207,6 +207,31 @@ def test_server_reports_missing_segments(server_with_data):
     assert dt.to_block().agg_intermediates[0] == 2000
 
 
+def test_device_fault_surfaces_and_never_reaches_the_host_twin(
+        server_with_data, monkeypatch):
+    """Only the planner's own verdicts (UnsupportedOnDevice,
+    GroupsLimitExceeded) may route a segment to host_exec. A runtime
+    fault of the device — compile failure, RESOURCE_EXHAUSTED — must
+    come back as an exception, not as a clean-looking host answer."""
+    from pinot_tpu.query import host_exec
+    from pinot_tpu.query.plan import SegmentPlan
+    server, _ = server_with_data
+
+    def device_fault(self):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected device fault")
+
+    def host_twin(*a, **k):
+        raise AssertionError("a device fault fell through to host_exec")
+
+    monkeypatch.setattr(SegmentPlan, "execute", device_fault)
+    monkeypatch.setattr(host_exec, "execute_host", host_twin)
+    dt = _query_server(server, "SELECT SUM(runs) FROM baseballStats "
+                               "WHERE yearID >= 1999")
+    assert any("RESOURCE_EXHAUSTED" in e for e in dt.exceptions), \
+        dt.exceptions
+    assert dt.num_rows() == 0
+
+
 def test_server_unknown_table(server_with_data):
     server, _ = server_with_data
     dt = _query_server(server, "SELECT COUNT(*) FROM nope")
