@@ -474,7 +474,8 @@ def wire_schema() -> dict:
         selection_results=SelectionResults(columns=["a"], results=[[1]]),
         exceptions=[{"errorCode": 0, "message": "m"}],
         num_consuming_segments_queried=1,
-        trace_info={"broker": []}, trace_tree={"spanId": "r"})
+        trace_info={"broker": []}, trace_tree={"spanId": "r"},
+        profile_info={"paths": {"scan": 1}})
 
     # object serde: tag byte per exemplar python type
     object_tags = {}

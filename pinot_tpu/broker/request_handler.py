@@ -11,6 +11,7 @@ partial-response tolerance, reduce via BrokerReduceService).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import json
 import threading
@@ -125,6 +126,15 @@ class TcpTransport(ServerTransport):
             else:
                 conn.close_threadsafe()
         self._conns.clear()
+
+
+def _dispatch_span(trace: Optional[TraceContext], dspan: Optional[dict],
+                   name: str):
+    """A span under one dispatch span (explicit parent: concurrent
+    dispatches share the event-loop thread); nothing when untraced."""
+    if dspan is None:
+        return contextlib.nullcontext()
+    return trace.span(name, parent_id=dspan["spanId"])
 
 
 def _server_error(server: str, message: str) -> dict:
@@ -357,14 +367,18 @@ class QueryRouter:
             dspan = trace.record(f"dispatch:{server}", 0.0,
                                  parent_id=parent_span_id,
                                  segments=len(segments))
-        payload = instance_request_to_bytes(InstanceRequest(
-            request_id=request_id, query=sub, search_segments=segments,
-            broker_id=self.broker_id, enable_trace=enable_trace,
-            deadline_budget_ms=budget * 1e3,
-            trace_id=trace.trace_id if dspan is not None else None,
-            parent_span_id=dspan["spanId"] if dspan is not None else None,
-            workload=workload, hedge=hedge,
-            exchange_sources=exchange_sources))
+        with _dispatch_span(trace, dspan,
+                            BrokerQueryPhase.REQUEST_SERIALIZATION):
+            payload = instance_request_to_bytes(InstanceRequest(
+                request_id=request_id, query=sub,
+                search_segments=segments,
+                broker_id=self.broker_id, enable_trace=enable_trace,
+                deadline_budget_ms=budget * 1e3,
+                trace_id=trace.trace_id if dspan is not None else None,
+                parent_span_id=dspan["spanId"] if dspan is not None
+                else None,
+                workload=workload, hedge=hedge,
+                exchange_sources=exchange_sources))
         self.metrics.meter(BrokerMeter.INSTANCE_REQUEST_BYTES).mark(
             len(payload))
         t0 = self._clock()
@@ -378,7 +392,10 @@ class QueryRouter:
                 len(raw))
             with self.metrics.timer(
                     BrokerQueryPhase
-                    .SERVER_RESPONSE_DESERIALIZATION).time():
+                    .SERVER_RESPONSE_DESERIALIZATION).time(), \
+                    _dispatch_span(
+                        trace, dspan,
+                        BrokerQueryPhase.RESPONSE_DESERIALIZATION):
                 # colocated shared-memory replies decode straight from
                 # the segment, then unlink (the decoder copies blocks
                 # out of writable buffers by contract)
@@ -902,8 +919,11 @@ class BrokerRequestHandler:
             resp.time_used_ms)
         self.metrics.meter(BrokerMeter.DOCUMENTS_SCANNED).mark(
             resp.num_docs_scanned)
-        self._fold_profiles(request, tables, resp.time_used_ms)
+        profile = self._fold_profiles(request, tables, resp.time_used_ms)
         if request.query_options.trace:
+            # which path answered (paths.scan / cube / host / sharded),
+            # dispatches, bytes pulled: beside the tree, traced only
+            resp.profile_info = profile
             trace.finish_root()
             resp.trace_info = {"broker": trace.to_list()}
             merged = trace.to_list()
@@ -941,9 +961,10 @@ class BrokerRequestHandler:
 
     def _fold_profiles(self, request: BrokerRequest,
                        tables: List[DataTable],
-                       time_used_ms: float) -> None:
+                       time_used_ms: float) -> Optional[dict]:
         """Merge every server's per-query operator profile into one
-        query-level record on the rolling per-table stats."""
+        query-level record on the rolling per-table stats; returns it
+        (None where no server sent one)."""
         merged: Optional[dict] = None
         for dt in tables:
             if dt.metadata.get(RESULT_CACHE_HIT_KEY):
@@ -974,6 +995,7 @@ class BrokerRequestHandler:
         if merged is not None:
             self.table_stats.record(raw_table(request.table_name),
                                     merged, time_used_ms)
+        return merged
 
     async def _retry_missing_segments(self, routes, tables,
                                       deadline: float,

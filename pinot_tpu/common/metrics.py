@@ -283,6 +283,10 @@ class BrokerQueryPhase:
     # the serde share of the gather, metered per dispatch)
     SERVER_RESPONSE_DESERIALIZATION = "serverResponseDeserialization"
     REDUCE = "reduce"
+    # span names only (trace=true): the broker's encode of one
+    # InstanceRequest and its decode of one server reply, a dispatch
+    REQUEST_SERIALIZATION = "requestSerialization"
+    RESPONSE_DESERIALIZATION = "responseDeserialization"
     QUERY_TOTAL = "queryTotal"
 
 
@@ -340,9 +344,18 @@ class ServerMeter:
     # The probe-vs-exact-fallback split per segment rides the obs
     # profiler's path counters ("ivfProbe" / "ivfExactFallback")
     IVF_NPROBE_QUERIES = "ivfNprobeQueries"
+    # jax.monitoring (obs/profiler.py bind_compile_metrics): programs
+    # this process met for the first time (compiled by XLA or loaded
+    # from the persistent cache), and how many of those the persistent
+    # cache supplied
+    XLA_COMPILES = "xlaCompiles"
+    XLA_COMPILE_CACHE_HITS = "xlaCompileCacheHits"
 
 
 class ServerTimer:
+    # seconds-of-compile as a timer: one update a program met for the
+    # first time, backend compile or cache load (jax.monitoring)
+    XLA_COMPILE = "xlaCompile"
     # queries served per sealed batch window (a Timer so the occupancy
     # DISTRIBUTION rides the existing histogram/percentile machinery;
     # the "ms" unit suffix in the exposition reads as "queries")
@@ -401,6 +414,15 @@ class ServerQueryPhase:
     QUERY_PLAN_EXECUTION = "queryPlanExecution"
     QUERY_PROCESSING = "queryProcessing"
     RESPONSE_SERIALIZATION = "responseSerialization"
+    # span names only (trace=true): the inside of segmentExecution and
+    # queryPlanExecution (docs/OBSERVABILITY.md has the tree)
+    SEGMENT_QUEUE_WAIT = "segmentQueueWait"
+    STAR_TREE_EXECUTE = "starTreeExecute"
+    OPERAND_GATHER = "operandGather"
+    KERNEL_LAUNCH = "kernelLaunch"
+    KERNEL_DISPATCH = "kernelDispatch"
+    OUTPUT_RELEASE = "outputRelease"
+    RESULT_FINISH = "resultFinish"
 
 
 class ServerGauge:

@@ -118,6 +118,10 @@ class BrokerResponse:
     # compile/route/scatter/reduce spans with each server's queue-wait/
     # plan/execute/serde subtree grafted under its dispatch span
     trace_tree: Optional[dict] = None
+    # trace=true responses: the servers' merged operator profile
+    # (obs/profiler.py QueryProfile.to_json: paths, kernelDispatches,
+    # deviceTransferBytes ...), the record /debug/tableStats folds
+    profile_info: Optional[dict] = None
 
     def to_json(self) -> dict:
         d = {
@@ -152,6 +156,8 @@ class BrokerResponse:
             d["traceInfo"] = self.trace_info
         if self.trace_tree is not None:
             d["traceTree"] = self.trace_tree
+        if self.profile_info is not None:
+            d["profileInfo"] = self.profile_info
         return d
 
     def to_json_str(self) -> str:

@@ -18,14 +18,24 @@ The ambient context is a per-thread slot: the server executor activates
 (profile, trace) around a query, worker-pool threads re-activate the
 captured context inside their closure, and the hot-path check when
 nothing is active is a single threading.local attribute read.
+
+Two process-level hooks of the chip's holder live here too, both with
+jax imported inside the call (the broker imports this module):
+`bind_compile_metrics` (XLA compile counters from `jax.monitoring`) and
+`DeviceProfiler` (one `jax.profiler` session whose start is known on
+the wall clock, behind the server's `/debug/profiler/*`).
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
-from contextlib import contextmanager
+import weakref
+from contextlib import contextmanager, nullcontext
 from typing import Dict, Optional, Tuple
+
+from pinot_tpu.common.metrics import (ServerMeter, ServerQueryPhase,
+                                      ServerTimer)
 
 _tls = threading.local()
 
@@ -57,15 +67,19 @@ def reactivate(ctx: Optional[tuple]):
         _tls.ctx = prev
 
 
-@contextmanager
+#: what `obs_span` hands back while no trace is on: one shared object
+#: whose enter yields None and whose exit does nothing
+_NO_SPAN = nullcontext()
+
+
 def obs_span(name: str, **attrs):
-    """A trace span on the ambient trace (noop when nothing is active)."""
+    """A span on the ambient trace, for `with obs_span(...) as span`.
+    With no trace on it is one thread-local read and the shared no-op
+    context (`span` is None): no generator, no clock, no allocation."""
     ctx = getattr(_tls, "ctx", None)
     if ctx is None or ctx[1] is None or not ctx[1].enabled:
-        yield None
-        return
-    with ctx[1].span(name, **attrs) as s:
-        yield s
+        return _NO_SPAN
+    return ctx[1].span(name, **attrs)
 
 
 def profiled_device_get(x):
@@ -74,24 +88,28 @@ def profiled_device_get(x):
     Every driver funnels its one explicit batched device→host pull per
     dispatch through here: the ambient profile counts the dispatch and
     the host-side bytes, and the ambient trace gets a `kernelDispatch`
-    span. With nothing active this is jax.device_get + one
+    span (the host's wait for the device and the copy, not device
+    time). With nothing active this is jax.device_get + one
     threading.local read.
     """
     import jax
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
         return jax.device_get(x)
-    t0 = time.perf_counter()
-    outs = jax.device_get(x)
-    ms = (time.perf_counter() - t0) * 1e3
+    profile = ctx[0]
+    # `ms` is device_get alone, also under a span: the profile's
+    # kernelMs stays clear of the span's own bookkeeping
+    with obs_span(ServerQueryPhase.KERNEL_DISPATCH) as span:
+        t0 = time.perf_counter()
+        outs = jax.device_get(x)
+        ms = (time.perf_counter() - t0) * 1e3
     nbytes = 0
     for leaf in jax.tree_util.tree_leaves(outs):
         nbytes += int(getattr(leaf, "nbytes", 0))
-    profile, trace = ctx
     if profile is not None:
         profile.add_dispatch(nbytes, ms)
-    if trace is not None and trace.enabled:
-        trace.record("kernelDispatch", ms, bytes=nbytes)
+    if span is not None:
+        span["attrs"] = {"bytes": nbytes}
     return outs
 
 
@@ -230,3 +248,128 @@ class TableStatsAggregator:
         if shallow is None:
             return {}
         return json.loads(json.dumps(shallow))
+
+
+# -- XLA compile counters ---------------------------------------------------
+
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_bound: "weakref.WeakSet" = weakref.WeakSet()
+_compile_lock = threading.Lock()
+_compile_listening = False
+
+
+def _compile_registries() -> list:
+    with _compile_lock:
+        return list(_compile_bound)
+
+
+def _on_compile_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    for metrics in _compile_registries():
+        metrics.meter(ServerMeter.XLA_COMPILES).mark()
+        metrics.timer(ServerTimer.XLA_COMPILE).update(duration_secs * 1e3)
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    if event != _CACHE_HIT_EVENT:
+        return
+    for metrics in _compile_registries():
+        metrics.meter(ServerMeter.XLA_COMPILE_CACHE_HITS).mark()
+
+
+def bind_compile_metrics(metrics) -> None:
+    """Count on `metrics` every program this PROCESS meets for the
+    first time: meter `xlaCompiles` and timer `xlaCompile` from JAX's
+    backend-compile event (it fires whether XLA compiles the program or
+    the persistent cache supplies it), meter `xlaCompileCacheHits` from
+    the cache's hit event. All three exist at 0 from this call on. The
+    listeners are process-global and registered once; registries are
+    held weakly, like the residency ledger's."""
+    global _compile_listening
+    metrics.meter(ServerMeter.XLA_COMPILES)
+    metrics.meter(ServerMeter.XLA_COMPILE_CACHE_HITS)
+    metrics.timer(ServerTimer.XLA_COMPILE)
+    with _compile_lock:
+        _compile_bound.add(metrics)
+        if _compile_listening:
+            return
+        _compile_listening = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
+    jax.monitoring.register_event_listener(_on_compile_event)
+
+
+# -- the device profiler, one session at a time ------------------------------
+
+PROFILER_ANCHOR = "pinot.profilerAnchor"
+
+
+class ProfilerBusy(Exception):
+    """A session is open (start), or none is (stop)."""
+
+
+class DeviceProfiler:
+    """One `jax.profiler` session of this process, with its start known
+    on the wall clock.
+
+    Every plane of a session's `.xplane.pb` shares one clock whose zero
+    is the session's start, so a span (`startUs`, wall clock) can be
+    laid beside a device op only if that zero is known on the wall
+    clock. `start` reads `time.time_ns()` immediately before and after
+    `jax.profiler.start_trace` (the zero lies between the two), then
+    opens and closes one `TraceAnnotation(PROFILER_ANCHOR, wall_ns=...)`
+    whose own start on the profiler's clock pins the offset to the
+    width of one call: wall = anchorWallNs + (event.start_ns −
+    anchor.start_ns)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._open: Optional[dict] = None
+        self._last: Optional[dict] = None
+
+    def start(self, log_dir: str) -> dict:
+        import jax
+        with self._lock:
+            if self._open is not None:
+                raise ProfilerBusy("a profiler session is open since "
+                                   f"{self._open['startedNs'][0]} ns")
+            # the host's annotations and no Python call stacks: what
+            # the spans need, at the cost PERF.md states
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 1
+            options.python_tracer_level = 0
+            before = time.time_ns()
+            jax.profiler.start_trace(log_dir, profiler_options=options)
+            after = time.time_ns()
+            anchor_ns = time.time_ns()
+            with jax.profiler.TraceAnnotation(PROFILER_ANCHOR,
+                                              wall_ns=anchor_ns):
+                pass
+            self._open = {"dir": log_dir, "startedNs": [before, after],
+                          "anchorWallNs": anchor_ns}
+            return dict(self._open)
+
+    def stop(self) -> dict:
+        import jax
+        with self._lock:
+            if self._open is None:
+                raise ProfilerBusy("no profiler session is open")
+            stopped = time.time_ns()
+            try:
+                jax.profiler.stop_trace()
+            finally:
+                session, self._open = self._open, None
+            self._last = dict(session, stoppedNs=stopped)
+            return dict(self._last)
+
+    def status(self) -> dict:
+        with self._lock:
+            return {"open": dict(self._open) if self._open else None,
+                    "last": dict(self._last) if self._last else None}
+
+
+#: this process's one session (jax.profiler is process-global)
+PROFILER = DeviceProfiler()

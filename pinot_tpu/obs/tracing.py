@@ -11,10 +11,24 @@ list into ONE tree with correct cross-process parent links.
 
 Design notes:
 
-- Spans are plain dicts ``{"name", "ms", "spanId", "parentId"}`` (+
-  optional ``"attrs"``) appended to a per-request list under a lock —
-  the flat list stays cheap to serialize into DataTable metadata, and
-  the tree is assembled once, at the broker, by `build_trace_tree`.
+- Spans are plain dicts ``{"name", "ms", "startUs", "spanId",
+  "parentId"}`` (+ optional ``"attrs"``) appended to a per-request list
+  under a lock — the flat list stays cheap to serialize into DataTable
+  metadata, and the tree is assembled once, at the broker, by
+  `build_trace_tree`.
+- ``startUs`` is the span's start on the WALL clock (microseconds since
+  the epoch, one `time.time_ns()` a span) so that spans of different
+  threads and processes of one machine, and the device trace of a
+  `jax.profiler` session whose wall-clock start is known
+  (`obs/profiler.py:DeviceProfiler`), can be laid beside each other;
+  ``ms`` stays a `perf_counter` duration. The key is optional on the
+  wire: a span from a peer without it still parses and still lands in
+  the tree.
+- A context may be given an ``annotate`` factory (the server hands in
+  `jax.profiler.TraceAnnotation`): `span()` then also enters
+  ``annotate(name, **attrs)``, which puts the span on the host threads
+  of an open profiler session, on the profiler's own clock. This module
+  never imports jax: the broker and the controller use it too.
 - Parenting is a per-THREAD stack inside the context: the broker path
   is async and the server path fans segments onto a worker pool, so a
   single global stack would interleave spans across threads. Workers
@@ -25,7 +39,8 @@ Design notes:
 
 Wire format (DataTable metadata "traceInfo" / InstanceRequest):
 ``{"traceId": ..., "rootSpanId": ..., "spans": [...]}``; the legacy
-flat ``[{"name", "ms"}, ...]`` list still parses (version skew).
+flat ``[{"name", "ms"}, ...]`` list, and spans without ``startUs``,
+still parse (version skew).
 """
 from __future__ import annotations
 
@@ -35,12 +50,17 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 
 def _new_id() -> str:
     """A 12-hex-char id, unique enough for one trace's span namespace."""
     return os.urandom(6).hex()
+
+
+def _now_us() -> int:
+    """The wall clock in microseconds since the epoch (`startUs`)."""
+    return time.time_ns() // 1000
 
 
 class TraceContext:
@@ -50,8 +70,13 @@ class TraceContext:
 
     def __init__(self, trace_id: Optional[str] = None,
                  parent_span_id: Optional[str] = None,
-                 root_name: str = "query"):
+                 root_name: str = "query",
+                 annotate: Optional[Callable[..., object]] = None):
         self.trace_id = trace_id or _new_id()
+        # (name, **attrs) -> context manager entered beside every
+        # span() (the server's jax.profiler.TraceAnnotation); None on
+        # the broker and the controller, which stay off jax
+        self._annotate = annotate
         # span ids are prefix+counter: one urandom call per context, not
         # per span (spans are created on the hot path)
         self._prefix = _new_id()
@@ -61,6 +86,7 @@ class TraceContext:
         self._tls = threading.local()
         self.root_span_id = self._next_id()
         self._root = {"name": root_name, "ms": 0.0,
+                      "startUs": _now_us(),
                       "spanId": self.root_span_id,
                       "parentId": parent_span_id}
         self.spans.append(self._root)
@@ -93,11 +119,16 @@ class TraceContext:
 
     # -- span creation ------------------------------------------------------
     def record(self, name: str, ms: float,
-               parent_id: Optional[str] = None, **attrs) -> dict:
+               parent_id: Optional[str] = None,
+               start_us: Optional[int] = None, **attrs) -> dict:
         """Append a completed span (for durations measured externally,
-        e.g. scheduler queue-wait)."""
+        e.g. scheduler queue-wait). It is taken to have ended now, so
+        it started `ms` ago, unless the caller knows its `start_us`."""
         span: Dict[str, object] = {
-            "name": name, "ms": round(ms, 3), "spanId": self._next_id(),
+            "name": name, "ms": round(ms, 3),
+            "startUs": _now_us() - int(ms * 1e3) if start_us is None
+            else int(start_us),
+            "spanId": self._next_id(),
             "parentId": parent_id or self.current_span_id()}
         if attrs:
             span["attrs"] = attrs
@@ -109,7 +140,8 @@ class TraceContext:
     def span(self, name: str, parent_id: Optional[str] = None, **attrs):
         """Open a span; children created on this thread nest under it."""
         s: Dict[str, object] = {
-            "name": name, "ms": 0.0, "spanId": self._next_id(),
+            "name": name, "ms": 0.0, "startUs": 0,
+            "spanId": self._next_id(),
             "parentId": parent_id or self.current_span_id()}
         if attrs:
             s["attrs"] = attrs
@@ -117,11 +149,18 @@ class TraceContext:
             self.spans.append(s)
         stack = self._stack()
         stack.append(s["spanId"])
+        annotation = None
+        if self._annotate is not None:
+            annotation = self._annotate(name, **attrs)
+            annotation.__enter__()
+        s["startUs"] = _now_us()
         t0 = time.perf_counter()
         try:
             yield s
         finally:
             s["ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             # pop by value: interleaved async spans on one thread may
             # close out of LIFO order
             if stack and stack[-1] == s["spanId"]:
@@ -180,7 +219,8 @@ class NoopTraceContext(TraceContext):
         yield
 
     def record(self, name: str, ms: float,
-               parent_id: Optional[str] = None, **attrs) -> dict:
+               parent_id: Optional[str] = None,
+               start_us: Optional[int] = None, **attrs) -> dict:
         return {}
 
     @contextmanager
@@ -199,11 +239,13 @@ class NoopTraceContext(TraceContext):
 
 def make_trace_context(enabled: bool, trace_id: Optional[str] = None,
                        parent_span_id: Optional[str] = None,
-                       root_name: str = "query") -> TraceContext:
+                       root_name: str = "query",
+                       annotate: Optional[Callable[..., object]] = None
+                       ) -> TraceContext:
     if not enabled:
         return NoopTraceContext()
     return TraceContext(trace_id=trace_id, parent_span_id=parent_span_id,
-                        root_name=root_name)
+                        root_name=root_name, annotate=annotate)
 
 
 def build_trace_tree(spans: List[Dict[str, object]],

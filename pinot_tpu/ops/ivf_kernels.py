@@ -94,9 +94,11 @@ def build_ivf_probe_kernel(c_pad: int, dim_pad: int, nprobe: int,
 
 @functools.lru_cache(maxsize=64)
 def get_ivf_assign_kernel(n_pad: int, c_pad: int, dim_pad: int):
-    return jax.jit(build_ivf_assign_kernel(n_pad, c_pad, dim_pad))
+    return jax.jit(kernels.named_kernel(
+        build_ivf_assign_kernel(n_pad, c_pad, dim_pad), "ivf_assign"))
 
 
 @functools.lru_cache(maxsize=64)
 def get_ivf_train_kernel(n_pad: int, c_pad: int, dim_pad: int):
-    return jax.jit(build_ivf_train_kernel(n_pad, c_pad, dim_pad))
+    return jax.jit(kernels.named_kernel(
+        build_ivf_train_kernel(n_pad, c_pad, dim_pad), "ivf_train"))

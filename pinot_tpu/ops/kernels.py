@@ -1616,9 +1616,20 @@ def build_window_kernel(n_pad: int, n_order: int, n_sums: int):
     return kernel
 
 
+def named_kernel(fn, family: str):
+    """Name a kernel closure by its FAMILY before it is jitted, so the
+    device trace's `XLA Modules` read `jit_pinot_<family>(<fingerprint>)`
+    instead of `jit_kernel(...)`. Never a literal or a shape: the
+    fingerprint XLA appends tells programs apart. The name is part of
+    the HLO module and so of the persistent compile cache's key."""
+    fn.__name__ = fn.__qualname__ = f"pinot_{family}"
+    return fn
+
+
 @functools.lru_cache(maxsize=128)
 def get_window_kernel(n_pad: int, n_order: int, n_sums: int):
-    return jax.jit(build_window_kernel(n_pad, n_order, n_sums))
+    return jax.jit(named_kernel(build_window_kernel(n_pad, n_order, n_sums),
+                                "window"))
 
 
 def run_window_kernel(part, orders, sums, num_rows):
@@ -1653,7 +1664,15 @@ def build_segment_kernel(padded: int, filter_spec, agg_specs, group_spec,
                                            plist))
         return outs
 
-    return kernel
+    return named_kernel(kernel, scan_family(group_spec, select_spec))
+
+
+def scan_family(group_spec, select_spec) -> str:
+    """`scan_group`, `scan_select` or `scan_agg`: which of the three
+    output stages the whole-plan kernel was built with."""
+    if group_spec is not None:
+        return "scan_group"
+    return "scan_select" if select_spec is not None else "scan_agg"
 
 
 @functools.lru_cache(maxsize=1024)
@@ -1692,7 +1711,9 @@ def get_batched_segment_kernel(padded: int, filter_spec, agg_specs,
     property rather than a numerical accident for the integer paths."""
     base = build_segment_kernel(padded, filter_spec, agg_specs, None,
                                 select_spec)
-    return jax.jit(jax.vmap(base, in_axes=(None, 0, None)))
+    return jax.jit(named_kernel(
+        jax.vmap(base, in_axes=(None, 0, None)),
+        f"{scan_family(None, select_spec)}_batched"))
 
 
 def stack_param_leaves(params_list):

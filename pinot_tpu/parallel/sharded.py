@@ -148,7 +148,9 @@ def get_sharded_kernel(mesh: Mesh, padded: int, filter_spec, agg_specs,
     fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(col_specs, P(), P(SEG_AXIS)),
                        out_specs=P(), check_vma=False)
-    return jax.jit(fn)
+    from pinot_tpu.ops.kernels import named_kernel, scan_family
+    return jax.jit(named_kernel(
+        fn, f"sharded_{scan_family(group_spec, select_spec)}"))
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,8 @@ import jax
 import numpy as np
 
 from pinot_tpu.analysis.runtime import debug_transfer_guard
-from pinot_tpu.obs.profiler import profiled_device_get
+from pinot_tpu.common.metrics import ServerQueryPhase
+from pinot_tpu.obs.profiler import obs_span, profiled_device_get
 from pinot_tpu.ops import kernels
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
 from pinot_tpu.segment.loader import ImmutableSegment
@@ -67,7 +68,11 @@ def gather_operands_for(segment, needed_cols) -> Dict[str, object]:
 
 
 def gather_operands(plan) -> Dict[str, object]:
-    return gather_operands_for(plan.segment, plan.needed_cols)
+    """The plan's lanes as device arrays, under an `operandGather`
+    span: the host-side rebuild of each padded operand, the look-up in
+    the lane cache and, on a miss, the upload."""
+    with obs_span(ServerQueryPhase.OPERAND_GATHER):
+        return gather_operands_for(plan.segment, plan.needed_cols)
 
 
 def execute_segment_plan(plan) -> IntermediateResultsBlock:
@@ -89,35 +94,57 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
     def run(agg_specs, group_spec, extra_params=()):
         # returns DEVICE outs; each driver batches the device→host pull
         # into one explicit jax.device_get per dispatch (tpulint
-        # host-sync: never per-scalar)
-        return kernels.run_segment_kernel(
-            segment.padded_docs, plan.filter_spec, agg_specs,
-            group_spec, plan.select_spec, cols,
-            tuple(plan.params) + tuple(extra_params),
-            segment.num_docs)
+        # host-sync: never per-scalar). kernelLaunch ends at the
+        # asynchronous return: argument handling, jit cache look-up,
+        # any compile, enqueue
+        with obs_span(ServerQueryPhase.KERNEL_LAUNCH):
+            return kernels.run_segment_kernel(
+                segment.padded_docs, plan.filter_spec, agg_specs,
+                group_spec, plan.select_spec, cols,
+                tuple(plan.params) + tuple(extra_params),
+                segment.num_docs)
 
     blk = IntermediateResultsBlock()
+    spec_used = None
     if plan.group_spec is not None:
         outs, spec_used = drive_group_execution(run, plan.group_spec,
                                                 segment.padded_docs,
                                                 segment.num_docs)
-        if spec_used is None:
-            blk.group_map = {}
-        else:
-            _finish_group_by(_with_group_spec(plan, spec_used), outs, blk)
     else:
         # profiled twin of jax.device_get: counts the dispatch and the
         # host-side bytes on the ambient query profile
-        outs = profiled_device_get(run(plan.agg_specs, None, ()))
-        if plan.agg_specs:
+        launched = run(plan.agg_specs, None, ())
+        outs = profiled_device_get(launched)
+        # dropping the last reference to the device outputs is not
+        # free (the tracing's first finding: 1.9 ms a segment on the
+        # CPU rehearsal): it happens here, under a span, not wherever
+        # the temporary would have died
+        with obs_span(ServerQueryPhase.OUTPUT_RELEASE):
+            del launched
+    with obs_span(ServerQueryPhase.RESULT_FINISH):
+        if plan.group_spec is not None:
+            if spec_used is None:
+                blk.group_map = {}
+            else:
+                _finish_group_by(_with_group_spec(plan, spec_used), outs,
+                                 blk)
+        elif plan.agg_specs:
             _finish_aggregation(plan, outs, blk)
+        _finish_selection_and_stats(plan, outs, blk, 0.0)
+    blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
+    return blk
+
+
+def _finish_selection_and_stats(plan, outs, blk, elapsed_ms: float) -> None:
+    """The tail every path shares: the selection rows, if any, and the
+    segment's ExecutionStats."""
+    segment = plan.segment
     matched = int(outs["stats.num_docs_matched"])
     if plan.select_spec is not None:
         if plan.select_spec[0] == "vector":
             _finish_vector(plan, outs, blk, matched)
         else:
             _finish_selection(plan, outs, blk, matched)
-
     n_leaves = _count_filter_leaves(plan.filter_spec)
     n_project = len({c for c, _ in plan.needed_cols})
     blk.stats = ExecutionStats(
@@ -127,8 +154,7 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
         num_segments_processed=1,
         num_segments_matched=1 if matched else 0,
         total_docs=segment.num_docs,
-        time_used_ms=(time.perf_counter() - t0) * 1e3)
-    return blk
+        time_used_ms=elapsed_ms)
 
 
 def execute_segment_plans_batched(plans) -> List[IntermediateResultsBlock]:
@@ -151,45 +177,35 @@ def execute_segment_plans_batched(plans) -> List[IntermediateResultsBlock]:
     with debug_transfer_guard():
         cols = gather_operands(lead)
         if lead.params:
-            outs_b = profiled_device_get(kernels.run_segment_kernel_batched(
-                segment.padded_docs, lead.filter_spec, lead.agg_specs,
-                lead.select_spec, cols,
-                [tuple(p.params) for p in plans], segment.num_docs))
+            with obs_span(ServerQueryPhase.KERNEL_LAUNCH):
+                launched = kernels.run_segment_kernel_batched(
+                    segment.padded_docs, lead.filter_spec, lead.agg_specs,
+                    lead.select_spec, cols,
+                    [tuple(p.params) for p in plans], segment.num_docs)
+            outs_b = profiled_device_get(launched)
             per_member = [{k: v[b] for k, v in outs_b.items()}
                           for b in range(len(plans))]
         else:
             # param-free same-signature plans are identical programs:
             # one unbatched dispatch, every member reads the same outs
-            outs1 = profiled_device_get(kernels.run_segment_kernel(
-                segment.padded_docs, lead.filter_spec, lead.agg_specs,
-                None, lead.select_spec, cols, (), segment.num_docs))
-            per_member = [outs1] * len(plans)
+            with obs_span(ServerQueryPhase.KERNEL_LAUNCH):
+                launched = kernels.run_segment_kernel(
+                    segment.padded_docs, lead.filter_spec, lead.agg_specs,
+                    None, lead.select_spec, cols, (), segment.num_docs)
+            per_member = [profiled_device_get(launched)] * len(plans)
+        with obs_span(ServerQueryPhase.OUTPUT_RELEASE):
+            del launched
     elapsed_ms = (time.perf_counter() - t0) * 1e3
-    n_leaves = _count_filter_leaves(lead.filter_spec)
-    n_project = len({c for c, _ in lead.needed_cols})
     blocks = []
-    for plan, outs in zip(plans, per_member):
-        blk = IntermediateResultsBlock()
-        if plan.agg_specs:
-            _finish_aggregation(plan, outs, blk)
-        matched = int(outs["stats.num_docs_matched"])
-        if plan.select_spec is not None:
-            if plan.select_spec[0] == "vector":
-                _finish_vector(plan, outs, blk, matched)
-            else:
-                _finish_selection(plan, outs, blk, matched)
-        # the dispatch was shared; each member reports the batch wall
-        # time (it really waited that long) and its own scan stats
-        blk.stats = ExecutionStats(
-            num_docs_scanned=matched,
-            num_entries_scanned_in_filter=n_leaves * segment.num_docs,
-            num_entries_scanned_post_filter=matched * max(
-                n_project - n_leaves, 0),
-            num_segments_processed=1,
-            num_segments_matched=1 if matched else 0,
-            total_docs=segment.num_docs,
-            time_used_ms=elapsed_ms)
-        blocks.append(blk)
+    with obs_span(ServerQueryPhase.RESULT_FINISH):
+        for plan, outs in zip(plans, per_member):
+            blk = IntermediateResultsBlock()
+            if plan.agg_specs:
+                _finish_aggregation(plan, outs, blk)
+            # the dispatch was shared; each member reports the batch
+            # wall time (it really waited that long) and its own stats
+            _finish_selection_and_stats(plan, outs, blk, elapsed_ms)
+            blocks.append(blk)
     return blocks
 
 

@@ -83,19 +83,11 @@ class ServerQueryExecutor:
             selected = self.pruner.prune(segments, request)
         num_pruned = len(segments) - len(selected)
 
-        from pinot_tpu.query.plan import upsert_mask_active
-        if request.is_aggregation and not request.is_selection and \
-                len(selected) > 1 and \
-                not any(upsert_mask_active(s) for s in selected) and \
-                all(getattr(s, "star_trees", None) for s in selected):
-            from pinot_tpu.startree.executor import \
-                try_star_tree_execute_multi
-            blk = try_star_tree_execute_multi(selected, request)
-            if blk is not None:
-                obs_profiler.count_path("cube", len(selected))
-                blk.stats.num_segments_pruned = num_pruned
-                blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
-                return blk
+        blk = self._try_star_tree_multi(selected, request)
+        if blk is not None:
+            blk.stats.num_segments_pruned = num_pruned
+            blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
+            return blk
 
         with trace.span(ServerQueryPhase.SEGMENT_EXECUTION):
             if self.segment_executor is not None and len(selected) > 1:
@@ -103,7 +95,8 @@ class ServerQueryExecutor:
                     self._run_parallel(selected, request, deadline, trace)
             else:
                 blocks, extra_parts, extra_matched, executed = \
-                    self._run_sequential(selected, request, deadline)
+                    self._run_sequential(selected, request, deadline,
+                                         trace)
         truncated = executed < len(selected)
 
         if not blocks:
@@ -186,14 +179,31 @@ class ServerQueryExecutor:
             seg = seg.snapshot_view()
         return [self._execute_segment(seg, request)], 0, 0
 
+    @staticmethod
+    def _record_queue_wait(trace: Optional[TraceContext], t_queued: float,
+                           seg, parent_id: Optional[str] = None) -> None:
+        """`segmentQueueWait`: how long this segment's work waited for
+        its turn since `t_queued` (perf_counter): for a worker of the
+        pool (the second wave of 8 segments on 4 workers), or for the
+        segments before it in a sequential walk. A sibling of the
+        `segment` span it precedes."""
+        if trace is not None and trace.enabled:
+            trace.record(ServerQueryPhase.SEGMENT_QUEUE_WAIT,
+                         (time.perf_counter() - t_queued) * 1e3,
+                         parent_id=parent_id,
+                         segment=getattr(seg, "segment_name", "?"))
+
     def _run_sequential(self, selected, request: BrokerRequest,
-                        deadline: Optional[float]):
+                        deadline: Optional[float],
+                        trace: Optional[TraceContext] = None):
         blocks: List[IntermediateResultsBlock] = []
         extra_parts = extra_matched = 0
         executed = 0
+        t_queued = time.perf_counter()
         for seg in selected:
             if deadline is not None and time.monotonic() >= deadline:
                 break
+            self._record_queue_wait(trace, t_queued, seg)
             segment_blocks, parts, matched = self._segment_work(seg,
                                                                 request)
             blocks.extend(segment_blocks)
@@ -219,9 +229,12 @@ class ServerQueryExecutor:
         ambient = obs_profiler.current()
         parent_id = trace.current_span_id() if trace is not None else None
 
+        t_queued = time.perf_counter()
+
         def work(seg):
             if deadline is not None and time.monotonic() >= deadline:
                 return None                 # budget gone before start
+            self._record_queue_wait(trace, t_queued, seg, parent_id)
             with obs_profiler.reactivate(ambient):
                 if trace is not None and trace.enabled:
                     with trace.attach(parent_id):
@@ -271,15 +284,9 @@ class ServerQueryExecutor:
 
     def _execute_segment(self, segment: ImmutableSegment,
                          request: BrokerRequest) -> IntermediateResultsBlock:
-        from pinot_tpu.query.plan import upsert_mask_active
-        if request.is_aggregation and not request.is_selection and \
-                not upsert_mask_active(segment) and \
-                getattr(segment, "star_trees", None):
-            from pinot_tpu.startree.executor import try_star_tree_execute
-            blk = try_star_tree_execute(segment, request)
-            if blk is not None:
-                obs_profiler.count_path("cube")
-                return blk
+        blk = self._try_star_tree(segment, request)
+        if blk is not None:
+            return blk
         if self.use_device and \
                 (self.device_gate is None or self.device_gate(segment)):
             try:
@@ -323,8 +330,7 @@ class ServerQueryExecutor:
 
     def _execute_batch(self, requests, segments, deadline):
         t0 = time.perf_counter()
-        from pinot_tpu.query.plan import (preprocess_request,
-                                          upsert_mask_active)
+        from pinot_tpu.query.plan import preprocess_request
         members = []
         for req in requests:
             req = preprocess_request(segments, req)
@@ -334,25 +340,19 @@ class ServerQueryExecutor:
         # per-member multi-segment star-tree fast path (mirrors
         # _execute; a member it answers never reaches the batch loop)
         for m in members:
-            req, selected = m.request, m.selected
-            if req.is_aggregation and not req.is_selection and \
-                    len(selected) > 1 and \
-                    not any(upsert_mask_active(s) for s in selected) and \
-                    all(getattr(s, "star_trees", None) for s in selected):
-                from pinot_tpu.startree.executor import \
-                    try_star_tree_execute_multi
-                blk = try_star_tree_execute_multi(selected, req)
-                if blk is not None:
-                    obs_profiler.count_path("cube", len(selected))
-                    m.final = blk
+            m.final = self._try_star_tree_multi(m.selected, m.request)
         pending = [m for m in members if m.final is None]
 
+        ambient = obs_profiler.current()
+        trace = ambient[1] if ambient is not None else None
+        t_queued = time.perf_counter()
         for seg in segments:
             if deadline is not None and time.monotonic() >= deadline:
                 break
             takers = [m for m in pending if id(seg) in m.selected_ids]
             if not takers:
                 continue
+            self._record_queue_wait(trace, t_queued, seg)
             self._batch_segment(seg, takers)
             for m in takers:
                 m.executed += 1
@@ -413,14 +413,43 @@ class ServerQueryExecutor:
                 m.add([blk], 0, 0)
 
     def _try_star_tree(self, segment, request):
+        """One segment's cube descent under a `starTreeExecute` span
+        (`attrs.hit` false where no cube covers the query and the
+        segment goes on to the scan)."""
         from pinot_tpu.query.plan import upsert_mask_active
         if request.is_aggregation and not request.is_selection and \
                 not upsert_mask_active(segment) and \
                 getattr(segment, "star_trees", None):
             from pinot_tpu.startree.executor import try_star_tree_execute
-            blk = try_star_tree_execute(segment, request)
+            with obs_span(ServerQueryPhase.STAR_TREE_EXECUTE,
+                          segment=getattr(segment, "segment_name",
+                                          "?")) as span:
+                blk = try_star_tree_execute(segment, request)
+                if span is not None:
+                    span["attrs"]["hit"] = blk is not None
             if blk is not None:
                 obs_profiler.count_path("cube")
+                return blk
+        return None
+
+    def _try_star_tree_multi(self, selected, request):
+        """The multi-segment cube fast path (every selected segment
+        must hold a covering cube), one `starTreeExecute` span for the
+        lot (`attrs.segments`)."""
+        from pinot_tpu.query.plan import upsert_mask_active
+        if request.is_aggregation and not request.is_selection and \
+                len(selected) > 1 and \
+                not any(upsert_mask_active(s) for s in selected) and \
+                all(getattr(s, "star_trees", None) for s in selected):
+            from pinot_tpu.startree.executor import \
+                try_star_tree_execute_multi
+            with obs_span(ServerQueryPhase.STAR_TREE_EXECUTE,
+                          segments=len(selected)) as span:
+                blk = try_star_tree_execute_multi(selected, request)
+                if span is not None:
+                    span["attrs"]["hit"] = blk is not None
+            if blk is not None:
+                obs_profiler.count_path("cube", len(selected))
                 return blk
         return None
 

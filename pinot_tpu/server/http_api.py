@@ -42,6 +42,11 @@ class ServerApiServer(ApiServer):
         self.router.add("GET", "/debug/memory", self._memory)
         self.router.add("GET", "/debug/residency", self._residency)
         self.router.add("GET", "/debug/health", self._debug_health)
+        self.router.add("GET", "/debug/profiler", self._profiler_status)
+        self.router.add("POST", "/debug/profiler/start",
+                        self._profiler_start)
+        self.router.add("POST", "/debug/profiler/stop",
+                        self._profiler_stop)
 
     async def _metrics(self, request: HttpRequest) -> HttpResponse:
         return metrics_response(self.server.metrics, request)
@@ -140,6 +145,50 @@ class ServerApiServer(ApiServer):
         # beside the ledger's total above (do the two agree on a chip?)
         out["device"] = device_report()
         return HttpResponse.of_json(out)
+
+    # -- the device profiler (obs/profiler.py DeviceProfiler) ----------------
+    # Only the process that holds the chip can trace it. start/stop run
+    # off the API's event loop: opening a session and writing its
+    # .xplane.pb each take seconds on a chip.
+    async def _profiler_start(self, request: HttpRequest) -> HttpResponse:
+        """Body {"dir"}. 409 while a session is open. Returns
+        `startedNs` (the wall clock immediately before and after
+        `jax.profiler.start_trace`) and `anchorWallNs` (see
+        DeviceProfiler). The host's annotations are on and Python call
+        stacks off (tracer levels 1 and 0): what the spans need."""
+        from pinot_tpu.obs.profiler import PROFILER
+        try:
+            body = request.json() or {}
+        except ValueError as e:
+            return HttpResponse.error(400, f"bad JSON body: {e}")
+        log_dir = body.get("dir")
+        if not log_dir or not isinstance(log_dir, str):
+            return HttpResponse.error(400, "`dir` (where the session's "
+                                      ".xplane.pb is written) is required")
+        return await self._profiler_call(PROFILER.start, log_dir)
+
+    async def _profiler_stop(self, request: HttpRequest) -> HttpResponse:
+        """Closes the session and writes its trace; returns {dir,
+        startedNs: [before, after], anchorWallNs, stoppedNs}: the
+        session's zero on the wall clock, to the width of one call.
+        409 when none is open."""
+        from pinot_tpu.obs.profiler import PROFILER
+        return await self._profiler_call(PROFILER.stop)
+
+    @staticmethod
+    async def _profiler_call(fn, *args) -> HttpResponse:
+        import asyncio
+        from pinot_tpu.obs.profiler import ProfilerBusy
+        try:
+            out = await asyncio.get_running_loop().run_in_executor(
+                None, fn, *args)
+        except ProfilerBusy as e:
+            return HttpResponse.error(409, str(e))
+        return HttpResponse.of_json(out)
+
+    async def _profiler_status(self, request: HttpRequest) -> HttpResponse:
+        from pinot_tpu.obs.profiler import PROFILER
+        return HttpResponse.of_json(PROFILER.status())
 
     async def _residency(self, request: HttpRequest) -> HttpResponse:
         """The process-global residency ledger: every accounted device

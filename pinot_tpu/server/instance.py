@@ -71,6 +71,9 @@ class ServerInstance:
         from pinot_tpu.utils.device import configure_compile_cache
         configure_compile_cache()
         self.metrics = MetricsRegistry("server")
+        # xlaCompiles / xlaCompile / xlaCompileCacheHits, at 0 from boot
+        from pinot_tpu.obs.profiler import bind_compile_metrics
+        bind_compile_metrics(self.metrics)
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)
         self.data_manager = InstanceDataManager()
@@ -547,13 +550,15 @@ class ServerInstance:
         return fut
 
     def _serialize(self, request: InstanceRequest, dt: DataTable) -> bytes:
+        traced = request.enable_trace and "traceInfo" in dt.metadata
         with self.metrics.timer(
                 ServerQueryPhase.RESPONSE_SERIALIZATION).time():
+            start_us = time.time_ns() // 1000 if traced else 0
             t0 = time.perf_counter()
             payload = dt.to_bytes()
             ser_ms = (time.perf_counter() - t0) * 1e3
         self.metrics.meter(ServerMeter.RESPONSE_BYTES).mark(len(payload))
-        if request.enable_trace and "traceInfo" in dt.metadata:
+        if traced:
             # the serde span cannot ride inside the bytes it measures:
             # amend the trace and re-serialize (trace=true only — the
             # untraced path pays a single to_bytes)
@@ -565,8 +570,8 @@ class ServerInstance:
             if root is not None:
                 info["spans"].append({
                     "name": ServerQueryPhase.RESPONSE_SERIALIZATION,
-                    "ms": round(ser_ms, 3), "spanId": f"{root}.serde",
-                    "parentId": root})
+                    "ms": round(ser_ms, 3), "startUs": start_us,
+                    "spanId": f"{root}.serde", "parentId": root})
                 dt.metadata["traceInfo"] = json.dumps(info)
                 payload = dt.to_bytes()
         return payload

@@ -24,6 +24,16 @@ from pinot_tpu.query.executor import ServerQueryExecutor
 from pinot_tpu.server.data_manager import InstanceDataManager
 
 
+def _trace_annotation(name: str, **attrs):
+    """The server's span annotation: every `TraceContext.span` of a
+    traced query is also a `jax.profiler.TraceAnnotation`, so an open
+    profiler session (`/debug/profiler/*`) shows the spans on the host
+    threads beside `XLA Ops`, on the profiler's own clock. Handed to
+    the context here because `obs/tracing.py` must stay off jax."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **attrs)
+
+
 class InstanceQueryExecutor:
     """Executes InstanceRequests against this server's tables."""
 
@@ -84,11 +94,17 @@ class InstanceQueryExecutor:
         trace = make_trace_context(request.enable_trace,
                                    trace_id=request.trace_id,
                                    parent_span_id=request.parent_span_id,
-                                   root_name="server")
-        if deser_ms:
-            trace.record(ServerQueryPhase.REQUEST_DESERIALIZATION,
-                         deser_ms)
-        trace.record(ServerQueryPhase.SCHEDULER_WAIT, scheduler_wait_ms)
+                                   root_name="server",
+                                   annotate=_trace_annotation)
+        if trace.enabled:
+            # both ended before this context existed: the wait just
+            # now, the decode before the wait began
+            wait = trace.record(ServerQueryPhase.SCHEDULER_WAIT,
+                                scheduler_wait_ms)
+            if deser_ms:
+                trace.record(ServerQueryPhase.REQUEST_DESERIALIZATION,
+                             deser_ms, start_us=wait["startUs"] -
+                             int(deser_ms * 1e3))
         query = request.query
         if query.windows and request.exchange_sources is not None:
             # window stage 2 (coordinator): all data arrives through the

@@ -75,7 +75,7 @@ class _PayloadTooLarge(Exception):
 
 _REASONS = {200: "OK", 204: "No Content", 400: "Bad Request",
             403: "Forbidden", 404: "Not Found", 405: "Method Not Allowed",
-            413: "Payload Too Large", 429: "Too Many Requests",
+            409: "Conflict", 413: "Payload Too Large", 429: "Too Many Requests",
             500: "Internal Server Error", 503: "Service Unavailable"}
 
 

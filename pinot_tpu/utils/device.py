@@ -41,8 +41,9 @@ def configure_compile_cache() -> str:
 
 def device_report() -> dict:
     """Platform, kind and count as JAX reports them, the x64 mode, the
-    compile cache in use, and the backend's own bytes-in-use where it
-    keeps that statistic (the CPU backend does not). Initialises the
+    compile cache in use, and the backend's own bytes-in-use and their
+    peak since the process started where it keeps those statistics
+    (None where it does not: the CPU backend). Initialises the
     backend — only a process that owns the device may call this."""
     import jax
     devices = jax.devices()
@@ -53,5 +54,6 @@ def device_report() -> dict:
         "count": len(devices),
         "x64": bool(jax.config.jax_enable_x64),
         "bytesInUse": stats.get("bytes_in_use"),
+        "peakBytesInUse": stats.get("peak_bytes_in_use"),
         "compileCacheDir": jax.config.jax_compilation_cache_dir,
     }

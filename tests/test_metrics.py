@@ -165,3 +165,82 @@ def test_acl_factory():
     assert isinstance(acl2, TableAclAccessControl)
     with pytest.raises(ValueError):
         AccessControlFactory.create("nope")
+
+
+# -- XLA compile counters (jax.monitoring -> the server's registry) ---------
+
+def test_xla_compile_counters_exist_at_zero_from_boot():
+    server = ServerInstance("server_boot")
+    try:
+        snap = server.metrics.snapshot()
+        assert snap["meter.xlaCompiles.count"] == 0
+        assert snap["meter.xlaCompileCacheHits.count"] == 0
+        assert snap["timer.xlaCompile.count"] == 0
+        assert snap["timer.xlaCompile.totalMs"] == 0
+    finally:
+        server.stop()
+
+
+def test_xla_compiles_grow_by_a_fresh_shapes_programs_then_by_zero(cluster):
+    """`xlaCompiles` counts JAX's backend-compile events: one a program
+    this process meets for the first time, none on its second run."""
+    import jax.monitoring
+    handler, server = cluster
+    seen = []
+
+    def listener(event, duration_secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration_secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        m = server.metrics
+        before = m.meter("xlaCompiles").count
+        # a predicate tree no other test of this process compiles
+        pql = ("SELECT SUM(hits) FROM metricsT WHERE runs > {} AND "
+               "(yearID > 1993 OR salary > 12345.5) AND hits < 240")
+        resp = handler.handle(pql.format(17))
+        assert not resp.exceptions
+        grown = m.meter("xlaCompiles").count - before
+        assert grown == len(seen) >= 1
+        assert m.timer("xlaCompile").count >= grown
+        assert m.timer("xlaCompile").total_ms >= sum(seen[:grown]) * 1e3 \
+            - 1e-6
+        # the same shape with another literal: the same programs
+        resp = handler.handle(pql.format(18))
+        assert not resp.exceptions
+        assert m.meter("xlaCompiles").count - before == grown == len(seen)
+        assert m.meter("xlaCompileCacheHits").count >= 0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+def test_compile_listeners_register_once_and_hold_registries_weakly():
+    import gc
+    import jax.monitoring
+    from jax._src import monitoring as jax_monitoring
+    from pinot_tpu.obs import profiler
+
+    def ours():
+        return [cb for cb in
+                jax_monitoring.get_event_duration_listeners()
+                if cb is profiler._on_compile_duration]
+
+    reg = MetricsRegistry("server")
+    profiler.bind_compile_metrics(reg)
+    profiler.bind_compile_metrics(reg)
+    profiler.bind_compile_metrics(MetricsRegistry("server"))
+    assert len(ours()) == 1
+    assert sum(1 for m in profiler._compile_registries() if m is reg) == 1
+    # the event reaches every live registry, and only its own event
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25)
+    jax.monitoring.record_event_duration_secs("/jax/other", 1.0)
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert reg.meter("xlaCompiles").count == 1
+    assert reg.timer("xlaCompile").total_ms == pytest.approx(250.0)
+    assert reg.meter("xlaCompileCacheHits").count == 1
+    del reg
+    gc.collect()
+    assert all(m.component == "server"
+               for m in profiler._compile_registries())
