@@ -350,6 +350,11 @@ class ServerMeter:
     # cache supplied
     XLA_COMPILES = "xlaCompiles"
     XLA_COMPILE_CACHE_HITS = "xlaCompileCacheHits"
+    # segment lane cache (segment/loader.py DataSource._device, marked
+    # through obs/residency.py): device-lane accesses served by one
+    # look-up, and accesses that built the padded host operand
+    LANE_CACHE_HITS = "laneCacheHits"
+    LANE_CACHE_MISSES = "laneCacheMisses"
 
 
 class ServerTimer:

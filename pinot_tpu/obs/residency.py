@@ -35,7 +35,7 @@ import threading
 import weakref
 from typing import Callable, Dict, List, Optional, Tuple
 
-from pinot_tpu.common.metrics import CommonGauge
+from pinot_tpu.common.metrics import CommonGauge, ServerMeter
 
 #: the accounted upload kinds — also the pre-registered gauge series.
 #: scan: immutable/frozen segment column lanes (ids/vals/raw/mv/parts/
@@ -237,6 +237,11 @@ def ledgered_asarray(host, *, owner: str, table: str, segment: str,
 _BOUND: List["weakref.ref"] = []
 _BOUND_LOCK = threading.Lock()
 _TABLE_GAUGES: set = set()
+#: (laneCacheHits, laneCacheMisses) meters of every live bound registry,
+#: swapped whole by bind_registry so mark_lane_cache reads the tuple
+#: without a lock of its own (Meter.mark takes the meter's); a registry
+#: that died keeps its pair here until the next bind_registry
+_LANE_METERS: tuple = ()
 
 
 def _live_bound() -> List[object]:
@@ -258,21 +263,36 @@ def bind_registry(metrics) -> None:
     ``|<kind>``; obs/prometheus.py splits it back into labels). The
     first scrape therefore already carries `deviceBytesResident` —
     empty-registry exposition was a real PR 5 bug class. Per-table
-    twins (``<table>|<kind>`` suffix) register as uploads appear."""
+    twins (``<table>|<kind>`` suffix) register as uploads appear.
+    The lane cache's two meters exist at 0 from this call on too."""
+    global _LANE_METERS
     metrics.gauge(DEVICE_BYTES_RESIDENT).set_callable(LEDGER.total_bytes)
     for kind in KINDS:
         metrics.gauge(DEVICE_BYTES_RESIDENT,
                       table=f"|{kind}").set_callable(
             lambda k=kind: LEDGER.kind_bytes(k))
     with _BOUND_LOCK:
-        if not any(m is metrics for m in _live_bound()):
+        live = _live_bound()
+        if not any(m is metrics for m in live):
             _BOUND.append(weakref.ref(metrics))
+            live.append(metrics)
         pairs = list(_TABLE_GAUGES)
+        _LANE_METERS = tuple(
+            (m.meter(ServerMeter.LANE_CACHE_HITS),
+             m.meter(ServerMeter.LANE_CACHE_MISSES)) for m in live)
     for table, kind in pairs:
         metrics.gauge(DEVICE_BYTES_RESIDENT,
                       table=f"{table}|{kind}").set_callable(
             lambda t=table, k=kind:
             LEDGER.table_kind_bytes().get((t, k), 0))
+
+
+def mark_lane_cache(hit: bool) -> None:
+    """One segment-lane access (segment/loader.py `_device`) on every
+    bound registry: `laneCacheHits` when the lane cache answered,
+    `laneCacheMisses` when the host operand had to be built."""
+    for hits, misses in _LANE_METERS:
+        (hits if hit else misses).mark()
 
 
 def _ensure_table_gauge(table: str, kind: str) -> None:

@@ -69,8 +69,8 @@ def gather_operands_for(segment, needed_cols) -> Dict[str, object]:
 
 def gather_operands(plan) -> Dict[str, object]:
     """The plan's lanes as device arrays, under an `operandGather`
-    span: the host-side rebuild of each padded operand, the look-up in
-    the lane cache and, on a miss, the upload."""
+    span: one look-up in the lane cache a lane and, only on a miss,
+    the build of its padded host operand and the upload."""
     with obs_span(ServerQueryPhase.OPERAND_GATHER):
         return gather_operands_for(plan.segment, plan.needed_cols)
 

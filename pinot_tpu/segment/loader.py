@@ -12,11 +12,13 @@ from __future__ import annotations
 
 import os
 import threading
+import weakref
 from typing import Dict, Optional
 
 import numpy as np
 
 from pinot_tpu.common.datatype import DataType
+from pinot_tpu.obs import residency
 from pinot_tpu.segment import format as fmt
 from pinot_tpu.segment.bloom import BloomFilter
 from pinot_tpu.segment.dictionary import Dictionary
@@ -186,10 +188,10 @@ class DataSource:
     # -- device access -----------------------------------------------------
     def device_dict_ids(self):
         """Padded int32 dictIds on device; padding = cardinality (invalid)."""
-        return self._device("dict_ids", self.host_operand("ids"))
+        return self._device("dict_ids", "ids")
 
     def device_mv_dict_ids(self):
-        return self._device("mv_dict_ids", self.host_operand("mv"))
+        return self._device("mv_dict_ids", "mv")
 
     def device_dict_values(self):
         """Numeric dictionary values on device (f64/i64 host width preserved
@@ -197,51 +199,51 @@ class DataSource:
         bucket the kernels use for cardinality so compiled executables are
         shared across segments with similar dictionaries; padding slots
         repeat the last value (kernels mask them out)."""
-        return self._device("dict_values", self.host_operand("vals"))
+        return self._device("dict_values", "vals")
 
     def device_raw_values(self):
-        return self._device("raw_values", self.host_operand("raw"))
+        return self._device("raw_values", "raw")
 
     def device_part_lanes(self):
         """Bit-sliced int8 part lanes [n_parts, P] for exact integer sums
         (see kernels.py 'TPU reduction strategy')."""
-        return self._device("part_lanes", self.host_operand("parts"))
+        return self._device("part_lanes", "parts")
 
     def device_value_lane(self):
         """Decoded dictionary-value lane [P] for float sums."""
-        return self._device("value_lane", self.host_operand("vlane"))
+        return self._device("value_lane", "vlane")
 
     def device_vec_values(self):
         """Padded [P, dim_pad] float32 embedding block on device; row
         padding is zeros (masked by the kernel's validity iota), dim
         padding is zeros (an exact no-op in the tree-dot sums)."""
-        return self._device("vec_values", self.host_operand("vec"))
+        return self._device("vec_values", "vec")
 
     def device_ivf_assign(self):
         """Narrow per-row coarse-cell lane [P] (padding rows carry the
         never-probed sentinel id numCentroids)."""
-        return self._device("ivf_assign", self.host_operand("ivfa"))
+        return self._device("ivf_assign", "ivfa")
 
     def device_ivf_centroids(self):
         """Zero-padded codebook [C_pad, dim_pad] f32."""
-        return self._device("ivf_centroids", self.host_operand("ivfc"))
+        return self._device("ivf_centroids", "ivfc")
 
     def device_ivf_valid(self):
         """Centroid liveness [C_pad] bool (live count rides as a lane,
         not a param, so sharded plans stay shareable)."""
-        return self._device("ivf_valid", self.host_operand("ivfv"))
+        return self._device("ivf_valid", "ivfv")
 
     def device_hll_idx(self):
         """Per-dictId HLL register-index table [card_pad] int32 — built
         once from the dictionary values with the SAME hashing the host
         HyperLogLog uses (sketches.hll_tables), so the device register
         kernel is bit-identical to the host sketch by construction."""
-        return self._device("hll_idx", self.host_operand("hllidx"))
+        return self._device("hll_idx", "hllidx")
 
     def device_hll_rank(self):
         """Per-dictId HLL rank table [card_pad] int32 (padding rank 0 =
         the register-max merge identity)."""
-        return self._device("hll_rank", self.host_operand("hllrank"))
+        return self._device("hll_rank", "hllrank")
 
     def int_part_info(self) -> tuple:
         """(n_parts, min_value) for the bit-sliced integer sum encoding.
@@ -324,34 +326,43 @@ class DataSource:
                      "hll_rank": "hll", "ivf_assign": "vector",
                      "ivf_centroids": "vector", "ivf_valid": "vector"}
 
-    def _device(self, key: str, host_array: np.ndarray):
-        if key not in self._dev:
-            import weakref
-            from pinot_tpu.obs import residency
-            seg = self._segment
-            with self._lane_lock:
-                if self._dev_finalizer is None:
-                    # superseded frozen snapshots are freed by GC, not
-                    # destroy() — the finalizer keeps the ledger truthful
-                    # on that path too (release_prefix is idempotent)
-                    self._dev_finalizer = weakref.finalize(
-                        self, residency.LEDGER.release_prefix,
-                        f"ds:{id(self)}:")
-                if key not in self._dev:
-                    self._dev[key] = residency.ledgered_asarray(
-                        host_array,
-                        owner=f"ds:{id(self)}:{key}",
-                        table=seg.metadata.table_name
-                        if seg is not None else "",
-                        segment=seg.segment_name
-                        if seg is not None else "",
-                        kind=self._LEDGER_KINDS.get(key, "scan"))
-        return self._dev[key]
+    def _device(self, key: str, kind: str):
+        """Device lane `key`, from the lane cache: a hit is one look-up
+        and does no host work. Only a miss builds the padded host
+        operand of `kind` (needed once, for the upload; never kept)."""
+        lane = self._dev.get(key)
+        if lane is not None:
+            residency.mark_lane_cache(hit=True)
+            return lane
+        # built OUTSIDE _lane_lock: raw_values, int_part_info and the
+        # HLL tables take that same non-reentrant lock. Published under
+        # it, first published wins: a losing racer drops its operand
+        # and neither uploads nor registers a duplicate
+        host_array = self.host_operand(kind)
+        residency.mark_lane_cache(hit=False)
+        seg = self._segment
+        with self._lane_lock:
+            if self._dev_finalizer is None:
+                # superseded frozen snapshots are freed by GC, not
+                # destroy() — the finalizer keeps the ledger truthful
+                # on that path too (release_prefix is idempotent)
+                self._dev_finalizer = weakref.finalize(
+                    self, residency.LEDGER.release_prefix,
+                    f"ds:{id(self)}:")
+            if key not in self._dev:
+                self._dev[key] = residency.ledgered_asarray(
+                    host_array,
+                    owner=f"ds:{id(self)}:{key}",
+                    table=seg.metadata.table_name
+                    if seg is not None else "",
+                    segment=seg.segment_name
+                    if seg is not None else "",
+                    kind=self._LEDGER_KINDS.get(key, "scan"))
+            return self._dev[key]
 
     def release_device(self) -> None:
         """Drop every device lane and its ledger entries (segment drop/
         eviction path; re-upload after this re-registers)."""
-        from pinot_tpu.obs import residency
         self._dev.clear()
         residency.LEDGER.release_prefix(f"ds:{id(self)}:")
 
@@ -513,12 +524,10 @@ class ImmutableSegment:
         re-uploaded only when the bitmap version changes. Rows past
         num_docs pad False; the kernel ANDs with its row-validity iota
         anyway."""
-        from pinot_tpu.obs import residency
         vd = self.valid_doc_ids
         ver = vd.version
         cached = self._valid_dev
         if cached is None or cached[0] != ver:
-            import weakref
             host = np.zeros(self.padded_docs, dtype=bool)
             host[: self.num_docs] = vd.valid_mask(0, self.num_docs)
             if self._valid_finalizer is None:
@@ -564,7 +573,6 @@ class ImmutableSegment:
         """Drop every device lane (vdoc included) and the ledger
         entries backing them, keeping host arrays intact — the
         device→host demotion step. Re-access re-uploads lazily."""
-        from pinot_tpu.obs import residency
         self._valid_dev = None  # tpulint: disable=concurrency -- the residency manager drains query pins before releasing; worst case a racing reader re-uploads one lane
         residency.LEDGER.release(f"seg:{id(self)}:vdoc")
         for ds in self._data_sources.values():

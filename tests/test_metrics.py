@@ -215,6 +215,45 @@ def test_xla_compiles_grow_by_a_fresh_shapes_programs_then_by_zero(cluster):
         jax.monitoring.unregister_event_duration_listener(listener)
 
 
+# -- lane cache meters (segment/loader.py _device -> obs/residency.py) ------
+
+def test_lane_cache_meters_exist_at_zero_from_boot():
+    server = ServerInstance("server_lanes")
+    try:
+        snap = server.metrics.snapshot()
+        assert snap["meter.laneCacheHits.count"] == 0
+        assert snap["meter.laneCacheMisses.count"] == 0
+    finally:
+        server.stop()
+
+
+def test_lane_cache_meters_count_a_querys_lanes(cluster):
+    """A scan's first run misses each lane once; the same lanes under
+    another literal are all hits, as many as the first run looked up."""
+    handler, server = cluster
+    m = server.metrics
+
+    def counts():
+        return (m.meter("laneCacheHits").count,
+                m.meter("laneCacheMisses").count)
+
+    # a summed column no other test of this module touches
+    pql = "SELECT SUM(salary) FROM metricsT WHERE average > {}"
+    h0, m0 = counts()
+    resp = handler.handle(pql.format(0.25))
+    assert not resp.exceptions
+    h1, m1 = counts()
+    lanes = (h1 - h0) + (m1 - m0)
+    assert m1 - m0 >= 1 and lanes >= 2
+    resp = handler.handle(pql.format(0.5))
+    assert not resp.exceptions
+    h2, m2 = counts()
+    assert m2 == m1 and h2 - h1 == lanes
+    snap = m.snapshot()
+    assert snap["meter.laneCacheHits.count"] == h2
+    assert snap["meter.laneCacheMisses.count"] == m2
+
+
 def test_compile_listeners_register_once_and_hold_registries_weakly():
     import gc
     import jax.monitoring

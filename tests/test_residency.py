@@ -182,6 +182,243 @@ def test_destroy_releases_every_ledgered_lane(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the lane cache (DataSource._device): a hit is one look-up and no host
+# work; only a miss builds the padded operand, uploads and registers
+# ---------------------------------------------------------------------------
+
+#: operand kind -> (accessor, residency ledger kind)
+LANE_KINDS = {
+    "ids": ("device_dict_ids", "scan"),
+    "mv": ("device_mv_dict_ids", "scan"),
+    "vals": ("device_dict_values", "scan"),
+    "raw": ("device_raw_values", "scan"),
+    "parts": ("device_part_lanes", "scan"),
+    "vlane": ("device_value_lane", "scan"),
+    "vec": ("device_vec_values", "vector"),
+    "ivfa": ("device_ivf_assign", "vector"),
+    "ivfc": ("device_ivf_centroids", "vector"),
+    "ivfv": ("device_ivf_valid", "vector"),
+    "hllidx": ("device_hll_idx", "hll"),
+    "hllrank": ("device_hll_rank", "hll"),
+}
+
+
+class _LazyChunks:
+    """Stands in for a chunked raw reader: `raw_values` decodes it on
+    first use, under the DataSource's lane lock."""
+
+    def __init__(self, values):
+        self._values = values
+        self.decodes = 0
+
+    def decode_all(self):
+        import time
+        self.decodes += 1
+        time.sleep(0.01)
+        return self._values
+
+
+def _lane_source(kind, n=3000, lazy_raw=False):
+    """A DataSource that serves `kind`, built by hand the way the
+    loader builds one (ds._segment None: unnamed ledger entries)."""
+    import numpy as np
+    from pinot_tpu.common.datatype import DataType
+    from pinot_tpu.segment.dictionary import Dictionary
+    from pinot_tpu.segment.loader import DataSource
+    from pinot_tpu.segment.metadata import ColumnMetadata
+    rng = np.random.default_rng(7)
+    if kind == "raw":
+        cm = ColumnMetadata("c", DataType.INT, n, 32, has_dictionary=False,
+                            total_number_of_entries=n)
+        ds = DataSource(cm, None)
+        values = rng.integers(0, 1 << 20, n).astype(np.int32)
+        if lazy_raw:
+            ds.raw_chunks = _LazyChunks(values)
+        else:
+            ds.raw_values = values
+    elif kind == "mv":
+        cm = ColumnMetadata("c", DataType.INT, 40, 6, single_value=False,
+                            total_number_of_entries=n)
+        ds = DataSource(cm, None)
+        ds.dictionary = Dictionary(DataType.INT, np.arange(40, dtype=np.int32))
+        ds.mv_dict_ids = rng.integers(0, 41, (n, 3)).astype(np.int32)
+    elif kind in ("vec", "ivfa", "ivfc", "ivfv"):
+        cm = ColumnMetadata("c", DataType.VECTOR, n, 32, has_dictionary=False,
+                            total_number_of_entries=n, vector_dimension=6)
+        ds = DataSource(cm, None)
+        ds.vec_values = rng.random((n, 6)).astype(np.float32)
+        ds.ivf_centroids = rng.random((5, 6)).astype(np.float32)
+        ds.ivf_assignments = rng.integers(0, 5, n).astype(np.int32)
+    else:
+        values = np.unique(rng.integers(-500, 70_000, 900)).astype(np.int32)
+        cm = ColumnMetadata("c", DataType.INT, len(values), 10,
+                            total_number_of_entries=n)
+        ds = DataSource(cm, None)
+        ds.dictionary = Dictionary(DataType.INT, values)
+        ds.dict_ids = rng.integers(0, len(values), n).astype(np.int32)
+    return ds
+
+
+def _count_host_operands(ds, monkeypatch):
+    """Every kind `host_operand` is entered with, in order."""
+    calls, real = [], ds.host_operand
+
+    def counted(kind):
+        calls.append(kind)
+        return real(kind)
+
+    monkeypatch.setattr(ds, "host_operand", counted)
+    return calls
+
+
+def _lane_entries(ds):
+    snap = LEDGER.snapshot(max_entries=1_000_000)
+    return [e for e in snap["entries"]
+            if e["owner"].startswith(f"ds:{id(ds)}:")]
+
+
+@pytest.mark.parametrize("kind", LANE_KINDS)
+def test_cold_lane_builds_uploads_and_registers_exactly_once(
+        kind, monkeypatch):
+    import numpy as np
+    ds = _lane_source(kind)
+    accessor, ledger_kind = LANE_KINDS[kind]
+    want = ds.host_operand(kind)
+    calls = _count_host_operands(ds, monkeypatch)
+    try:
+        assert _lane_entries(ds) == []
+        lane = getattr(ds, accessor)()
+        assert calls.count(kind) == 1
+        # the miss path's padding and dtype are host_operand's own
+        assert lane.shape == want.shape and lane.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(lane), want)
+        entry, = _lane_entries(ds)
+        assert entry["bytes"] == int(lane.nbytes) == want.nbytes
+        assert entry["kind"] == ledger_kind
+        assert list(ds._dev.values()) == [lane]
+    finally:
+        ds.release_device()
+    assert _lane_entries(ds) == []
+
+
+@pytest.mark.parametrize("kind", LANE_KINDS)
+def test_warm_lane_hit_is_the_same_array_and_never_builds_a_host_operand(
+        kind, monkeypatch):
+    ds = _lane_source(kind)
+    accessor = getattr(ds, LANE_KINDS[kind][0])
+    try:
+        lane = accessor()
+        calls = _count_host_operands(ds, monkeypatch)
+        assert accessor() is lane and accessor() is lane
+        assert calls == []
+        assert len(_lane_entries(ds)) == 1
+        # the host rows gone to the disk tier: a hit needs none of them
+        ds.release_host()
+        assert accessor() is lane
+        assert calls == []
+    finally:
+        ds.release_device()
+
+
+@pytest.mark.parametrize("kind", LANE_KINDS)
+def test_release_device_then_access_rebuilds_and_reregisters(
+        kind, monkeypatch):
+    ds = _lane_source(kind)
+    accessor = getattr(ds, LANE_KINDS[kind][0])
+    try:
+        first = accessor()
+        nbytes = int(first.nbytes)
+        ds.release_device()
+        assert ds._dev == {} and _lane_entries(ds) == []
+        calls = _count_host_operands(ds, monkeypatch)
+        again = accessor()
+        assert again is not first and calls.count(kind) == 1
+        assert [e["bytes"] for e in _lane_entries(ds)] == [nbytes]
+        assert accessor() is again and calls.count(kind) == 1
+    finally:
+        ds.release_device()
+
+
+@pytest.mark.parametrize("kind", ["raw", "parts", "hllidx"])
+def test_eight_cold_racers_agree_on_one_lane_without_deadlock(kind):
+    """`raw` over a lazily decoded reader, `parts` (int_part_info) and
+    the HLL tables all take `_lane_lock` while the host operand is
+    built: building it inside that lock would hang every racer."""
+    import sys
+    import threading
+    ds = _lane_source(kind, lazy_raw=True)
+    accessor = getattr(ds, LANE_KINDS[kind][0])
+    n_threads = 8
+    barrier = threading.Barrier(n_threads)
+    got, errors = [], []
+
+    def racer():
+        try:
+            barrier.wait(timeout=30)
+            for _ in range(3):
+                got.append(accessor())
+        except Exception as e:          # surfaced by the assert below
+            errors.append(e)
+
+    threads = [threading.Thread(target=racer, daemon=True)
+               for _ in range(n_threads)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    try:
+        assert not any(t.is_alive() for t in threads), "deadlock"
+        assert errors == []
+        assert len(got) == 3 * n_threads
+        assert all(lane is got[0] for lane in got)
+        # the losers dropped their copies unregistered
+        assert [e["bytes"] for e in _lane_entries(ds)] == \
+            [int(got[0].nbytes)]
+        assert list(ds._dev.values()) == [got[0]]
+        if kind == "raw":
+            assert ds.raw_chunks.decodes == 1
+    finally:
+        ds.release_device()
+
+
+def test_lane_cache_meters_bound_at_zero_and_marked_on_live_registries():
+    import gc
+    from pinot_tpu.common.metrics import MetricsRegistry
+    reg, other = MetricsRegistry("server"), MetricsRegistry("server")
+    residency.bind_registry(reg)
+    residency.bind_registry(reg)
+    residency.bind_registry(other)
+    for r in (reg, other):
+        snap = r.snapshot()
+        assert snap["meter.laneCacheHits.count"] == 0
+        assert snap["meter.laneCacheMisses.count"] == 0
+    ds = _lane_source("ids")
+    try:
+        ds.device_dict_ids()
+        ds.device_dict_ids()
+        ds.device_dict_ids()
+    finally:
+        ds.release_device()
+    for r in (reg, other):          # bound twice, marked once a lane
+        assert r.meter("laneCacheMisses").count == 1
+        assert r.meter("laneCacheHits").count == 2
+    # registries are held weakly: a dead one's meters leave at the
+    # next bind
+    dead = id(other.meter("laneCacheHits"))
+    del other, r
+    gc.collect()
+    residency.bind_registry(reg)
+    assert dead not in {id(hits) for hits, _ in residency._LANE_METERS}
+    assert any(hits is reg.meter("laneCacheHits")
+               for hits, _ in residency._LANE_METERS)
+
+
+# ---------------------------------------------------------------------------
 # exchange budget regression: publish -> overflow -> sweep -> zero
 # ---------------------------------------------------------------------------
 
