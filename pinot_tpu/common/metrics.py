@@ -355,6 +355,11 @@ class ServerMeter:
     # look-up, and accesses that built the padded host operand
     LANE_CACHE_HITS = "laneCacheHits"
     LANE_CACHE_MISSES = "laneCacheMisses"
+    # star-tree executor (startree/executor.py, marked through
+    # obs/profiler.py): segments a cube answered by the one native
+    # select-and-gather call, and by the stepwise numpy twin
+    CUBE_DESCENTS_NATIVE = "cubeDescentsNative"
+    CUBE_DESCENTS_NUMPY = "cubeDescentsNumpy"
 
 
 class ServerTimer:

@@ -62,6 +62,13 @@ def lib() -> Optional[ctypes.CDLL]:
         return _LIB
 
 
+def loaded() -> Optional[ctypes.CDLL]:
+    """The library if `lib()` has already built and bound it, else None.
+    For callers on a query path, which must never compile: the library
+    is built where cubes are loaded (startree/cube.py)."""
+    return _LIB
+
+
 def _bind(cdll: ctypes.CDLL) -> None:
     i64, i32, u32, f64, vp = (ctypes.c_int64, ctypes.c_int32,
                               ctypes.c_uint32, ctypes.c_double,
@@ -75,6 +82,9 @@ def _bind(cdll: ctypes.CDLL) -> None:
     cdll.group_stats_sorted_f64.argtypes = [vp, vp, i64, i64, vp, vp, vp,
                                             vp]
     cdll.packed_key_i64.argtypes = [vp, vp, ctypes.c_int, i64, vp]
+    cdll.cube_select_gather.restype = i64
+    cdll.cube_select_gather.argtypes = [i64, vp, vp, vp, vp, i64, vp, i64,
+                                        i64, i64, vp, vp, vp, vp]
 
 
 def _ptr(a: np.ndarray):
@@ -182,3 +192,44 @@ def packed_key(dims, cards) -> Optional[np.ndarray]:
     out = np.empty(n, np.int64)
     L.packed_key_i64(ptrs, _ptr(cards64), len(arrs), n, _ptr(out))
     return out
+
+
+def cube_select_gather(tables, n_gcols: int, n_stats: int, cap: int,
+                       block_limit: int):
+    """One query's cube descent over all its segments in one foreign
+    call. `tables` are seglib.cpp's five (seg_hdr, preds, ivs, gcols,
+    stats), each a flat list of Python ints; the lanes behind the
+    addresses in them are kept alive by the caller. -> (codes [G, m],
+    counts [m], stat lanes [K, m], [matched, examined] a segment), or
+    None where the stepwise numpy path has to answer: no library
+    loaded, a level past `block_limit`, a lane id outside its table.
+    `cap` is a guess at the rows the blocks hold; a short one costs a
+    second call at the exact size."""
+    L = loaded()
+    if L is None:
+        return None
+    n_seg = len(tables[0]) // 4
+    flat = np.array([v for t in tables for v in t], dtype=np.int64)
+    addr, at = [], flat.ctypes.data
+    for t in tables:
+        addr.append(at)
+        at += 8 * len(t)
+    while True:
+        # one buffer for what comes back as int64: codes, counts, and
+        # each segment's matched and examined
+        ints = np.empty((n_gcols + 1) * cap + 2 * n_seg, np.int64)
+        lanes = np.empty((n_stats, cap), np.float64)
+        out = ints.ctypes.data
+        per_seg = ints[(n_gcols + 1) * cap:]
+        m = L.cube_select_gather(
+            n_seg, addr[0], addr[1], addr[2], addr[3], n_gcols, addr[4],
+            n_stats, block_limit, cap, out, out + 8 * n_gcols * cap,
+            _ptr(lanes), out + 8 * (n_gcols + 1) * cap)
+        if m != -2:
+            break
+        cap = sum(per_seg.tolist()[1::2])
+    if m < 0:
+        return None
+    codes = ints[:n_gcols * cap].reshape(n_gcols, cap)
+    return (codes[:, :m], ints[n_gcols * cap:n_gcols * cap + m],
+            lanes[:, :m], per_seg.tolist())

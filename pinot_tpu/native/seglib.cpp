@@ -174,4 +174,127 @@ void packed_key_i64(const int32_t* const* dims, const int64_t* cards,
     }
 }
 
+// ---------------------------------------------------------------------------
+// cube_select_gather: one query's star-tree descent over S segments, the
+// one entry point here on the QUERY path (startree/executor.py). Cube rows
+// are sorted by the split order, so the leading dimensions' dictId
+// intervals narrow to row blocks by binary search, level after level; the
+// rows of the surviving blocks are tested against the residual leaves
+// (interval lists over their own dimension lanes) and the matched rows'
+// group codes, counts and stat lanes are written out segment-major, rows
+// ascending: what the numpy twin's gathers and concatenations yield.
+// Literal semantics stay in Python: this sees ids and intervals only.
+//
+// All tables are int64, pointers included:
+//   seg_hdr [S][4]     n_groups, counts*, n_levels, n_resid
+//   preds   [P][3]     lane* (int32), first and one-past-last row of `ivs`;
+//                      segment-major, a segment's levels then its residuals
+//   ivs     [I][2]     dictId interval [lo, hi)
+//   gcols   [S][G][3]  lane* (int32), lut* (int64 local id -> group code;
+//                      0 = the id itself), lut length
+//   stats   [S][K]     stat lane* (float64)
+//   out_codes [G][cap], out_counts [cap], out_stats [K][cap],
+//   out_seg [S][2]     rows matched, rows examined
+// Returns the rows written; -1 where a level would pass block_limit (the
+// stepwise twin stops descending there and scans); -2 where the blocks hold
+// more than `cap` rows (out_seg then carries every segment's examined
+// count, nothing else is written); -3 on a lane id outside its lut or an
+// allocation failure.
+// ---------------------------------------------------------------------------
+static inline bool in_intervals(int64_t id, const int64_t* ivs,
+                                int64_t first, int64_t last) {
+    for (int64_t i = first; i < last; ++i)
+        if (id >= ivs[2 * i] && id < ivs[2 * i + 1]) return true;
+    return false;
+}
+
+int64_t cube_select_gather(
+        int64_t n_seg, const int64_t* seg_hdr, const int64_t* preds,
+        const int64_t* ivs, const int64_t* gcols, int64_t n_gcols,
+        const int64_t* stats, int64_t n_stats, int64_t block_limit,
+        int64_t cap, int64_t* out_codes, int64_t* out_counts,
+        double* out_stats, int64_t* out_seg) {
+    typedef std::pair<int64_t, int64_t> Block;
+    try {
+        // pass 1: the descent — every segment's row blocks
+        std::vector<std::vector<Block>> seg_blocks((size_t)n_seg);
+        std::vector<int64_t> first_pred((size_t)n_seg);
+        std::vector<Block> next;
+        int64_t p = 0, examined_all = 0;
+        for (int64_t s = 0; s < n_seg; ++s) {
+            const int64_t* h = seg_hdr + 4 * s;
+            std::vector<Block>& blocks = seg_blocks[s];
+            blocks.push_back(Block(0, h[0]));
+            first_pred[s] = p;
+            for (int64_t l = 0; l < h[2] && !blocks.empty(); ++l) {
+                const int64_t* pr = preds + 3 * (p + l);
+                const int32_t* lane = (const int32_t*)(intptr_t)pr[0];
+                int64_t n_iv = pr[2] - pr[1];
+                if ((int64_t)blocks.size() * std::max<int64_t>(n_iv, 1)
+                        > block_limit)
+                    return -1;
+                next.clear();
+                for (const Block& b : blocks)
+                    for (int64_t i = pr[1]; i < pr[2]; ++i) {
+                        const int32_t* lo = std::lower_bound(
+                            lane + b.first, lane + b.second, ivs[2 * i],
+                            [](int32_t v, int64_t k) { return v < k; });
+                        const int32_t* hi = std::lower_bound(
+                            lo, lane + b.second, ivs[2 * i + 1],
+                            [](int32_t v, int64_t k) { return v < k; });
+                        if (lo < hi) next.push_back(Block(lo - lane,
+                                                          hi - lane));
+                    }
+                blocks.swap(next);
+            }
+            p += h[2] + h[3];
+            int64_t examined = 0;
+            for (const Block& b : blocks) examined += b.second - b.first;
+            out_seg[2 * s] = 0;
+            out_seg[2 * s + 1] = examined;
+            examined_all += examined;
+        }
+        if (examined_all > cap) return -2;
+
+        // pass 2: residual leaves, then the gather of what matched
+        int64_t m = 0;
+        for (int64_t s = 0; s < n_seg; ++s) {
+            const int64_t* h = seg_hdr + 4 * s;
+            const int64_t* counts = (const int64_t*)(intptr_t)h[1];
+            const int64_t* resid = preds + 3 * (first_pred[s] + h[2]);
+            const int64_t* gc = gcols + 3 * n_gcols * s;
+            const int64_t* st = stats + n_stats * s;
+            const int64_t m0 = m;
+            for (const Block& b : seg_blocks[s])
+                for (int64_t r = b.first; r < b.second; ++r) {
+                    bool keep = true;
+                    for (int64_t q = 0; q < h[3] && keep; ++q) {
+                        const int64_t* pr = resid + 3 * q;
+                        keep = in_intervals(
+                            ((const int32_t*)(intptr_t)pr[0])[r], ivs,
+                            pr[1], pr[2]);
+                    }
+                    if (!keep) continue;
+                    for (int64_t g = 0; g < n_gcols; ++g) {
+                        int64_t id =
+                            ((const int32_t*)(intptr_t)gc[3 * g])[r];
+                        if (gc[3 * g + 1]) {
+                            if (id < 0 || id >= gc[3 * g + 2]) return -3;
+                            id = ((const int64_t*)(intptr_t)
+                                  gc[3 * g + 1])[id];
+                        }
+                        out_codes[g * cap + m] = id;
+                    }
+                    out_counts[m] = counts[r];
+                    for (int64_t k = 0; k < n_stats; ++k)
+                        out_stats[k * cap + m] =
+                            ((const double*)(intptr_t)st[k])[r];
+                    ++m;
+                }
+            out_seg[2 * s] = m - m0;
+        }
+        return m;
+    } catch (...) { return -3; }
+}
+
 }  // extern "C"

@@ -302,6 +302,35 @@ def bind_compile_metrics(metrics) -> None:
     jax.monitoring.register_event_listener(_on_compile_event)
 
 
+# -- cube descent counters --------------------------------------------------
+
+#: (cubeDescentsNative, cubeDescentsNumpy) meters of every registry
+#: bound, swapped whole by bind_cube_metrics so that a descent marks
+#: without a lock of its own (Meter.mark takes the meter's), as the
+#: residency ledger's lane-cache meters are
+_cube_bound: "weakref.WeakSet" = weakref.WeakSet()
+_CUBE_METERS: tuple = ()
+
+
+def bind_cube_metrics(metrics) -> None:
+    """Meters `cubeDescentsNative` / `cubeDescentsNumpy` on `metrics`,
+    at 0 from this call on."""
+    global _CUBE_METERS
+    with _compile_lock:
+        _cube_bound.add(metrics)
+        _CUBE_METERS = tuple(
+            (m.meter(ServerMeter.CUBE_DESCENTS_NATIVE),
+             m.meter(ServerMeter.CUBE_DESCENTS_NUMPY)) for m in _cube_bound)
+
+
+def mark_cube_descents(native: bool, segments: int) -> None:
+    """`segments` segments answered from their cubes
+    (startree/executor.py `_cube_execute`): by the one native
+    select-and-gather call, or by the stepwise numpy twin."""
+    for by_native, by_numpy in _CUBE_METERS:
+        (by_native if native else by_numpy).mark(segments)
+
+
 # -- the device profiler, one session at a time ------------------------------
 
 PROFILER_ANCHOR = "pinot.profilerAnchor"

@@ -96,3 +96,6 @@ class IntermediateResultsBlock:
     # combine) or "sequential" (per-segment + host merge); None when the
     # block came from a layer that doesn't choose (e.g. per-segment)
     execution_path: Optional[str] = None
+    # star-tree blocks: whether the one native select-and-gather call
+    # answered (False: the stepwise numpy twin); None for any other block
+    cube_native: Optional[bool] = None

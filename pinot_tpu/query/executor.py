@@ -427,6 +427,8 @@ class ServerQueryExecutor:
                 blk = try_star_tree_execute(segment, request)
                 if span is not None:
                     span["attrs"]["hit"] = blk is not None
+                    if blk is not None:
+                        span["attrs"]["native"] = blk.cube_native
             if blk is not None:
                 obs_profiler.count_path("cube")
                 return blk
@@ -448,6 +450,8 @@ class ServerQueryExecutor:
                 blk = try_star_tree_execute_multi(selected, request)
                 if span is not None:
                     span["attrs"]["hit"] = blk is not None
+                    if blk is not None:
+                        span["attrs"]["native"] = blk.cube_native
             if blk is not None:
                 obs_profiler.count_path("cube", len(selected))
                 return blk

@@ -74,6 +74,9 @@ class ServerInstance:
         # xlaCompiles / xlaCompile / xlaCompileCacheHits, at 0 from boot
         from pinot_tpu.obs.profiler import bind_compile_metrics
         bind_compile_metrics(self.metrics)
+        # cubeDescentsNative / cubeDescentsNumpy, at 0 from boot
+        from pinot_tpu.obs.profiler import bind_cube_metrics
+        bind_cube_metrics(self.metrics)
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)
         self.data_manager = InstanceDataManager()

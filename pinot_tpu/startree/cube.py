@@ -289,4 +289,10 @@ def load_star_trees(seg_dir) -> List[StarTreeCube]:
             logging.getLogger(__name__).warning(
                 "skipping unloadable star-tree cube %d in %s", idx,
                 d.path, exc_info=True)
+    if cubes:
+        # the descent's native call (startree/executor.py) is built here,
+        # with the cubes it reads, and never inside a query: lib()
+        # compiles under a lock on first use
+        from pinot_tpu import native
+        native.lib()
     return cubes

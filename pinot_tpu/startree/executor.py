@@ -26,10 +26,15 @@ import numpy as np
 from pinot_tpu.common import expression as expr_mod
 from pinot_tpu.common.request import (BrokerRequest, FilterOperator,
                                       FilterQueryTree)
+from pinot_tpu.obs import profiler as obs_profiler
 from pinot_tpu.query.aggregation import make_functions
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
 
-_COVERED_BASES = {"COUNT", "SUM", "AVG", "MIN", "MAX", "MINMAXRANGE"}
+# covered aggregation base -> the cube stat lanes it reads (COUNT reads
+# the counts alone)
+_STAT_KINDS = {"SUM": ("sum",), "AVG": ("sum",), "MIN": ("min",),
+               "MAX": ("max",), "MINMAXRANGE": ("min", "max")}
+_COVERED_BASES = {"COUNT", *_STAT_KINDS}
 
 # stop expanding prefix blocks past this fan-out: the residual scan over
 # a bounded union of blocks is cheaper than deep enumeration
@@ -67,23 +72,21 @@ class _CubeView:
                                self._cube.dim_ids[col])
 
 
-def _eligible_cube(segment, request: BrokerRequest, functions):
-    """Pick the first cube covering the query, or None.
-
-    Coverage: filter + group columns ⊆ dimensions (expressions allowed in
-    filters when their source columns are dimensions); aggregations are
-    COUNT(*) or covered-base functions over cube metrics.
-    """
-    cubes = getattr(segment, "star_trees", None)
-    if not cubes or not request.is_aggregation or request.is_selection:
+def _query_needs(request: BrokerRequest, functions
+                 ) -> Optional[Tuple[set, set]]:
+    """(dimensions, metrics) a covering cube must hold, or None where
+    no cube can answer. Coverage: filter + group columns ⊆ dimensions
+    (expressions allowed in filters when their source columns are
+    dimensions); aggregations are COUNT(*) or covered-base functions
+    over cube metrics."""
+    if not request.is_aggregation or request.is_selection:
         return None
     if request.query_options.options.get("useStarTree") == "false":
         return None
     needed_dims = set()
     for c in request.filter_columns():
         needed_dims.update(expr_mod.referenced_columns(c))
-    group_cols = list(request.group_by.columns) if request.group_by else []
-    for c in group_cols:
+    for c in (request.group_by.columns if request.group_by else ()):
         if expr_mod.is_expression(c):
             return None                       # group keys must be plain dims
         needed_dims.add(c)
@@ -98,56 +101,66 @@ def _eligible_cube(segment, request: BrokerRequest, functions):
         if expr_mod.is_expression(f.column):
             return None
         needed_metrics.add(f.column)
+    return needed_dims, needed_metrics
+
+
+def _eligible_cube(segment, needs, leaves: "_QueryLeaves", si: int):
+    """(cube, descent levels, est. fraction of its rows left) of the
+    best cube of segment `si` covering the query, or None."""
     best = None
     best_score = None
-    leaves = _conjunctive_leaves(request.filter)
-    for cube in cubes:
-        if not (needed_dims <= set(cube.dimensions) and
-                needed_metrics <= set(cube.metrics)):
+    for cube in getattr(segment, "star_trees", None) or ():
+        if not (needs[0] <= set(cube.dimensions) and
+                needs[1] <= set(cube.metrics)):
             continue
-        score, frac = _prefix_narrowing(segment, cube, leaves)
+        levels = _descent_levels(cube, leaves, si)
+        frac = _prefix_fraction(segment, levels)
         if cube.n_groups * frac * 8 > segment.num_docs:
             # a cube nearly as tall as the segment must be narrowed to a
             # genuinely small block before it beats the doc-scale kernel:
             # a prefix "hit" from one wide RANGE on the leading dim (e.g.
             # dim >= 'A') would otherwise degrade to a near-full host scan
             continue
-        key = (score, -cube.n_groups * frac)
+        key = (len(levels), -cube.n_groups * frac)
         if best is None or key > best_score:
-            best, best_score = cube, key
+            best, best_score = (cube, levels, frac), key
     return best
 
 
-def _prefix_narrowing(segment, cube, leaves) -> Tuple[int, float]:
-    """(depth, est fraction): how many leading split dims a conjunctive
-    filter narrows, and the estimated fraction of cube rows left after the
-    descent (product of per-dim dictId coverage under a uniform-ids
-    assumption). Depth ranks cube choice; the fraction gates eligibility so
-    a wide RANGE on the leading dim doesn't count as real narrowing."""
-    if not leaves:
-        return 0, 1.0
-    by_col = {}
-    for lf in leaves:
-        by_col.setdefault(lf.column, []).append(lf)
-    score = 0
-    frac = 1.0
+def _descent_levels(cube, leaves: "_QueryLeaves", si: int
+                    ) -> List[Tuple[str, FilterQueryTree, list]]:
+    """(dimension, leaf, dictId intervals) of the leading split
+    dimensions a conjunctive filter narrows in segment `si`: a level for
+    each dimension in split order that some leaf resolves, ending with
+    the first level that holds an interval wider than one id (rows
+    inside a multi-id block aren't sorted by deeper dims). Cube choice,
+    the stepwise descent and the native call all read this."""
+    levels = []
     for dim in cube.dimensions:
-        ivs = None
-        ds = segment.data_source(dim)
-        for lf in by_col.get(dim, ()):
-            ivs = _leaf_id_intervals(lf, ds)
+        for lf in leaves.by_col.get(dim, ()):
+            ivs = leaves.intervals(lf, si)
             if ivs is not None:
                 break
-        if ivs is None:
-            break
-        score += 1
-        card = max(1, len(ds.dictionary)) if ds.dictionary is not None \
-            else 1
-        covered = sum(b - a for a, b in ivs)
-        frac *= min(1.0, covered / card)
+        else:
+            break                       # unconstrained dim: stop descent
+        levels.append((dim, lf, ivs))
         if not all(b - a == 1 for a, b in ivs):
-            break                       # descent stops after an interval
-    return score, frac
+            break
+    return levels
+
+
+def _prefix_fraction(segment, levels) -> float:
+    """Estimated fraction of cube rows left after the descent (product
+    of per-dim dictId coverage under a uniform-ids assumption). The
+    depth, len(levels), ranks cube choice; the fraction gates
+    eligibility so a wide RANGE on the leading dim doesn't count as real
+    narrowing."""
+    frac = 1.0
+    for dim, _, ivs in levels:
+        d = segment.data_source(dim).dictionary
+        card = max(1, len(d)) if d is not None else 1
+        frac *= min(1.0, sum(b - a for a, b in ivs) / card)
+    return frac
 
 
 def _conjunctive_leaves(tree: Optional[FilterQueryTree]
@@ -169,14 +182,14 @@ def _conjunctive_leaves(tree: Optional[FilterQueryTree]
     return out
 
 
-def _leaf_id_intervals(leaf: FilterQueryTree, ds
+def _leaf_id_intervals(leaf: FilterQueryTree, d
                        ) -> Optional[List[Tuple[int, int]]]:
-    """Sorted-dictionary dictId intervals [a, b) equivalent to the leaf,
-    or None when the leaf can't narrow a sorted cube lane (NOT/NOT_IN/
-    REGEXP, expression columns, unsorted mutable dictionaries)."""
+    """Sorted-dictionary dictId intervals [a, b) of dictionary `d`
+    equivalent to the leaf, or None when the leaf can't narrow a sorted
+    cube lane (NOT/NOT_IN/REGEXP, expression columns, unsorted mutable
+    dictionaries)."""
     if expr_mod.is_expression(leaf.column):
         return None
-    d = ds.dictionary
     if d is None or not getattr(d, "is_sorted", True):
         return None
     op = leaf.operator
@@ -194,34 +207,70 @@ def _leaf_id_intervals(leaf: FilterQueryTree, ds
     return None
 
 
-def _prefix_select(segment, cube, leaves: List[FilterQueryTree]
+class _QueryLeaves:
+    """One query's conjunctive filter leaves as dictId intervals of each
+    of its segments, resolved once (on first demand: a query no cube
+    covers resolves nothing) and read by cube choice and descent alike.
+    Over several segments the literal is searched once, in the union of
+    their dictionaries, and a segment's interval is two look-ups in the
+    union's rank table."""
+
+    def __init__(self, segments, tree: Optional[FilterQueryTree]):
+        self.segments = segments
+        self.leaves = _conjunctive_leaves(tree)   # None: the tree has an OR
+        # the three maps live as long as the query: a key a filter leaf
+        # or a column of it
+        self.by_col: Dict[str, List[FilterQueryTree]] = {}  # tpulint: disable=cache-bound -- one query's object: bounded by its filter's leaves
+        for lf in self.leaves or ():
+            self.by_col.setdefault(lf.column, []).append(lf)
+        self._tables: Dict[str, "_UnionTables"] = {}  # tpulint: disable=cache-bound -- one query's object: bounded by its filter and group columns
+        self._intervals: Dict[int, Optional[list]] = {}  # tpulint: disable=cache-bound -- one query's object: bounded by its filter's leaves
+
+    def tables(self, col: str) -> "_UnionTables":
+        t = self._tables.get(col)
+        if t is None:
+            t = self._tables[col] = _union_tables(self.segments, col)
+        return t
+
+    def intervals(self, leaf: FilterQueryTree, si: int
+                  ) -> Optional[List[Tuple[int, int]]]:
+        """The leaf as intervals of segment `si`'s own dictIds; None
+        where `_leaf_id_intervals` says it is none."""
+        key = id(leaf)
+        if key not in self._intervals:
+            self._intervals[key] = self._resolve(leaf)
+        per_seg = self._intervals[key]
+        return None if per_seg is None else per_seg[si]
+
+    def _resolve(self, leaf: FilterQueryTree) -> Optional[list]:
+        if expr_mod.is_expression(leaf.column):
+            return None
+        t = self.tables(leaf.column)
+        uivs = _leaf_id_intervals(leaf, t.dictionary)
+        if uivs is None:
+            return None
+        if t.ranks is None:               # one segment: its own ids
+            return [uivs]
+        ranks = t.ranks
+        return [[(ranks[si, a], ranks[si, b]) for a, b in uivs
+                 if ranks[si, a] < ranks[si, b]]
+                for si in range(len(self.segments))]
+
+
+def _prefix_select(segment, cube, leaves: List[FilterQueryTree], levels
                    ) -> Optional[Tuple[np.ndarray, int]]:
     """(selected row indices, rows examined) via sorted-prefix descent,
     or None when the leading split dimension is unconstrained (full scan
     is then the only option). Parity: StarTreeFilterOperator's
     depth-first child matching over OffHeapStarTreeNode, done as binary
     searches on the sorted dim lanes."""
-    by_col: Dict[str, List[FilterQueryTree]] = {}
-    for lf in leaves:
-        by_col.setdefault(lf.column, []).append(lf)
-
     blocks: List[Tuple[int, int]] = [(0, cube.n_groups)]
     consumed: set = set()
-    narrowed = False
-    for dim in cube.dimensions:
-        ivs = None
-        src = None
-        for lf in by_col.get(dim, ()):
-            ivs = _leaf_id_intervals(lf, segment.data_source(dim))
-            if ivs is not None:
-                src = lf
-                break
-        if ivs is None:
-            break                       # unconstrained dim: stop descent
-        lane = cube.dim_ids[dim]
-        new_blocks: List[Tuple[int, int]] = []
+    for dim, src, ivs in levels:
         if len(blocks) * max(len(ivs), 1) > _PREFIX_BLOCK_LIMIT:
             break
+        lane = cube.dim_ids[dim]
+        new_blocks: List[Tuple[int, int]] = []
         dt = lane.dtype.type          # dim lanes are int32; ids fit
         for lo, hi in blocks:
             seg_lane = lane[lo:hi]
@@ -234,13 +283,9 @@ def _prefix_select(segment, cube, leaves: List[FilterQueryTree]
                     new_blocks.append((s, e))
         blocks = new_blocks
         consumed.add(id(src))
-        narrowed = True
         if not blocks:
             break
-        if not all(b - a == 1 for a, b in ivs):
-            # rows inside a multi-id block aren't sorted by deeper dims
-            break
-    if not narrowed:
+    if not consumed:
         return None
 
     sel = (np.concatenate([np.arange(lo, hi, dtype=np.int64)
@@ -276,15 +321,15 @@ class _SlicedCubeView:
                                self._cube.dim_ids[col][self._sel])
 
 
-def _cube_select(segment, cube, tree: Optional[FilterQueryTree]
+def _cube_select(segment, cube, tree: Optional[FilterQueryTree],
+                 leaves: Optional[List[FilterQueryTree]], levels
                  ) -> Tuple[np.ndarray, int]:
     """Selected cube row indices + rows-examined. Prefix descent when
     the filter is conjunctive and constrains the leading split dims;
     full member-gather scan otherwise. Raises for predicates the host
     evaluator can't resolve (callers fall back to the non-cube path)."""
-    leaves = _conjunctive_leaves(tree)
-    if leaves is not None and tree is not None:
-        ps = _prefix_select(segment, cube, leaves)
+    if levels:
+        ps = _prefix_select(segment, cube, leaves, levels)
         if ps is not None:
             return ps
     from pinot_tpu.query import host_exec
@@ -298,129 +343,173 @@ def try_star_tree_execute(segment, request: BrokerRequest
     """Execute over a covering cube; None when not eligible."""
     if not getattr(segment, "star_trees", None):
         return None
-    functions = make_functions(request.aggregations)
-    cube = _eligible_cube(segment, request, functions)
-    if cube is None:
-        return None
-    try:
-        sel, examined = _cube_select(segment, cube, request.filter)
-    except Exception:  # noqa: BLE001 — unresolvable predicate: fall back
-        return None
-
-    blk = IntermediateResultsBlock()
-    counts = cube.counts
-    matched_docs = int(counts[sel].sum())
-    if request.is_group_by:
-        _cube_group_by(segment, cube, request, functions, sel, blk)
-    else:
-        blk.agg_intermediates = [
-            _cube_aggregate(cube, f, sel) for f in functions]
-    blk.stats = ExecutionStats(
-        num_docs_scanned=int(sel.size),           # groups, not raw docs —
-        # parity: star-tree queries report aggregated doc counts
-        num_entries_scanned_in_filter=examined,
-        num_segments_processed=1,
-        num_segments_matched=1 if matched_docs else 0,
-        total_docs=segment.num_docs)
-    return blk
+    return _cube_execute([segment], request, trim=False)
 
 
 def try_star_tree_execute_multi(segments, request: BrokerRequest
                                 ) -> Optional[IntermediateResultsBlock]:
     """Vectorized cube execution across MANY segments at once.
 
-    The per-segment path emits one group_map dict per segment and merges
-    them entry-by-entry in Python — fine for two segments, dominant cost
-    for many. Here the matched cube rows (decoded group values, counts,
-    stat lanes) from every segment are concatenated and aggregated in one
-    numpy group-by pass. Parity: the combine step of
-    StarTreeAggregationExecutor outputs, done columnar.
+    Merging one group_map dict per segment entry-by-entry in Python is
+    fine for two segments, dominant cost for many. Here the matched cube
+    rows (union group codes, counts, stat lanes) from every segment come
+    back as one set of arrays and are aggregated in one numpy group-by
+    pass. Parity: the combine step of StarTreeAggregationExecutor
+    outputs, done columnar.
     """
-    if not request.is_aggregation or request.is_selection:
-        return None
+    return _cube_execute(list(segments), request, trim=True)
+
+
+def _cube_execute(segments, request: BrokerRequest, trim: bool
+                  ) -> Optional[IntermediateResultsBlock]:
+    """One algorithm for one segment and for many: choose each segment's
+    cube, select and gather its matched rows (`_gather_native` where the
+    filter is a conjunction of interval leaves under a narrowed prefix,
+    the stepwise `_gather_numpy` otherwise), aggregate them once."""
     functions = make_functions(request.aggregations)
-    pairs = []
-    for seg in segments:
-        cube = _eligible_cube(seg, request, functions)
-        if cube is None:
+    needs = _query_needs(request, functions)
+    if needs is None:
+        return None
+    leaves = _QueryLeaves(segments, request.filter)
+    plans = []
+    for si, seg in enumerate(segments):
+        plan = _eligible_cube(seg, needs, leaves, si)
+        if plan is None:
             return None                   # all segments must be covered
-        pairs.append((seg, cube))
+        plans.append((seg,) + plan)
 
     gcols = list(request.group_by.columns) if request.group_by else []
-    # per gcol: (union value table, per-segment local-id -> union-id LUTs)
+    # per gcol: union value table + per-segment local-id -> union-id LUTs
     # — cached per (segment set, column); keeps the hot path free of
     # OBJECT-array uniques (python string compares dominated the q3.2
     # residual at 8 segments)
-    unions = [_union_lut([seg for seg, _ in pairs], c) for c in gcols]
-    code_chunks: List[List[np.ndarray]] = [[] for _ in gcols]
-    cnt_chunks: List[np.ndarray] = []
-    stat_chunks: Dict[str, List[np.ndarray]] = {}
-    # each column's stat lanes exactly once per segment — two functions
-    # over the same column (MIN(x), MAX(x)) must not double-append
-    stat_cols = sorted({f.column for f in functions
-                        if f.info.base != "COUNT"})
-    total_docs = 0
-    matched_groups = 0
-    scanned = 0
-    for si, (seg, cube) in enumerate(pairs):
-        total_docs += seg.num_docs
+    unions = [leaves.tables(c) for c in gcols]
+    lanes = _stat_lanes(functions)
+    rows = _gather_native(plans, leaves, gcols, unions, lanes)
+    native = rows is not None
+    if not native:
         try:
-            sel, examined = _cube_select(seg, cube, request.filter)
+            rows = _gather_numpy(plans, request.filter, leaves, gcols,
+                                 unions, lanes)
         except Exception:  # noqa: BLE001 — unresolvable predicate
             return None
+    codes, counts, stat_rows, matched_groups, scanned = rows
+
+    blk = IntermediateResultsBlock()
+    if not gcols:
+        blk.agg_intermediates = [
+            _cube_aggregate(counts, stat_rows, f) for f in functions]
+    else:
+        _multi_group_by([u.values for u in unions], codes, counts,
+                        stat_rows, functions, blk)
+        if trim:
+            # same memory/parity bound combine_blocks applies on the
+            # per-segment path (AggregationGroupByTrimmingService)
+            from pinot_tpu.query.combine import (trim_group_map,
+                                                 trim_size_for)
+            t = trim_size_for(request.group_by.top_n)
+            if len(blk.group_map) > 4 * t:
+                blk.group_map = trim_group_map(blk.group_map, functions, t)
+    blk.stats = ExecutionStats(
+        num_docs_scanned=matched_groups,          # groups, not raw docs —
+        # parity: star-tree queries report aggregated doc counts
+        num_entries_scanned_in_filter=scanned,
+        num_segments_processed=len(segments),
+        num_segments_matched=len(segments) if matched_groups else 0,
+        total_docs=sum(seg.num_docs for seg in segments))
+    blk.cube_native = native
+    obs_profiler.mark_cube_descents(native, len(segments))
+    return blk
+
+
+def _stat_lanes(functions) -> List[Tuple[str, str]]:
+    """The (metric column, "sum"/"min"/"max") lanes the functions read,
+    each once — two functions over one column (MIN(x), MAX(x)) share."""
+    return sorted({(f.column, k) for f in functions
+                   for k in _STAT_KINDS.get(f.info.base, ())})
+
+
+def _gather_numpy(plans, tree, leaves: _QueryLeaves, gcols, unions, lanes):
+    """The stepwise select and gather, a numpy call a step: the twin of
+    `_gather_native`, and the path for what that does not take."""
+    code_chunks: List[List[np.ndarray]] = [[] for _ in gcols]
+    cnt_chunks: List[np.ndarray] = []
+    stat_chunks: List[List[np.ndarray]] = [[] for _ in lanes]
+    matched_groups = 0
+    scanned = 0
+    for si, (seg, cube, levels, _) in enumerate(plans):
+        sel, examined = _cube_select(seg, cube, tree, leaves.leaves, levels)
         scanned += examined
         matched_groups += len(sel)
         cnt_chunks.append(cube.counts[sel])
         for i, c in enumerate(gcols):
-            lut = unions[i][1][si]
-            code_chunks[i].append(lut[cube.dim_ids[c][sel]])
-        for col in stat_cols:
-            stats = cube.metric_stats[col]
-            for k in ("sum", "min", "max"):
-                stat_chunks.setdefault(f"{col}.{k}", []).append(
-                    stats[k][sel])
-
-    counts = np.concatenate(cnt_chunks) if cnt_chunks else \
-        np.zeros(0, np.int64)
-    stats_cat = {k: np.concatenate(v) for k, v in stat_chunks.items()}
-    blk = IntermediateResultsBlock()
-    if not gcols:
-        mask_all = np.ones(len(counts), dtype=bool)
-        flat_cube = StarTreeCubeLike(counts, stats_cat)
-        blk.agg_intermediates = [
-            _cube_aggregate(flat_cube, f, mask_all) for f in functions]
-    else:
-        _multi_group_by([u[0] for u in unions], code_chunks, counts,
-                        stats_cat, functions, blk)
-        from pinot_tpu.query.combine import trim_group_map, trim_size_for
-        t = trim_size_for(request.group_by.top_n)
-        if len(blk.group_map) > 4 * t:
-            # same memory/parity bound combine_blocks applies on the
-            # per-segment path (AggregationGroupByTrimmingService)
-            blk.group_map = trim_group_map(blk.group_map, functions, t)
-    blk.stats = ExecutionStats(
-        num_docs_scanned=matched_groups,
-        num_entries_scanned_in_filter=scanned,
-        num_segments_processed=len(segments),
-        num_segments_matched=len(segments) if matched_groups else 0,
-        total_docs=total_docs)
-    return blk
+            ids = cube.dim_ids[c][sel]
+            lut = unions[i].luts[si]
+            code_chunks[i].append(ids if lut is None else lut[ids])
+        for chunks, (col, kind) in zip(stat_chunks, lanes):
+            chunks.append(cube.metric_stats[col][kind][sel])
+    codes = [np.concatenate(chunks).astype(np.int64)
+             for chunks in code_chunks]
+    stat_rows = {lane: np.concatenate(chunks)
+                 for lane, chunks in zip(lanes, stat_chunks)}
+    return (codes, np.concatenate(cnt_chunks), stat_rows, matched_groups,
+            scanned)
 
 
-class StarTreeCubeLike:
-    """Concatenated cross-segment cube rows, shaped like a cube for
-    _cube_aggregate."""
+def _gather_native(plans, leaves: _QueryLeaves, gcols, unions, lanes):
+    """Select and gather for all segments in ONE foreign call
+    (seglib.cpp `cube_select_gather`), which runs with the interpreter
+    lock released: the number of lock-dropping calls a descent makes no
+    longer grows with segments x levels x blocks. None where the query
+    or a cube is not of its kind: an OR, a leaf that is no interval
+    list, a free leading split dimension (the member scan), a descent
+    past _PREFIX_BLOCK_LIMIT, no library. It sees ids and intervals
+    only."""
+    from pinot_tpu import native
+    if native.loaded() is None or leaves.leaves is None:
+        return None
+    addrs = _cube_addresses(plans)
+    if addrs is None:
+        return None
+    seg_hdr, preds, ivs_flat, gcol_rows, stat_rows = [], [], [], [], []
+    est = 0.0
+    for si, (seg, cube, levels, frac) in enumerate(plans):
+        if not levels:
+            return None
+        a = addrs[si]
+        consumed = {id(lf) for _, lf, _ in levels}
+        resid = []
+        for lf in leaves.leaves:
+            if id(lf) not in consumed:
+                ivs = leaves.intervals(lf, si)
+                if ivs is None:
+                    return None
+                resid.append((lf.column, lf, ivs))
+        for dim, _, ivs in levels + resid:
+            first = len(ivs_flat) // 2
+            for iv in ivs:
+                ivs_flat += iv
+            preds += (a["dim", dim], first, len(ivs_flat) // 2)
+        seg_hdr += (cube.n_groups, a["counts"], len(levels), len(resid))
+        for c, u in zip(gcols, unions):
+            gcol_rows += (a["dim", c], u.lut_addrs[si], u.lut_sizes[si])
+        stat_rows += [a[lane] for lane in lanes]
+        est += cube.n_groups * frac
+    out = native.cube_select_gather(
+        (seg_hdr, preds, ivs_flat, gcol_rows, stat_rows), len(gcols),
+        len(lanes), max(1024, 2 * int(est)), _PREFIX_BLOCK_LIMIT)
+    if out is None:
+        return None
+    codes, counts, stat_lanes, per_seg = out
+    return (list(codes), counts, dict(zip(lanes, stat_lanes)),
+            sum(per_seg[0::2]), sum(per_seg[1::2]))
 
-    def __init__(self, counts: np.ndarray, stats_cat: Dict[str, np.ndarray]):
-        self.counts = counts
-        self.metric_stats: Dict[str, Dict[str, np.ndarray]] = {}  # tpulint: disable=cache-bound -- keyed by metric column: bounded by the star-tree's metric set
-        for k, arr in stats_cat.items():
-            col, stat = k.rsplit(".", 1)
-            self.metric_stats.setdefault(col, {})[stat] = arr
 
-
-_UNION_LUT_CACHE: Dict = {}
-_UNION_LUT_LOCK = threading.Lock()
+def _segment_identity(s):
+    """The segment artifact, not the object: name, rows, CRC."""
+    md = getattr(s, "metadata", None)
+    return (getattr(s, "segment_name", None), s.num_docs,
+            getattr(md, "crc", None))
 
 
 def _segment_cache_identity(s, col: str):
@@ -435,42 +524,125 @@ def _segment_cache_identity(s, col: str):
     d = s.data_source(col).dictionary
     n = len(d)
     fingerprint = (n, str(d.values[0]), str(d.values[n - 1])) if n else (0,)
-    md = getattr(s, "metadata", None)
-    return (getattr(s, "segment_name", None), s.num_docs,
-            getattr(md, "crc", None), fingerprint)
+    return _segment_identity(s) + (fingerprint,)
 
 
-def _union_lut(segments, col: str):
-    """(union value table, per-segment local-dictId -> union-id LUT).
+# union tables per (segment set, column) and lane addresses per (segment
+# set, cubes), under one lock and one bound
+_SEGMENT_SET_CACHE: Dict = {}
+_SEGMENT_SET_LOCK = threading.Lock()
 
-    Cached per (segment identity tuple, column): the union merge and its
-    object-array compares run once per segment set, leaving only int
-    gathers on the query hot path."""
+
+def _cache_get(key):
+    with _SEGMENT_SET_LOCK:
+        return _SEGMENT_SET_CACHE.get(key)
+
+
+def _cache_put(key, value) -> None:
+    with _SEGMENT_SET_LOCK:
+        if len(_SEGMENT_SET_CACHE) > 256:
+            _SEGMENT_SET_CACHE.clear()
+        _SEGMENT_SET_CACHE[key] = value
+
+
+class _UnionTables:
+    """One column over a set of segments: the sorted union of their
+    dictionaries and the tables between a segment's dictIds and the
+    union's.
+
+    values      the union's values; a group code decodes through it
+    luts[s]     segment s's local dictId -> union id (int64; None for a
+                single segment, whose ids are the union's)
+    dictionary  the union as a Dictionary, so that a literal resolves by
+                the segments' own rules, once for all of them; None
+                where a segment's dictionary is missing or unsorted
+    ranks       [S, len(union) + 1]: segment s's values below union id
+                u, so that a union id range [a, b) is the local range
+                [ranks[s, a], ranks[s, b]) (None for a single segment);
+                a memoryview, whose look-ups are Python ints and no
+                array calls
+    """
+
+    def __init__(self, segments, col: str):
+        dicts = [s.data_source(col).dictionary for s in segments]
+        if len(dicts) == 1:
+            self.values = dicts[0].values
+            self.luts = [None]
+            self.ranks = None
+            self.dictionary = dicts[0]
+        else:
+            vals = [np.asarray(d.values) for d in dicts]
+            self.values = np.unique(np.concatenate(vals))
+            self.luts = [np.searchsorted(self.values, v).astype(np.int64)
+                         for v in vals]
+            self.ranks = self.dictionary = None
+            if all(getattr(d, "is_sorted", True) for d in dicts) and \
+                    len({d.data_type for d in dicts}) == 1:
+                from pinot_tpu.segment.dictionary import Dictionary
+                self.dictionary = Dictionary(dicts[0].data_type,
+                                             self.values)
+                ids = np.arange(len(self.values) + 1)
+                self.ranks = memoryview(np.stack(
+                    [np.searchsorted(lut, ids) for lut in self.luts]))
+        # for the native gather, which reads a lut through its address
+        self.lut_addrs = [0 if t is None else t.ctypes.data
+                          for t in self.luts]
+        self.lut_sizes = [0 if t is None else len(t) for t in self.luts]
+
+
+def _union_tables(segments, col: str) -> _UnionTables:
+    """Cached per (segment identity tuple, column): the union merge and
+    its object-array compares run once per segment set, leaving only int
+    gathers on the query hot path. A single segment's tables are its own
+    dictionary and cost nothing to make."""
+    if len(segments) == 1:
+        return _UnionTables(segments, col)
     key = (tuple(_segment_cache_identity(s, col) for s in segments), col)
-    with _UNION_LUT_LOCK:
-        hit = _UNION_LUT_CACHE.get(key)
-    if hit is not None:
-        return hit
-    dicts = [np.asarray(s.data_source(col).dictionary.values)
-             for s in segments]
-    union = np.unique(np.concatenate(dicts)) if dicts else \
-        np.zeros(0, object)
-    luts = [np.searchsorted(union, d).astype(np.int64) for d in dicts]
-    with _UNION_LUT_LOCK:
-        if len(_UNION_LUT_CACHE) > 256:
-            _UNION_LUT_CACHE.clear()
-        _UNION_LUT_CACHE[key] = (union, luts)
-    return union, luts
+    hit = _cache_get(key)
+    if hit is None:
+        hit = _UnionTables(segments, col)
+        _cache_put(key, hit)
+    return hit
 
 
-def _multi_group_by(uniq_vals, code_chunks, counts, stats_cat, functions,
+def _cube_addresses(plans) -> Optional[List[Dict]]:
+    """Per segment, the addresses of its chosen cube's lanes ("counts",
+    ("dim", column), (metric, stat kind)) for the native gather; None
+    where a lane is not as seglib.cpp reads it (int32 dimensions, int64
+    counts, float64 stats, C-contiguous, n_groups long). Cached per
+    (segment set, cubes); an entry holds its cubes, so a hit is checked
+    by identity and a reloaded segment's cube never reads through stale
+    addresses."""
+    cubes = [cube for _, cube, _, _ in plans]
+    key = tuple(_segment_identity(seg) + (seg.star_trees.index(cube),)
+                for seg, cube, _, _ in plans)
+    hit = _cache_get(key)
+    if hit is not None and all(a is b for a, b in zip(hit[0], cubes)):
+        return hit[1]
+    addrs = []
+    for cube in cubes:
+        a = {}
+        lanes = [("counts", cube.counts, np.int64)]
+        lanes += [(("dim", d), ids, np.int32)
+                  for d, ids in cube.dim_ids.items()]
+        lanes += [((m, k), arr, np.float64)
+                  for m, st in cube.metric_stats.items()
+                  for k, arr in st.items()]
+        for name, arr, dtype in lanes:
+            if arr.dtype != dtype or not arr.flags.c_contiguous or \
+                    arr.shape != (cube.n_groups,):
+                return None
+            a[name] = arr.ctypes.data
+        addrs.append(a)
+    _cache_put(key, (cubes, addrs))
+    return addrs
+
+
+def _multi_group_by(uniq_vals, codes, counts, stat_rows, functions,
                     blk) -> None:
-    """Cross-segment group-by over UNION-id codes (int lanes only; the
-    object-domain work happened once in _union_lut)."""
-    n = len(counts)
-    codes = [np.concatenate(chunks).astype(np.int64) if chunks else
-             np.zeros(0, np.int64) for chunks in code_chunks]
-    key = np.zeros(n, dtype=np.int64)
+    """Group-by over UNION-id codes, a lane a group column (int lanes
+    only; the object-domain work happened once in _union_tables)."""
+    key = np.zeros(len(counts), dtype=np.int64)
     for u, inv in zip(uniq_vals, codes):
         key = key * max(len(u), 1) + inv
     uniq_keys, inverse = np.unique(key, return_inverse=True)
@@ -484,66 +656,38 @@ def _multi_group_by(uniq_vals, code_chunks, counts, stats_cat, functions,
     value_cols.reverse()
 
     _fill_group_map(blk, functions, g, inverse, counts, value_cols,
-                    lambda f, k: stats_cat[f"{f.column}.{k}"])
+                    lambda f, k: stat_rows[f.column, k])
 
 
-def _cube_aggregate(cube, f, sel: np.ndarray):
-    """sel: selected row indices (or a boolean mask — fancy indexing
-    treats both identically here)."""
+def _cube_aggregate(counts: np.ndarray, stat_rows, f):
+    """Function f over the matched rows' counts and stat lanes."""
     base = f.info.base
-    mask = sel
-    cnt = int(cube.counts[mask].sum())
+    cnt = int(counts.sum())
     if base == "COUNT":
         return cnt
     if cnt == 0:
         return None
-    stats = cube.metric_stats[f.column]
     if base == "SUM":
-        return float(stats["sum"][mask].sum())
+        return float(stat_rows[f.column, "sum"].sum())
     if base == "AVG":
-        return (float(stats["sum"][mask].sum()), cnt)
+        return (float(stat_rows[f.column, "sum"].sum()), cnt)
     if base == "MIN":
-        return float(stats["min"][mask].min())
+        return float(stat_rows[f.column, "min"].min())
     if base == "MAX":
-        return float(stats["max"][mask].max())
+        return float(stat_rows[f.column, "max"].max())
     if base == "MINMAXRANGE":
-        return (float(stats["min"][mask].min()),
-                float(stats["max"][mask].max()))
+        return (float(stat_rows[f.column, "min"].min()),
+                float(stat_rows[f.column, "max"].max()))
     raise ValueError(base)
-
-
-def _cube_group_by(segment, cube, request, functions, sel: np.ndarray,
-                   blk: IntermediateResultsBlock) -> None:
-    gcols = request.group_by.columns
-    lanes = [cube.dim_ids[c][sel].astype(np.int64) for c in gcols]
-    cards = [segment.data_source(c).metadata.cardinality for c in gcols]
-    key = np.zeros(len(sel), dtype=np.int64)
-    for lane, card in zip(lanes, cards):
-        key = key * card + lane
-    uniq, inverse = np.unique(key, return_inverse=True)
-    g = len(uniq)
-
-    value_cols = []
-    rem = uniq.copy()
-    for c, card in zip(reversed(gcols), reversed(cards)):
-        d = segment.data_source(c).dictionary
-        value_cols.append(d.decode(rem % card))
-        rem //= card
-    value_cols.reverse()
-
-    _fill_group_map(blk, functions, g, inverse, cube.counts[sel],
-                    value_cols,
-                    lambda f, k: cube.metric_stats[f.column][k][sel])
 
 
 def _fill_group_map(blk: IntermediateResultsBlock, functions, g: int,
                     inverse: np.ndarray, row_counts: np.ndarray,
                     value_cols, stat_rows) -> None:
-    """Shared group-by finisher for the single-segment and multi-segment
-    cube paths: scatter matched cube rows into `g` group slots and emit
-    the engine's standard intermediate formats (AVG = (sum, count),
-    MINMAXRANGE = (min, max)). `stat_rows(f, kind)` yields the matched
-    rows' "sum"/"min"/"max" lane for function f."""
+    """The group-by finisher: scatter matched cube rows into `g` group
+    slots and emit the engine's standard intermediate formats (AVG =
+    (sum, count), MINMAXRANGE = (min, max)). `stat_rows(f, kind)` yields
+    the matched rows' "sum"/"min"/"max" lane for function f."""
     gcounts = np.zeros(g, dtype=np.int64)
     np.add.at(gcounts, inverse, row_counts)
     per_fn: List[List] = []

@@ -227,6 +227,16 @@ def test_lane_cache_meters_exist_at_zero_from_boot():
         server.stop()
 
 
+def test_cube_descent_meters_exist_at_zero_from_boot():
+    server = ServerInstance("server_cubes")
+    try:
+        snap = server.metrics.snapshot()
+        assert snap["meter.cubeDescentsNative.count"] == 0
+        assert snap["meter.cubeDescentsNumpy.count"] == 0
+    finally:
+        server.stop()
+
+
 def test_lane_cache_meters_count_a_querys_lanes(cluster):
     """A scan's first run misses each lane once; the same lanes under
     another literal are all hits, as many as the first run looked up."""

@@ -604,7 +604,9 @@ def test_star_tree_execute_span_says_hit_or_miss(star_tree_segments, batch):
         star_tree_segments,
         "SELECT SUM(runs) FROM baseballStats WHERE teamID = 'BOS'", batch)
     st = [s for s in spans if s["name"] == "starTreeExecute"]
-    assert [s["attrs"] for s in st] == [{"segments": 2, "hit": True}]
+    from pinot_tpu import native
+    assert [s["attrs"] for s in st] == [
+        {"segments": 2, "hit": True, "native": native.loaded() is not None}]
     assert not [s for s in spans if s["name"] == "segment"]
     # not covered (hits is no cube dimension): the multi path and each
     # segment's own descent miss, and the scan's spans follow
