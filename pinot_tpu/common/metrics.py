@@ -350,7 +350,7 @@ class ServerMeter:
     # cache supplied
     XLA_COMPILES = "xlaCompiles"
     XLA_COMPILE_CACHE_HITS = "xlaCompileCacheHits"
-    # segment lane cache (segment/loader.py DataSource._device, marked
+    # segment lane cache (segment/loader.py DataSource.device_lane, marked
     # through obs/residency.py): device-lane accesses served by one
     # look-up, and accesses that built the padded host operand
     LANE_CACHE_HITS = "laneCacheHits"

@@ -15,6 +15,7 @@ from pinot_tpu.common.response import BrokerResponse
 from pinot_tpu.pql.optimizer import BrokerRequestOptimizer
 from pinot_tpu.pql.parser import compile_pql
 from pinot_tpu.query.executor import ServerQueryExecutor
+from pinot_tpu.query.plan import preprocess_request
 from pinot_tpu.query.reduce import BrokerReduceService
 from pinot_tpu.segment.loader import ImmutableSegment, ImmutableSegmentLoader
 
@@ -25,15 +26,17 @@ class QueryEngine:
         """`mesh`: optional jax.sharding.Mesh — when given, multi-segment
         queries run the sharded executor (segment DP with ICI combine,
         parallel/sharded.py) and fall back to sequential per-segment
-        execution when segments aren't homogeneous enough."""
+        execution when segments aren't homogeneous enough
+        (`ServerQueryExecutor.execute` chooses)."""
         from pinot_tpu.utils.device import configure_compile_cache
         configure_compile_cache()
         self.segments = list(segments)
-        self.executor = ServerQueryExecutor(use_device=use_device)
         self.sharded = None
         if mesh is not None:
             from pinot_tpu.parallel.sharded import ShardedQueryExecutor
             self.sharded = ShardedQueryExecutor(mesh=mesh)
+        self.executor = ServerQueryExecutor(use_device=use_device,
+                                            sharded=self.sharded)
         self.optimizer = BrokerRequestOptimizer()
         self.reducer = BrokerReduceService()
 
@@ -45,24 +48,11 @@ class QueryEngine:
     def query(self, pql: str) -> BrokerResponse:
         t0 = time.perf_counter()
         request = self.optimizer.optimize(compile_pql(pql))
-        from pinot_tpu.query.plan import preprocess_request
         # FASTHLL derived rewrite, once, while the request is still
-        # private to this query — the executors preprocess defensively
-        # too (on copies), but the rewritten column name must be visible
-        # to the reduce for result naming (reference parity)
+        # private to this query: the executor plans with it and the
+        # reduce names results by it (reference parity)
         request = preprocess_request(self.segments, request)
-        block = self._execute(request)
+        block = self.executor.execute(request, self.segments)
         resp = self.reducer.reduce(request, [block])
         resp.time_used_ms = (time.perf_counter() - t0) * 1e3
         return resp
-
-    def _execute(self, request):
-        if self.sharded is not None and len(self.segments) > 1:
-            from pinot_tpu.parallel.sharded import NotShardable
-            from pinot_tpu.query.plan import (GroupsLimitExceeded,
-                                              UnsupportedOnDevice)
-            try:
-                return self.sharded.execute(request, self.segments)
-            except (NotShardable, GroupsLimitExceeded, UnsupportedOnDevice):
-                pass
-        return self.executor.execute(request, self.segments)

@@ -598,6 +598,8 @@ class ShardedQueryExecutor:
     def execute(self, request: BrokerRequest,
                 segments: Sequence[ImmutableSegment]
                 ) -> IntermediateResultsBlock:
+        """`request` arrives preprocessed, as for
+        `ServerQueryExecutor.execute`, its one serving caller."""
         # debug complement to tpulint host-sync: implicit device→host
         # pulls raise under PINOT_TPU_DEBUG_TRANSFERS=1
         with debug_transfer_guard():
@@ -607,10 +609,6 @@ class ShardedQueryExecutor:
                  segments: Sequence[ImmutableSegment]
                  ) -> IntermediateResultsBlock:
         t0 = time.perf_counter()
-        from pinot_tpu.query.plan import preprocess_request
-        # FASTHLL derived rewrite — on a copy; the shared request must
-        # not change under concurrently planning executors
-        request = preprocess_request(segments, request)
         stack = self.stack_for(segments)
         # Fast paths (star-tree cubes, metadata/dictionary answers) are
         # per-segment host work in each segment's OWN id domain — probe

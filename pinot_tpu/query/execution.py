@@ -31,48 +31,19 @@ def _count_filter_leaves(spec) -> int:
     return 1          # query leaf
 
 
-def gather_operands_for(segment, needed_cols) -> Dict[str, object]:
-    cols: Dict[str, object] = {}
-    for col, kind in needed_cols:
-        if kind == "vdoc":
-            # upsert validDocIds: a pseudo-column liveness lane served
-            # by the segment itself (version-cached device upload)
-            cols[f"{col}.vdoc"] = segment.device_valid_lane()
-            continue
-        ds = segment.data_source(col)
-        if kind == "ids":
-            cols[f"{col}.ids"] = ds.device_dict_ids()
-        elif kind == "vals":
-            cols[f"{col}.vals"] = ds.device_dict_values()
-        elif kind == "raw":
-            cols[f"{col}.raw"] = ds.device_raw_values()
-        elif kind == "mv":
-            cols[f"{col}.mv"] = ds.device_mv_dict_ids()
-        elif kind == "parts":
-            cols[f"{col}.parts"] = ds.device_part_lanes()
-        elif kind == "vlane":
-            cols[f"{col}.vlane"] = ds.device_value_lane()
-        elif kind == "vec":
-            cols[f"{col}.vec"] = ds.device_vec_values()
-        elif kind == "ivfa":
-            cols[f"{col}.ivfa"] = ds.device_ivf_assign()
-        elif kind == "ivfc":
-            cols[f"{col}.ivfc"] = ds.device_ivf_centroids()
-        elif kind == "ivfv":
-            cols[f"{col}.ivfv"] = ds.device_ivf_valid()
-        elif kind == "hllidx":
-            cols[f"{col}.hllidx"] = ds.device_hll_idx()
-        elif kind == "hllrank":
-            cols[f"{col}.hllrank"] = ds.device_hll_rank()
-    return cols
-
-
 def gather_operands(plan) -> Dict[str, object]:
     """The plan's lanes as device arrays, under an `operandGather`
     span: one look-up in the lane cache a lane and, only on a miss,
     the build of its padded host operand and the upload."""
+    segment = plan.segment
     with obs_span(ServerQueryPhase.OPERAND_GATHER):
-        return gather_operands_for(plan.segment, plan.needed_cols)
+        # upsert validDocIds ("vdoc"): a pseudo-column liveness lane
+        # served by the segment itself (version-cached device upload);
+        # every other kind is a row of the loader's DataSource.LANES
+        return {f"{col}.{kind}": segment.device_valid_lane()
+                if kind == "vdoc"
+                else segment.data_source(col).device_lane(kind)
+                for col, kind in plan.needed_cols}
 
 
 def execute_segment_plan(plan) -> IntermediateResultsBlock:

@@ -6,6 +6,11 @@ block with execution stats. Device-unsupported query shapes fall back to the
 host (numpy) executor per segment, the way the reference falls back from
 index-based to scan-based operators.
 
+`_route` decides a segment's path (cube, device scan, host twin) and hands
+it back as a value, `_run_route` runs it: the solo and the batched walk
+both go through the pair and end in `_finish_query`. Sharded or sequential
+is chosen in `execute`, nowhere else.
+
 Per-segment execution fans out on the scheduler's query-worker pool
 (CombineOperator parity: per-segment plans on an ExecutorService,
 CombineOperator.java:27). Device dispatches serialize on the chip anyway,
@@ -16,20 +21,86 @@ from __future__ import annotations
 
 import concurrent.futures
 import time
+from contextlib import contextmanager
 from typing import List, Optional, Tuple
 
 from pinot_tpu.common.metrics import ServerQueryPhase
-from pinot_tpu.common.request import BrokerRequest
+from pinot_tpu.common.request import VECTOR_RESULT_COLUMNS, BrokerRequest
 from pinot_tpu.obs import profiler as obs_profiler
 from pinot_tpu.obs.profiler import QueryProfile, obs_span
 from pinot_tpu.obs.tracing import TraceContext, make_trace_context
 from pinot_tpu.query.blocks import IntermediateResultsBlock
 from pinot_tpu.query.combine import combine_blocks
-from pinot_tpu.query import host_exec
+from pinot_tpu.query import execution, host_exec
 from pinot_tpu.query.plan import (GroupsLimitExceeded, InstancePlanMaker,
-                                  UnsupportedOnDevice)
+                                  UnsupportedOnDevice, batch_signature,
+                                  upsert_mask_active)
 from pinot_tpu.query.pruner import SegmentPrunerService
 from pinot_tpu.segment.loader import ImmutableSegment
+
+
+#: ONE logical segment's work: (blocks, extra_parts, extra_matched) — a
+#: consuming segment's frozen+tail pair yields two blocks that stay
+#: paired for stats accounting
+SegmentResult = Tuple[List[IntermediateResultsBlock], int, int]
+
+
+@contextmanager
+def _query_context(table: str, trace: Optional[TraceContext]):
+    """Keep whatever ambient profile the instance layer activated;
+    direct callers (engine, tests) get a private throwaway so the
+    per-dispatch accounting hooks always have a target."""
+    trace = trace if trace is not None else make_trace_context(False)
+    ambient = obs_profiler.current()
+    profile = ambient[0] if ambient is not None else QueryProfile(table)
+    with obs_profiler.active(profile, trace):
+        yield trace
+
+
+def _stamp(blk: IntermediateResultsBlock, num_pruned: int,
+           t0: float) -> IntermediateResultsBlock:
+    blk.stats.num_segments_pruned = num_pruned
+    blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
+    return blk
+
+
+def _finish_query(request: BrokerRequest, selected,
+                  results: List[SegmentResult], num_pruned: int,
+                  t0: float) -> IntermediateResultsBlock:
+    """The per-query frame behind the solo and the batched walk;
+    `results` is shorter than `selected` where the deadline cut it."""
+    blocks = [b for seg_blocks, _, _ in results for b in seg_blocks]
+    if not blocks:
+        blk = IntermediateResultsBlock()
+        if request.is_group_by:
+            blk.group_map = {}
+        elif request.is_aggregation:
+            blk.agg_intermediates = None
+        if request.is_selection:
+            blk.selection_rows = []
+            blk.selection_columns = list(request.selection.columns)
+            if request.vector is not None:
+                blk.selection_columns += list(VECTOR_RESULT_COLUMNS)
+    else:
+        blk = combine_blocks(request, blocks)
+    if len(results) < len(selected):
+        blk.exceptions.append(
+            "DeadlineExceededError: segment execution truncated at "
+            f"{len(results)}/{len(selected)} segments (budget "
+            "expired mid-query)")
+    # frozen+tail pairs are ONE logical consuming segment: both
+    # processed always, matched only when both halves matched
+    blk.stats.num_segments_processed -= sum(p for _, p, _ in results)
+    blk.stats.num_segments_matched -= sum(m for _, _, m in results)
+    # realtime freshness over the consuming segments this query saw
+    # (parity: ServerQueryExecutorV1Impl minConsumingFreshness)
+    consuming_ts = [int(s_.last_indexed_time_ms) for s_ in selected
+                    if getattr(s_, "is_mutable", False) and
+                    hasattr(s_, "last_indexed_time_ms")]
+    blk.stats.num_consuming_segments_processed = len(consuming_ts)
+    if consuming_ts:
+        blk.stats.min_consuming_freshness_ms = min(consuming_ts)
+    return _stamp(blk, num_pruned, t0)
 
 
 class ServerQueryExecutor:
@@ -37,12 +108,16 @@ class ServerQueryExecutor:
                  pruner: Optional[SegmentPrunerService] = None,
                  use_device: bool = True,
                  segment_executor: Optional[
-                     concurrent.futures.Executor] = None):
+                     concurrent.futures.Executor] = None,
+                 sharded=None):
         self.plan_maker = plan_maker or InstancePlanMaker()
         self.pruner = pruner or SegmentPrunerService()
         self.use_device = use_device
         # the scheduler's query-worker pool; None → sequential loop
         self.segment_executor = segment_executor
+        # parallel/sharded.py ShardedQueryExecutor (a mesh), or None:
+        # multi-segment queries try its combine first (`execute`)
+        self.sharded = sharded
         # residency gates (server/residency_manager.py): device_gate
         # routes host/disk-tier segments through host_exec instead of
         # the device kernels; mutable_gate blocks frozen-snapshot
@@ -51,104 +126,71 @@ class ServerQueryExecutor:
         self.device_gate = None
         self.mutable_gate = None
 
+    def _on_device(self, seg) -> bool:
+        return self.device_gate is None or self.device_gate(seg)
+
     def execute(self, request: BrokerRequest,
                 segments: List[ImmutableSegment],
                 trace: Optional[TraceContext] = None,
                 deadline: Optional[float] = None
                 ) -> IntermediateResultsBlock:
-        """`deadline`: absolute time.monotonic() instant; the
+        """`request` arrives preprocessed (`preprocess_request`: the
+        request frame or `QueryEngine.query` applies it, once).
+
+        `deadline`: absolute time.monotonic() instant; the
         per-segment fan-out stops (with an honest truncation exception)
         once it passes — a deadline-expired query must not keep a
-        worker pinned computing rows its broker stopped listening for."""
-        trace = trace if trace is not None else make_trace_context(False)
-        # keep whatever ambient profile the instance layer activated;
-        # direct callers (engine, tests) get a private throwaway so the
-        # per-dispatch accounting hooks always have a target
-        ambient = obs_profiler.current()
-        profile = ambient[0] if ambient is not None else \
-            QueryProfile(request.table_name)
-        with obs_profiler.active(profile, trace):
-            return self._execute(request, segments, trace, deadline)
+        worker pinned computing rows its broker stopped listening for.
+        """
+        with _query_context(request.table_name, trace) as trace:
+            # the one place that chooses sharded or sequential: the
+            # sharded combine stacks ALL segments' lanes in HBM — it
+            # only applies when every segment is device-tier (a demoted
+            # segment must not be re-uploaded through the stack path);
+            # what it cannot take it says by raising
+            if self.sharded is not None and len(segments) > 1 and \
+                    all(self._on_device(s) for s in segments):
+                from pinot_tpu.parallel.sharded import NotShardable
+                try:
+                    with trace.span(ServerQueryPhase.SHARDED_EXECUTION):
+                        blk = self.sharded.execute(request, segments)
+                    blk.execution_path = "sharded"
+                    obs_profiler.count_path("sharded", len(segments))
+                    return blk
+                except (NotShardable, GroupsLimitExceeded,
+                        UnsupportedOnDevice):
+                    pass
+            blk = self._execute(request, segments, trace, deadline)
+            blk.execution_path = "sequential"
+            return blk
 
     def _execute(self, request: BrokerRequest,
                  segments: List[ImmutableSegment],
                  trace: TraceContext,
                  deadline: Optional[float]) -> IntermediateResultsBlock:
         t0 = time.perf_counter()
-        from pinot_tpu.query.plan import preprocess_request
-        # FASTHLL derived rewrite — returns a copy when it rewrites, so
-        # the broker's shared request never changes under our feet
-        request = preprocess_request(segments, request)
         with trace.span(ServerQueryPhase.SEGMENT_PRUNING):
             selected = self.pruner.prune(segments, request)
         num_pruned = len(segments) - len(selected)
 
         blk = self._try_star_tree_multi(selected, request)
         if blk is not None:
-            blk.stats.num_segments_pruned = num_pruned
-            blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
-            return blk
+            return _stamp(blk, num_pruned, t0)
 
         with trace.span(ServerQueryPhase.SEGMENT_EXECUTION):
-            if self.segment_executor is not None and len(selected) > 1:
-                blocks, extra_parts, extra_matched, executed = \
-                    self._run_parallel(selected, request, deadline, trace)
-            else:
-                blocks, extra_parts, extra_matched, executed = \
-                    self._run_sequential(selected, request, deadline,
-                                         trace)
-        truncated = executed < len(selected)
-
-        if not blocks:
-            blk = IntermediateResultsBlock()
-            if request.is_group_by:
-                blk.group_map = {}
-            elif request.is_aggregation:
-                blk.agg_intermediates = None
-            if request.is_selection:
-                blk.selection_rows = []
-                blk.selection_columns = list(request.selection.columns)
-                if request.vector is not None:
-                    from pinot_tpu.common.request import \
-                        VECTOR_RESULT_COLUMNS
-                    blk.selection_columns += list(VECTOR_RESULT_COLUMNS)
-        else:
-            blk = combine_blocks(request, blocks)
-        if truncated:
-            blk.exceptions.append(
-                "DeadlineExceededError: segment execution truncated at "
-                f"{executed}/{len(selected)} segments (budget "
-                "expired mid-query)")
-        if extra_parts:
-            # frozen+tail pairs are ONE logical consuming segment: both
-            # processed always, matched only when both halves matched
-            blk.stats.num_segments_processed -= extra_parts
-            blk.stats.num_segments_matched -= extra_matched
-        # realtime freshness over the consuming segments this query saw
-        # (parity: ServerQueryExecutorV1Impl minConsumingFreshness)
-        consuming_ts = [int(s_.last_indexed_time_ms) for s_ in selected
-                        if getattr(s_, "is_mutable", False) and
-                        hasattr(s_, "last_indexed_time_ms")]
-        blk.stats.num_consuming_segments_processed = len(consuming_ts)
-        if consuming_ts:
-            blk.stats.min_consuming_freshness_ms = min(consuming_ts)
-        blk.stats.num_segments_pruned = num_pruned
-        blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
-        return blk
+            run = self._run_parallel if self.segment_executor is not None \
+                and len(selected) > 1 else self._run_sequential
+            results = run(selected, request, deadline, trace)
+        return _finish_query(request, selected, results, num_pruned, t0)
 
     # -- per-segment work ---------------------------------------------------
-    def _segment_work(self, seg, request: BrokerRequest
-                      ) -> Tuple[List[IntermediateResultsBlock], int, int]:
-        """Execute ONE logical segment; returns (blocks, extra_parts,
-        extra_matched) — a consuming segment's frozen+tail pair yields
-        two blocks that stay paired for stats accounting."""
+    def _segment_work(self, seg, request: BrokerRequest) -> SegmentResult:
         with obs_span("segment",
                       segment=getattr(seg, "segment_name", "?")):
-            return self._segment_work_inner(seg, request)
+            return self._logical_segment(seg, request)
 
-    def _segment_work_inner(self, seg, request: BrokerRequest
-                            ) -> Tuple[List[IntermediateResultsBlock],
-                                       int, int]:
+    def _logical_segment(self, seg, request: BrokerRequest
+                         ) -> SegmentResult:
         if self.use_device and getattr(seg, "is_mutable", False) and \
                 hasattr(seg, "device_view") and \
                 (self.mutable_gate is None or self.mutable_gate(seg)):
@@ -195,26 +237,21 @@ class ServerQueryExecutor:
 
     def _run_sequential(self, selected, request: BrokerRequest,
                         deadline: Optional[float],
-                        trace: Optional[TraceContext] = None):
-        blocks: List[IntermediateResultsBlock] = []
-        extra_parts = extra_matched = 0
-        executed = 0
+                        trace: Optional[TraceContext] = None
+                        ) -> List[SegmentResult]:
+        results: List[SegmentResult] = []
         t_queued = time.perf_counter()
         for seg in selected:
             if deadline is not None and time.monotonic() >= deadline:
                 break
             self._record_queue_wait(trace, t_queued, seg)
-            segment_blocks, parts, matched = self._segment_work(seg,
-                                                                request)
-            blocks.extend(segment_blocks)
-            extra_parts += parts
-            extra_matched += matched
-            executed += 1
-        return blocks, extra_parts, extra_matched, executed
+            results.append(self._segment_work(seg, request))
+        return results
 
     def _run_parallel(self, selected, request: BrokerRequest,
                       deadline: Optional[float],
-                      trace: Optional[TraceContext] = None):
+                      trace: Optional[TraceContext] = None
+                      ) -> List[SegmentResult]:
         """CombineOperator parity: every segment plan runs as a task on
         the scheduler's query-worker pool while this (runner) thread
         gathers. Deadline truncation: tasks not yet started when the
@@ -243,58 +280,54 @@ class ServerQueryExecutor:
 
         futures = [self.segment_executor.submit(work, seg)
                    for seg in selected]
-        results: List[Optional[tuple]] = [None] * len(selected)
         abandoned = False
-        for i, fut in enumerate(futures):
-            if abandoned:
-                fut.cancel()
-                continue
-            budget = None if deadline is None else \
-                deadline - time.monotonic()
-            try:
-                results[i] = fut.result(
-                    timeout=None if budget is None else max(budget, 0.0))
-            except concurrent.futures.TimeoutError:
-                # budget expired mid-gather: abandon this straggler and
-                # cancel everything not yet started; whatever already
-                # finished still counts (drain-what's-done semantics)
-                abandoned = True
-                fut.cancel()
-        if abandoned:
-            for i, fut in enumerate(futures):
-                if results[i] is None and fut.done() and \
-                        not fut.cancelled():
-                    try:
-                        results[i] = fut.result(timeout=0)
-                    except (concurrent.futures.TimeoutError,
-                            concurrent.futures.CancelledError):
-                        pass
-        blocks: List[IntermediateResultsBlock] = []
-        extra_parts = extra_matched = 0
-        executed = 0
-        for res in results:
-            if res is None:
-                continue
-            segment_blocks, parts, matched = res
-            blocks.extend(segment_blocks)
-            extra_parts += parts
-            extra_matched += matched
-            executed += 1
-        return blocks, extra_parts, extra_matched, executed
+        for fut in futures:
+            if not abandoned:
+                try:
+                    fut.result(timeout=None if deadline is None else
+                               max(deadline - time.monotonic(), 0.0))
+                    continue
+                except concurrent.futures.TimeoutError:
+                    # budget expired mid-gather: abandon this straggler
+                    # and cancel everything not yet started; whatever
+                    # already finished still counts (drain-what's-done
+                    # semantics)
+                    abandoned = True
+            fut.cancel()
+        results = [fut.result() for fut in futures
+                   if fut.done() and not fut.cancelled()]
+        return [res for res in results if res is not None]
 
-    def _execute_segment(self, segment: ImmutableSegment,
-                         request: BrokerRequest) -> IntermediateResultsBlock:
+    # -- the ladder: one segment, one request -------------------------------
+    def _route(self, segment, request: BrokerRequest):
+        """ONE segment's path, as a value: ("cube", block) where a
+        star-tree cube answered, ("scan", plan) where the planner made
+        a device plan, ("host", None) otherwise (no device, a segment
+        gated off it, or a shape the planner refuses: caught here and
+        nowhere else)."""
         blk = self._try_star_tree(segment, request)
         if blk is not None:
-            return blk
-        if self.use_device and \
-                (self.device_gate is None or self.device_gate(segment)):
+            return "cube", blk
+        if self.use_device and self._on_device(segment):
             try:
                 with obs_span(ServerQueryPhase.BUILD_QUERY_PLAN):
-                    plan = self.plan_maker.make_segment_plan(segment,
-                                                             request)
+                    return "scan", self.plan_maker.make_segment_plan(
+                        segment, request)
+            except (GroupsLimitExceeded, UnsupportedOnDevice):
+                pass
+        return "host", None
+
+    def _run_route(self, segment, request: BrokerRequest, route
+                   ) -> IntermediateResultsBlock:
+        """Run what `_route` decided and count the path taken; a plan
+        that refuses while running falls back to the host twin."""
+        path, payload = route
+        if path == "cube":
+            return payload
+        if path == "scan":
+            try:
                 with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
-                    blk = plan.execute()
+                    blk = payload.execute()
                 obs_profiler.count_path("scan")
                 return blk
             except (GroupsLimitExceeded, UnsupportedOnDevice):
@@ -302,15 +335,22 @@ class ServerQueryExecutor:
         obs_profiler.count_path("host")
         return host_exec.execute_host(segment, request)
 
+    def _execute_segment(self, segment: ImmutableSegment,
+                         request: BrokerRequest) -> IntermediateResultsBlock:
+        # planning and running stay fused inside the pool worker
+        return self._run_route(segment, request,
+                               self._route(segment, request))
+
     # -- cross-query batched execution --------------------------------------
     def execute_batch(self, requests: List[BrokerRequest],
                       segments: List[ImmutableSegment],
                       trace: Optional[TraceContext] = None,
                       deadline: Optional[float] = None
                       ) -> List[IntermediateResultsBlock]:
-        """Execute N same-shape requests over one segment set, sharing
-        device dispatches wherever their per-segment plans compile to
-        equal specs (query/plan.py:batch_signature).
+        """Execute N same-shape requests (preprocessed, as for
+        `execute`) over one segment set, sharing device dispatches
+        wherever their per-segment plans compile to equal specs
+        (query/plan.py:batch_signature).
 
         The coalescer (server/scheduler.py) guarantees the members
         share a table, segment list, and plan-shape key; this layer
@@ -321,199 +361,118 @@ class ServerQueryExecutor:
         trees, mutable segments, host fallback, group-by) run exactly
         the sequential ladder. Returns blocks aligned with `requests`.
         """
-        trace = trace if trace is not None else make_trace_context(False)
-        ambient = obs_profiler.current()
-        profile = ambient[0] if ambient is not None else \
-            QueryProfile(requests[0].table_name if requests else "?")
-        with obs_profiler.active(profile, trace):
-            return self._execute_batch(requests, segments, deadline)
+        table = requests[0].table_name if requests else "?"
+        with _query_context(table, trace) as trace:
+            t0 = time.perf_counter()
+            members = []
+            for req in requests:
+                m = _BatchMember(req, self.pruner.prune(segments, req),
+                                 len(segments))
+                # the multi-segment cube fast path, as in _execute: a
+                # member it answers never reaches the batch loop
+                m.final = self._try_star_tree_multi(m.selected, req)
+                members.append(m)
+            pending = [m for m in members if m.final is None]
 
-    def _execute_batch(self, requests, segments, deadline):
-        t0 = time.perf_counter()
-        from pinot_tpu.query.plan import preprocess_request
-        members = []
-        for req in requests:
-            req = preprocess_request(segments, req)
-            selected = self.pruner.prune(segments, req)
-            members.append(_BatchMember(req, selected, len(segments)))
-
-        # per-member multi-segment star-tree fast path (mirrors
-        # _execute; a member it answers never reaches the batch loop)
-        for m in members:
-            m.final = self._try_star_tree_multi(m.selected, m.request)
-        pending = [m for m in members if m.final is None]
-
-        ambient = obs_profiler.current()
-        trace = ambient[1] if ambient is not None else None
-        t_queued = time.perf_counter()
-        for seg in segments:
-            if deadline is not None and time.monotonic() >= deadline:
-                break
-            takers = [m for m in pending if id(seg) in m.selected_ids]
-            if not takers:
-                continue
-            self._record_queue_wait(trace, t_queued, seg)
-            self._batch_segment(seg, takers)
-            for m in takers:
-                m.executed += 1
-
-        return [m.final if m.final is not None
-                else m.finish(t0) for m in members]
+            t_queued = time.perf_counter()
+            for seg in segments:
+                if deadline is not None and time.monotonic() >= deadline:
+                    break
+                takers = [m for m in pending if id(seg) in m.selected_ids]
+                if takers:
+                    self._record_queue_wait(trace, t_queued, seg)
+                    self._batch_segment(seg, takers)
+            return [m.finish(t0) for m in members]
 
     def _batch_segment(self, seg, takers) -> None:
-        """One segment, many members: batch the plans whose compiled
-        signatures agree, run everything else down the sequential
-        ladder unchanged."""
-        from pinot_tpu.query import execution
-        from pinot_tpu.query.plan import batch_signature
-
-        if getattr(seg, "is_mutable", False) or not self.use_device or \
-                (self.device_gate is not None and
-                 not self.device_gate(seg)):
-            # consuming segments (frozen/tail or snapshot views) and
-            # gated-off-device segments keep their per-member path
+        """One segment, many members: batch the device plans whose
+        compiled signatures agree, run every other route as the solo
+        walk does."""
+        if getattr(seg, "is_mutable", False):
+            # consuming segments (frozen/tail or snapshot views) keep
+            # their per-member walk
             for m in takers:
-                m.add(*self._segment_work(seg, m.request))
+                m.results.append(self._segment_work(seg, m.request))
             return
 
         groups: dict = {}
         for m in takers:
-            blk = self._try_star_tree(seg, m.request)
-            if blk is not None:
-                m.add([blk], 0, 0)
-                continue
-            try:
-                with obs_span(ServerQueryPhase.BUILD_QUERY_PLAN):
-                    plan = self.plan_maker.make_segment_plan(seg,
-                                                             m.request)
-            except (GroupsLimitExceeded, UnsupportedOnDevice):
-                obs_profiler.count_path("host")
-                m.add([host_exec.execute_host(seg, m.request)], 0, 0)
-                continue
-            sig = batch_signature(plan)
+            path, payload = route = self._route(seg, m.request)
+            # fast-path / group-by plans have no signature: per member
+            sig = batch_signature(payload) if path == "scan" else None
             if sig is None:
-                # fast-path / group-by plans execute per member
-                try:
-                    with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
-                        blk = plan.execute()
-                    obs_profiler.count_path("scan")
-                except (GroupsLimitExceeded, UnsupportedOnDevice):
-                    obs_profiler.count_path("host")
-                    blk = host_exec.execute_host(seg, m.request)
-                m.add([blk], 0, 0)
-                continue
-            groups.setdefault(sig, []).append((m, plan))
+                m.results.append(
+                    ([self._run_route(seg, m.request, route)], 0, 0))
+            else:
+                groups.setdefault(sig, []).append((m, payload))
 
         for group in groups.values():
-            plans = [plan for _, plan in group]
             with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
-                blocks = execution.execute_segment_plans_batched(plans)
+                blocks = execution.execute_segment_plans_batched(
+                    [plan for _, plan in group])
             obs_profiler.count_path("scan", len(group))
             for (m, _), blk in zip(group, blocks):
-                m.add([blk], 0, 0)
+                m.results.append(([blk], 0, 0))
+
+    # -- star-tree cubes ----------------------------------------------------
+    @staticmethod
+    def _cube_answer(descend, n_segments: int, **attrs):
+        """A cube descent under a `starTreeExecute` span (`attrs.hit`
+        false where no cube covers the query; on a hit `attrs.native`)."""
+        with obs_span(ServerQueryPhase.STAR_TREE_EXECUTE, **attrs) as span:
+            blk = descend()
+            if span is not None:
+                span["attrs"]["hit"] = blk is not None
+                if blk is not None:
+                    span["attrs"]["native"] = blk.cube_native
+        if blk is not None:
+            obs_profiler.count_path("cube", n_segments)
+        return blk
 
     def _try_star_tree(self, segment, request):
-        """One segment's cube descent under a `starTreeExecute` span
-        (`attrs.hit` false where no cube covers the query and the
-        segment goes on to the scan)."""
-        from pinot_tpu.query.plan import upsert_mask_active
+        """One segment's cube descent (on a miss the segment goes on to
+        the scan)."""
         if request.is_aggregation and not request.is_selection and \
                 not upsert_mask_active(segment) and \
                 getattr(segment, "star_trees", None):
             from pinot_tpu.startree.executor import try_star_tree_execute
-            with obs_span(ServerQueryPhase.STAR_TREE_EXECUTE,
-                          segment=getattr(segment, "segment_name",
-                                          "?")) as span:
-                blk = try_star_tree_execute(segment, request)
-                if span is not None:
-                    span["attrs"]["hit"] = blk is not None
-                    if blk is not None:
-                        span["attrs"]["native"] = blk.cube_native
-            if blk is not None:
-                obs_profiler.count_path("cube")
-                return blk
+            return self._cube_answer(
+                lambda: try_star_tree_execute(segment, request), 1,
+                segment=getattr(segment, "segment_name", "?"))
         return None
 
     def _try_star_tree_multi(self, selected, request):
         """The multi-segment cube fast path (every selected segment
         must hold a covering cube), one `starTreeExecute` span for the
         lot (`attrs.segments`)."""
-        from pinot_tpu.query.plan import upsert_mask_active
         if request.is_aggregation and not request.is_selection and \
                 len(selected) > 1 and \
                 not any(upsert_mask_active(s) for s in selected) and \
                 all(getattr(s, "star_trees", None) for s in selected):
             from pinot_tpu.startree.executor import \
                 try_star_tree_execute_multi
-            with obs_span(ServerQueryPhase.STAR_TREE_EXECUTE,
-                          segments=len(selected)) as span:
-                blk = try_star_tree_execute_multi(selected, request)
-                if span is not None:
-                    span["attrs"]["hit"] = blk is not None
-                    if blk is not None:
-                        span["attrs"]["native"] = blk.cube_native
-            if blk is not None:
-                obs_profiler.count_path("cube", len(selected))
-                return blk
+            return self._cube_answer(
+                lambda: try_star_tree_execute_multi(selected, request),
+                len(selected), segments=len(selected))
         return None
 
 
 class _BatchMember:
     """Per-request accumulator for the batched execution loop."""
     __slots__ = ("request", "selected", "selected_ids", "num_pruned",
-                 "blocks", "extra_parts", "extra_matched", "executed",
-                 "final")
+                 "results", "final")
 
     def __init__(self, request, selected, num_total: int):
         self.request = request
         self.selected = selected
         self.selected_ids = {id(s) for s in selected}
         self.num_pruned = num_total - len(selected)
-        self.blocks: List[IntermediateResultsBlock] = []
-        self.extra_parts = 0
-        self.extra_matched = 0
-        self.executed = 0
+        # one SegmentResult a segment walked, as the solo walk keeps
+        self.results: List[SegmentResult] = []
         self.final: Optional[IntermediateResultsBlock] = None
 
-    def add(self, blocks, parts: int, matched: int) -> None:
-        self.blocks.extend(blocks)
-        self.extra_parts += parts
-        self.extra_matched += matched
-
     def finish(self, t0: float) -> IntermediateResultsBlock:
-        """Combine + stats, mirroring ServerQueryExecutor._execute's
-        tail for one member."""
-        request = self.request
-        if not self.blocks:
-            blk = IntermediateResultsBlock()
-            if request.is_group_by:
-                blk.group_map = {}
-            elif request.is_aggregation:
-                blk.agg_intermediates = None
-            if request.is_selection:
-                blk.selection_rows = []
-                blk.selection_columns = list(request.selection.columns)
-                if request.vector is not None:
-                    from pinot_tpu.common.request import \
-                        VECTOR_RESULT_COLUMNS
-                    blk.selection_columns += list(VECTOR_RESULT_COLUMNS)
-        else:
-            blk = combine_blocks(request, self.blocks)
-        if self.executed < len(self.selected):
-            blk.exceptions.append(
-                "DeadlineExceededError: segment execution truncated at "
-                f"{self.executed}/{len(self.selected)} segments (budget "
-                "expired mid-query)")
-        if self.extra_parts:
-            blk.stats.num_segments_processed -= self.extra_parts
-            blk.stats.num_segments_matched -= self.extra_matched
-        consuming_ts = [int(s_.last_indexed_time_ms)
-                        for s_ in self.selected
-                        if getattr(s_, "is_mutable", False) and
-                        hasattr(s_, "last_indexed_time_ms")]
-        blk.stats.num_consuming_segments_processed = len(consuming_ts)
-        if consuming_ts:
-            blk.stats.min_consuming_freshness_ms = min(consuming_ts)
-        blk.stats.num_segments_pruned = self.num_pruned
-        blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
-        return blk
+        if self.final is not None:
+            return _stamp(self.final, self.num_pruned, t0)
+        return _finish_query(self.request, self.selected, self.results,
+                             self.num_pruned, t0)

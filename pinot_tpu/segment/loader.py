@@ -186,12 +186,25 @@ class DataSource:
         self._hll_tables: Optional[tuple] = None
 
     # -- device access -----------------------------------------------------
+    #: lane kind (the tag of `plan.needed_cols` and of `host_operand`) →
+    #: (lane-cache key, residency ledger kind): a new kind is one row
+    #: here and its layout in `host_operand`
+    LANES = {
+        "ids": ("dict_ids", "scan"), "mv": ("mv_dict_ids", "scan"),
+        "vals": ("dict_values", "scan"), "raw": ("raw_values", "scan"),
+        "parts": ("part_lanes", "scan"), "vlane": ("value_lane", "scan"),
+        "vec": ("vec_values", "vector"), "ivfa": ("ivf_assign", "vector"),
+        "ivfc": ("ivf_centroids", "vector"),
+        "ivfv": ("ivf_valid", "vector"),
+        "hllidx": ("hll_idx", "hll"), "hllrank": ("hll_rank", "hll"),
+    }
+
     def device_dict_ids(self):
         """Padded int32 dictIds on device; padding = cardinality (invalid)."""
-        return self._device("dict_ids", "ids")
+        return self.device_lane("ids")
 
     def device_mv_dict_ids(self):
-        return self._device("mv_dict_ids", "mv")
+        return self.device_lane("mv")
 
     def device_dict_values(self):
         """Numeric dictionary values on device (f64/i64 host width preserved
@@ -199,51 +212,51 @@ class DataSource:
         bucket the kernels use for cardinality so compiled executables are
         shared across segments with similar dictionaries; padding slots
         repeat the last value (kernels mask them out)."""
-        return self._device("dict_values", "vals")
+        return self.device_lane("vals")
 
     def device_raw_values(self):
-        return self._device("raw_values", "raw")
+        return self.device_lane("raw")
 
     def device_part_lanes(self):
         """Bit-sliced int8 part lanes [n_parts, P] for exact integer sums
         (see kernels.py 'TPU reduction strategy')."""
-        return self._device("part_lanes", "parts")
+        return self.device_lane("parts")
 
     def device_value_lane(self):
         """Decoded dictionary-value lane [P] for float sums."""
-        return self._device("value_lane", "vlane")
+        return self.device_lane("vlane")
 
     def device_vec_values(self):
         """Padded [P, dim_pad] float32 embedding block on device; row
         padding is zeros (masked by the kernel's validity iota), dim
         padding is zeros (an exact no-op in the tree-dot sums)."""
-        return self._device("vec_values", "vec")
+        return self.device_lane("vec")
 
     def device_ivf_assign(self):
         """Narrow per-row coarse-cell lane [P] (padding rows carry the
         never-probed sentinel id numCentroids)."""
-        return self._device("ivf_assign", "ivfa")
+        return self.device_lane("ivfa")
 
     def device_ivf_centroids(self):
         """Zero-padded codebook [C_pad, dim_pad] f32."""
-        return self._device("ivf_centroids", "ivfc")
+        return self.device_lane("ivfc")
 
     def device_ivf_valid(self):
         """Centroid liveness [C_pad] bool (live count rides as a lane,
         not a param, so sharded plans stay shareable)."""
-        return self._device("ivf_valid", "ivfv")
+        return self.device_lane("ivfv")
 
     def device_hll_idx(self):
         """Per-dictId HLL register-index table [card_pad] int32 — built
         once from the dictionary values with the SAME hashing the host
         HyperLogLog uses (sketches.hll_tables), so the device register
         kernel is bit-identical to the host sketch by construction."""
-        return self._device("hll_idx", "hllidx")
+        return self.device_lane("hllidx")
 
     def device_hll_rank(self):
         """Per-dictId HLL rank table [card_pad] int32 (padding rank 0 =
         the register-max merge identity)."""
-        return self._device("hll_rank", "hllrank")
+        return self.device_lane("hllrank")
 
     def int_part_info(self) -> tuple:
         """(n_parts, min_value) for the bit-sliced integer sum encoding.
@@ -321,15 +334,12 @@ class DataSource:
         out[: len(ids)] = ids
         return out
 
-    #: _device key → residency ledger kind
-    _LEDGER_KINDS = {"vec_values": "vector", "hll_idx": "hll",
-                     "hll_rank": "hll", "ivf_assign": "vector",
-                     "ivf_centroids": "vector", "ivf_valid": "vector"}
-
-    def _device(self, key: str, kind: str):
-        """Device lane `key`, from the lane cache: a hit is one look-up
-        and does no host work. Only a miss builds the padded host
-        operand of `kind` (needed once, for the upload; never kept)."""
+    def device_lane(self, kind: str):
+        """The device lane of `kind` (a row of `LANES`), from the lane
+        cache: a hit is one look-up and does no host work. Only a miss
+        builds the padded host operand of `kind` (needed once, for the
+        upload; never kept)."""
+        key, ledger_kind = self.LANES[kind]
         lane = self._dev.get(key)
         if lane is not None:
             residency.mark_lane_cache(hit=True)
@@ -357,7 +367,7 @@ class DataSource:
                     if seg is not None else "",
                     segment=seg.segment_name
                     if seg is not None else "",
-                    kind=self._LEDGER_KINDS.get(key, "scan"))
+                    kind=ledger_kind)
             return self._dev[key]
 
     def release_device(self) -> None:

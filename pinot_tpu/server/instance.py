@@ -8,6 +8,7 @@ execute → DataTable bytes).
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 import os
 import threading
@@ -412,18 +413,32 @@ class ServerInstance:
             self._run_batch_chunk(members[i:i + self.MAX_BATCH_CHUNK],
                                   deadline_s)
 
+    def _run_one(self, request: InstanceRequest, deser_ms: float,
+                 deadline: Optional[float], t_queued: float) -> DataTable:
+        """Execute one request on the scheduler worker that picked it
+        up: the time since `t_queued` (perf_counter) is its
+        `schedulerWait`."""
+        wait_ms = (time.perf_counter() - t_queued) * 1e3
+        return self.executor.execute(request, scheduler_wait_ms=wait_ms,
+                                     deadline=deadline, deser_ms=deser_ms)
+
+    def _submit_one(self, request: InstanceRequest, deser_ms: float,
+                    deadline: Optional[float], budget_s: Optional[float],
+                    tenant: str) -> Future:
+        run = functools.partial(self._run_one, request, deser_ms, deadline,
+                                time.perf_counter())
+        return self.scheduler.submit(tenant, run, deadline_s=budget_s)
+
     def _run_batch_chunk(self, members: List[_BatchTicket],
                          deadline_s: Optional[float]) -> None:
-        waits = [(time.perf_counter() - m.t_arrive) * 1e3
-                 for m in members]
         try:
             if len(members) == 1:
                 m = members[0]
-                dt = self.executor.execute(
-                    m.request, scheduler_wait_ms=waits[0],
-                    deadline=deadline_s, deser_ms=m.deser_ms)
-                dts = [dt]
+                dts = [self._run_one(m.request, m.deser_ms, deadline_s,
+                                     m.t_arrive)]
             else:
+                waits = [(time.perf_counter() - m.t_arrive) * 1e3
+                         for m in members]
                 dts = self.executor.execute_batch(
                     [m.request for m in members], waits, deadline_s)
             for m, dt in zip(members, dts):
@@ -467,15 +482,8 @@ class ServerInstance:
         ticket = _BatchTicket(request, deser_ms)
         state, group = self.coalescer.arrive(key, ticket, deadline)
         if state in ("solo", "bypass"):
-            t_submit = time.perf_counter()
-
-            def run():
-                wait_ms = (time.perf_counter() - t_submit) * 1e3
-                return self.executor.execute(
-                    request, scheduler_wait_ms=wait_ms,
-                    deadline=deadline, deser_ms=deser_ms)
-
-            fut = self.scheduler.submit(tenant, run, deadline_s=budget_s)
+            fut = self._submit_one(request, deser_ms, deadline, budget_s,
+                                   tenant)
             fut.add_done_callback(
                 lambda _f, k=key: self.coalescer.leave(k))
             return fut
@@ -533,16 +541,8 @@ class ServerInstance:
             fut = self._coalesced_submit(request, deser_ms, deadline,
                                          budget_s, tenant)
         else:
-            t_submit = time.perf_counter()
-
-            def run():
-                wait_ms = (time.perf_counter() - t_submit) * 1e3
-                return self.executor.execute(request,
-                                             scheduler_wait_ms=wait_ms,
-                                             deadline=deadline,
-                                             deser_ms=deser_ms)
-
-            fut = self.scheduler.submit(tenant, run, deadline_s=budget_s)
+            fut = self._submit_one(request, deser_ms, deadline, budget_s,
+                                   tenant)
         if release_admission:
             # pairs with the admit() in the request path; a failed
             # future (e.g. OutOfCapacity) completes immediately, so the

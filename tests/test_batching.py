@@ -386,3 +386,157 @@ def test_hedged_duplicate_joins_open_batch_instead_of_shedding():
         for i in range(depth):
             s.admission.release(f"t{i}")
         s.stop()
+
+
+# ---------------------------------------------------------------------------
+# One ladder, one frame: whatever route a segment takes, a request
+# executed alone and the same request inside a batch agree
+# ---------------------------------------------------------------------------
+
+_SUM_PQLS = ["SELECT SUM(hits), COUNT(*) FROM baseballStats "
+             "WHERE runs > '%d'" % lit for lit in (10, 75, 130)]
+_CUBE_PQLS = ["SELECT SUM(runs) FROM baseballStats WHERE teamID = '%s'"
+              % team for team in ("BOS", "NYA", "SEA")]
+_GROUP_PQLS = ["SELECT SUM(hits) FROM baseballStats WHERE runs > '%d' "
+               "GROUP BY teamID TOP 30" % lit for lit in (10, 75, 130)]
+
+
+def _plain_segments(n_segs=2):
+    return [build_segment(tempfile.mkdtemp(), n=600, seed=80 + i,
+                          name=f"rt_{i}")[0] for i in range(n_segs)]
+
+
+def _cube_segments(n_segs):
+    import os
+    from fixtures import make_columns, make_schema, make_table_config
+    from pinot_tpu.segment.creator import SegmentCreator
+    from pinot_tpu.segment.loader import ImmutableSegmentLoader
+    from test_startree import ST_CONFIG
+    cfg = make_table_config()
+    cfg.indexing_config.star_tree_configs = [ST_CONFIG]
+    segs = []
+    for i in range(n_segs):
+        d = os.path.join(tempfile.mkdtemp(), f"rc{i}")
+        SegmentCreator(make_schema(), cfg, f"rc_{i}").build(
+            make_columns(2000, seed=90 + i), d)
+        segs.append(ImmutableSegmentLoader.load(d))
+    return segs
+
+
+def _consuming_segment():
+    """A consuming segment with a frozen prefix AND a tail."""
+    from fixtures import make_schema, make_table_config
+    from test_realtime import make_rows
+    from pinot_tpu.realtime.mutable_segment import MutableSegmentImpl
+    seg = MutableSegmentImpl(make_schema(), make_table_config(), "rt_cons")
+    seg.FREEZE_MIN_ROWS = 512
+    rows = make_rows(700, seed=33)
+    for r in rows[:600]:
+        seg.index_row(r)
+    frozen, _ = seg.device_view()
+    assert frozen is not None and frozen.num_docs == 600
+    for r in rows[600:]:
+        seg.index_row(r)
+    frozen, tail = seg.device_view()
+    assert frozen.num_docs == 600 and tail.num_docs == 100
+    return seg
+
+
+def _failing_plan_maker(where):
+    from pinot_tpu.query.plan import (GroupsLimitExceeded,
+                                      InstancePlanMaker,
+                                      UnsupportedOnDevice)
+
+    class Maker(InstancePlanMaker):
+        def make_segment_plan(self, segment, request):
+            if where == "plan":
+                raise UnsupportedOnDevice("refused by the test")
+            plan = super().make_segment_plan(segment, request)
+
+            def boom():
+                raise GroupsLimitExceeded("found while running")
+            plan.execute = boom
+            return plan
+    return Maker()
+
+
+# route → (segments, pqls, executor keywords, device gate, the profile's
+# path totals for the three requests)
+_ROUTES = {
+    "cube": (lambda: _cube_segments(1), _CUBE_PQLS, {}, None,
+             {"cube": 3}),
+    "cube_multi": (lambda: _cube_segments(2), _CUBE_PQLS, {}, None,
+                   {"cube": 6}),
+    "scan": (_plain_segments, _SUM_PQLS, {}, None, {"scan": 6}),
+    "plan_unsupported": (
+        _plain_segments, _SUM_PQLS,
+        {"plan_maker": lambda: _failing_plan_maker("plan")}, None,
+        {"host": 6}),
+    "run_groups_limit": (
+        _plain_segments, _GROUP_PQLS,
+        {"plan_maker": lambda: _failing_plan_maker("run")}, None,
+        {"host": 6}),
+    "consuming_frozen_tail": (
+        lambda: [_consuming_segment()] + _plain_segments(1), _SUM_PQLS,
+        {}, None, {"scan": 6, "host": 3}),
+    "gated_off_device": (_plain_segments, _SUM_PQLS, {},
+                         lambda seg: False, {"host": 6}),
+}
+
+
+@pytest.mark.parametrize("route", sorted(_ROUTES))
+def test_alone_and_batched_agree_on_every_route(route):
+    import dataclasses
+    from pinot_tpu.obs import profiler as obs_profiler
+    from pinot_tpu.obs.profiler import QueryProfile
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    make_segments, pqls, kw, gate, want_paths = _ROUTES[route]
+    segments = make_segments()
+    requests = [compile_pql(p) for p in pqls]
+    ex = ServerQueryExecutor(**{k: v() for k, v in kw.items()})
+    ex.device_gate = gate
+
+    def seen(request, blk):
+        stats = dataclasses.asdict(blk.stats)
+        del stats["time_used_ms"]
+        dt = DataTable.from_block(request, blk)
+        return dt.kind, dt.columns, dt.rows, blk.exceptions, stats
+
+    alone_profile, batch_profile = QueryProfile("t"), QueryProfile("t")
+    with obs_profiler.active(alone_profile, None):
+        alone = [seen(r, ex.execute(r, segments)) for r in requests]
+    with obs_profiler.active(batch_profile, None):
+        batched = [seen(r, b) for r, b in
+                   zip(requests, ex.execute_batch(requests, segments))]
+    for pql, a, b in zip(pqls, alone, batched):
+        assert not a[3], (pql, a[3])
+        assert a == b, pql
+    assert alone_profile.paths == want_paths
+    assert batch_profile.paths == want_paths
+
+
+@pytest.mark.parametrize("case", ["missing_table", "expired_deadline"])
+def test_unserved_replies_carry_the_same_metadata(case):
+    """A request answered without touching a segment gets the same
+    reply alone and in a batch: the exception, and the requestId the
+    broker matches replies by."""
+    from pinot_tpu.server.data_manager import InstanceDataManager
+    from pinot_tpu.server.query_executor import InstanceQueryExecutor
+    dm = InstanceDataManager()
+    if case == "expired_deadline":
+        seg, _ = build_segment(tempfile.mkdtemp(), n=200, seed=5,
+                               name="un_0")
+        dm.table("baseballStats", create=True).add_segment(seg)
+        deadline, want = time.monotonic() - 1.0, "DeadlineExceededError"
+    else:
+        deadline, want = None, "TableDoesNotExistError"
+    iqe = InstanceQueryExecutor(dm)
+    reqs = [InstanceRequest(request_id=40 + i, query=compile_pql(p))
+            for i, p in enumerate(_SUM_PQLS[:2])]
+    alone = [iqe.execute(r, deadline=deadline) for r in reqs]
+    batched = iqe.execute_batch(reqs, [0.0, 0.0], deadline)
+    for r, a, b in zip(reqs, alone, batched):
+        assert a.exceptions == b.exceptions
+        assert a.exceptions[0].startswith(want)
+        assert a.metadata == b.metadata
+        assert a.metadata["requestId"] == str(r.request_id)

@@ -350,3 +350,42 @@ def test_data_manager_removal_listener_evicts_stack():
     tdm.remove_segment(segs[0].segment_name)
     assert len(server.executor.sharded._stacks) == 0
     server.data_manager.shutdown()
+
+
+@pytest.mark.parametrize("pql, want_path", [
+    # shardable: one stacked dispatch for the eight segments
+    ("SELECT SUM(runs) FROM baseballStats WHERE yearID >= 2000", "sharded"),
+    # a fast-path plan (metadata answers it): NotShardable, so the
+    # per-segment walk serves it
+    ("SELECT COUNT(*) FROM baseballStats", "sequential"),
+])
+def test_engine_and_server_choose_the_same_path(cluster, pql, want_path):
+    """Sharded or sequential is chosen in one place
+    (`ServerQueryExecutor.execute`), so the in-process engine and the
+    server's request frame take the same path and count it."""
+    import json
+    from pinot_tpu.common.request import InstanceRequest
+    from pinot_tpu.obs import profiler as obs_profiler
+    from pinot_tpu.obs.profiler import QueryProfile
+    from pinot_tpu.server.data_manager import InstanceDataManager
+    from pinot_tpu.server.query_executor import InstanceQueryExecutor
+    segs, _, mesh = cluster
+    engine = QueryEngine(segs, mesh=mesh)
+    profile = QueryProfile("baseballStats")
+    with obs_profiler.active(profile, None):
+        resp = engine.query(pql)
+    assert not resp.exceptions
+
+    dm = InstanceDataManager()
+    tdm = dm.table("baseballStats", create=True)
+    for seg in segs:
+        tdm.add_segment(seg)
+    dt = InstanceQueryExecutor(dm, mesh=mesh).execute(
+        InstanceRequest(request_id=1, query=compile_pql(pql)))
+    assert not dt.exceptions
+    assert dt.metadata["executionPath"] == want_path
+    served = json.loads(dt.metadata["profileInfo"])["paths"]
+    assert profile.paths == served
+    assert ("sharded" in served) == (want_path == "sharded")
+    if want_path == "sharded":
+        assert served == {"sharded": len(segs)}

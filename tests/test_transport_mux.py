@@ -379,6 +379,39 @@ def test_parallel_segment_execution_deadline_truncates():
         pool.shutdown(wait=False)
 
 
+def test_parallel_gather_abandons_a_straggler_and_keeps_what_finished():
+    """The budget runs out while the gather waits for ONE slow segment:
+    it is abandoned, every segment that finished still counts."""
+    import threading
+    import time as _time
+    from pinot_tpu.query.executor import ServerQueryExecutor
+
+    segs, _ = _build_engine_segments()
+    assert len(segs) >= 3
+    pool = concurrent.futures.ThreadPoolExecutor(len(segs))
+    release = threading.Event()
+    try:
+        par = ServerQueryExecutor(use_device=False, segment_executor=pool)
+        work = par._segment_work
+
+        def slow_second(seg, request):
+            if seg is segs[1]:
+                release.wait(timeout=30)
+            return work(seg, request)
+        par._segment_work = slow_second
+        request = compile_pql("SELECT COUNT(*) FROM baseballStats")
+        blk = par.execute(request, segs, deadline=_time.monotonic() + 0.5)
+        done = len(segs) - 1
+        assert any(f"truncated at {done}/{len(segs)}" in e
+                   for e in blk.exceptions), blk.exceptions
+        assert blk.stats.num_segments_processed == done
+        assert blk.agg_intermediates[0] == sum(
+            s.num_docs for s in segs if s is not segs[1])
+    finally:
+        release.set()
+        pool.shutdown(wait=True)
+
+
 # ---------------------------------------------------------------------------
 # DataTable wire-format compatibility
 # ---------------------------------------------------------------------------
