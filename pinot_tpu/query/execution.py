@@ -7,6 +7,7 @@ decodes, group-key mixed-radix decode).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Tuple
 
@@ -46,6 +47,40 @@ def gather_operands(plan) -> Dict[str, object]:
                 for col, kind in plan.needed_cols}
 
 
+@functools.lru_cache(maxsize=4096)
+def _device_scalar(dtype: str, value: int, device):
+    """An integer scalar operand on `device` (None: the default one),
+    uploaded once a value. A scan's runtime operands are a handful of
+    dictId bounds and the segment's doc count; handed to the jitted
+    program as host scalars, each is a host→device transfer of its own
+    a launch (6-7 a Q1.x segment, 0.2-0.35 ms each on the chip's host,
+    and each gives the interpreter lock away:
+    `scripts/launch_contention.py`). DictIds and doc counts are small
+    integers that recur from query to query, so the launch finds them
+    already there; the program's avals are the same, so it is the same
+    program. Uncommitted, as the lanes are (`obs/residency.py`
+    `device_put`)."""
+    with jax.default_device(device):
+        return jax.device_put(np.dtype(dtype).type(value))  # tpulint: disable=device-ledger -- scalars of 4-8 bytes, at most 4096 of them: no lane; the ledger counts what residency can demote
+
+
+def _scalar_operands(plan, cols, extra_params):
+    """The plan's runtime params and its doc count as the program takes
+    them: integer numpy scalars from the `_device_scalar` table of the
+    device the lanes live on, everything else (arrays, floats, weakly
+    typed Python numbers) as it is."""
+    lane = next(iter(cols.values()), None)
+    devices = lane.devices() if lane is not None else ()
+    if len(devices) > 1:                    # no one device to put it on
+        return (*plan.params, *extra_params), plan.segment.num_docs
+    device = next(iter(devices), None)
+    return (tuple(_device_scalar(p.dtype.name, int(p), device)
+                  if isinstance(p, np.integer) else p
+                  for p in (*plan.params, *extra_params)),
+            # run_segment_kernel's jnp.int32() hands a device int32 back
+            _device_scalar("int32", plan.segment.num_docs, device))
+
+
 def execute_segment_plan(plan) -> IntermediateResultsBlock:
     if plan.fast_path_result is not None:
         return plan.fast_path_result
@@ -69,11 +104,10 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
         # asynchronous return: argument handling, jit cache look-up,
         # any compile, enqueue
         with obs_span(ServerQueryPhase.KERNEL_LAUNCH):
+            params, num_docs = _scalar_operands(plan, cols, extra_params)
             return kernels.run_segment_kernel(
                 segment.padded_docs, plan.filter_spec, agg_specs,
-                group_spec, plan.select_spec, cols,
-                tuple(plan.params) + tuple(extra_params),
-                segment.num_docs)
+                group_spec, plan.select_spec, cols, params, num_docs)
 
     blk = IntermediateResultsBlock()
     spec_used = None

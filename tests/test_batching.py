@@ -484,9 +484,17 @@ _ROUTES = {
 }
 
 
+def _seen(request, blk):
+    """What a client sees of a block, and its stats but the clock's."""
+    import dataclasses
+    stats = dataclasses.asdict(blk.stats)
+    del stats["time_used_ms"]
+    dt = DataTable.from_block(request, blk)
+    return dt.kind, dt.columns, dt.rows, blk.exceptions, stats
+
+
 @pytest.mark.parametrize("route", sorted(_ROUTES))
 def test_alone_and_batched_agree_on_every_route(route):
-    import dataclasses
     from pinot_tpu.obs import profiler as obs_profiler
     from pinot_tpu.obs.profiler import QueryProfile
     from pinot_tpu.query.executor import ServerQueryExecutor
@@ -496,23 +504,97 @@ def test_alone_and_batched_agree_on_every_route(route):
     ex = ServerQueryExecutor(**{k: v() for k, v in kw.items()})
     ex.device_gate = gate
 
-    def seen(request, blk):
-        stats = dataclasses.asdict(blk.stats)
-        del stats["time_used_ms"]
-        dt = DataTable.from_block(request, blk)
-        return dt.kind, dt.columns, dt.rows, blk.exceptions, stats
-
     alone_profile, batch_profile = QueryProfile("t"), QueryProfile("t")
     with obs_profiler.active(alone_profile, None):
-        alone = [seen(r, ex.execute(r, segments)) for r in requests]
+        alone = [_seen(r, ex.execute(r, segments)) for r in requests]
     with obs_profiler.active(batch_profile, None):
-        batched = [seen(r, b) for r, b in
+        batched = [_seen(r, b) for r, b in
                    zip(requests, ex.execute_batch(requests, segments))]
     for pql, a, b in zip(pqls, alone, batched):
         assert not a[3], (pql, a[3])
         assert a == b, pql
     assert alone_profile.paths == want_paths
     assert batch_profile.paths == want_paths
+
+
+# pql → the profile's path totals over the five segments of
+# `_mixed_route_segments` (a cube hit counts `cube`; the consuming
+# segment's frozen part scans, its tail takes the host twin)
+_MIXED_PQLS = {
+    # a cube covers it: plain_0 and plain_2 are one-launch scans
+    "cube_covered_sum": (
+        "SELECT SUM(runs), COUNT(*) FROM baseballStats "
+        "WHERE teamID = 'BOS'", {"scan": 3, "cube": 1, "host": 2}),
+    # no cube holds salary: four scans; a raw FLOAT lane summed in
+    # float block sums, so the order of the combine shows in the answer
+    "float_sum": (
+        "SELECT SUM(salary), COUNT(*) FROM baseballStats "
+        "WHERE runs > '40'", {"scan": 4, "host": 2}),
+    # a group-by ladder a segment
+    "group_by": (
+        "SELECT SUM(hits) FROM baseballStats WHERE teamID IN "
+        "('BOS', 'NYA', 'SEA') GROUP BY teamID TOP 30",
+        {"scan": 3, "cube": 1, "host": 2}),
+    "selection": (
+        "SELECT playerName, runs FROM baseballStats WHERE runs > '120' "
+        "ORDER BY runs DESC, playerName LIMIT 15", {"scan": 4, "host": 2}),
+}
+
+
+@pytest.fixture(scope="module")
+def mixed_route_segments():
+    """[plain_0, cube, consuming (frozen + tail), plain_1 (gated off
+    the device), plain_2], of unequal sizes, and the gate."""
+    plain = [build_segment(tempfile.mkdtemp(), n=500 + 100 * i,
+                           seed=70 + i, name=f"mx_{i}")[0]
+             for i in range(3)]
+    segments = [plain[0], _cube_segments(1)[0], _consuming_segment(),
+                plain[1], plain[2]]
+    return segments, lambda seg: seg is not plain[1]
+
+
+@pytest.mark.parametrize("shape", sorted(_MIXED_PQLS))
+def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
+        mixed_route_segments, shape, monkeypatch):
+    """One query whose segments take every route: the pool's tasks
+    together give what the plain sequential walk gives, combined in
+    the segments' order."""
+    from pinot_tpu.obs import profiler as obs_profiler
+    from pinot_tpu.obs.profiler import QueryProfile
+    from pinot_tpu.query import executor as executor_mod
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    segments, gate = mixed_route_segments
+    pql, want_paths = _MIXED_PQLS[shape]
+    request = compile_pql(pql)
+    combined = []
+    real_combine = executor_mod.combine_blocks
+
+    def spy_combine(req, blocks):
+        combined.append([b.stats.total_docs for b in blocks])
+        return real_combine(req, blocks)
+    monkeypatch.setattr(executor_mod, "combine_blocks", spy_combine)
+
+    pool = ThreadPoolExecutor(4)
+    try:
+        seen, inters, paths = [], [], []
+        for kw in ({}, {"segment_executor": pool}):
+            ex = ServerQueryExecutor(**kw)
+            ex.device_gate = gate
+            profile = QueryProfile("t")
+            with obs_profiler.active(profile, None):
+                blk = ex.execute(request, segments)
+            seen.append(_seen(request, blk))
+            inters.append(blk.agg_intermediates)
+            paths.append(profile.paths)
+    finally:
+        pool.shutdown(wait=True)
+    assert not seen[0][3], seen[0][3]
+    assert seen[0] == seen[1]
+    assert inters[0] == inters[1]          # exactly: the same float sums
+    assert paths[0] == paths[1] == want_paths
+    # six blocks (the consuming segment gives two), in `selected`'s order
+    assert len(combined) == 2 and combined[0] == combined[1]
+    assert len(combined[0]) == 6
 
 
 @pytest.mark.parametrize("case", ["missing_table", "expired_deadline"])

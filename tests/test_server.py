@@ -207,26 +207,33 @@ def test_server_reports_missing_segments(server_with_data):
     assert dt.to_block().agg_intermediates[0] == 2000
 
 
+@pytest.mark.parametrize("walk,pql", [
+    # one launch a segment
+    ("one_launch", "SELECT SUM(runs) FROM baseballStats "
+                   "WHERE yearID >= 1999"),
+    # a group-by ladder a segment: two or three launches
+    ("ladder", "SELECT SUM(runs) FROM baseballStats "
+               "WHERE yearID >= 1999 GROUP BY teamID TOP 5")])
 def test_device_fault_surfaces_and_never_reaches_the_host_twin(
-        server_with_data, monkeypatch):
+        server_with_data, monkeypatch, walk, pql):
     """Only the planner's own verdicts (UnsupportedOnDevice,
     GroupsLimitExceeded) may route a segment to host_exec. A runtime
     fault of the device — compile failure, RESOURCE_EXHAUSTED — must
     come back as an exception, not as a clean-looking host answer."""
+    from pinot_tpu.ops import kernels
     from pinot_tpu.query import host_exec
-    from pinot_tpu.query.plan import SegmentPlan
     server, _ = server_with_data
 
-    def device_fault(self):
+    def device_fault(*a, **k):
         raise RuntimeError("RESOURCE_EXHAUSTED: injected device fault")
 
     def host_twin(*a, **k):
         raise AssertionError("a device fault fell through to host_exec")
 
-    monkeypatch.setattr(SegmentPlan, "execute", device_fault)
+    # every launch goes through it
+    monkeypatch.setattr(kernels, "run_segment_kernel", device_fault)
     monkeypatch.setattr(host_exec, "execute_host", host_twin)
-    dt = _query_server(server, "SELECT SUM(runs) FROM baseballStats "
-                               "WHERE yearID >= 1999")
+    dt = _query_server(server, pql)
     assert any("RESOURCE_EXHAUSTED" in e for e in dt.exceptions), \
         dt.exceptions
     assert dt.num_rows() == 0

@@ -412,6 +412,123 @@ def test_parallel_gather_abandons_a_straggler_and_keeps_what_finished():
         pool.shutdown(wait=True)
 
 
+@pytest.fixture(scope="module")
+def eight_segments():
+    segs, _ = _build_engine_segments(n_segments=8, rows=300)
+    return segs
+
+
+@pytest.fixture
+def pooled_executor():
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    pool = concurrent.futures.ThreadPoolExecutor(4)
+    yield ServerQueryExecutor(segment_executor=pool)
+    pool.shutdown(wait=True)
+
+
+_ONE_LAUNCH_PQL = ("SELECT SUM(salary), COUNT(*) FROM baseballStats "
+                   "WHERE runs > '30'")
+
+
+def _profiled(executor, request, segments, **kw):
+    from pinot_tpu.obs import profiler as obs_profiler
+    profile = obs_profiler.QueryProfile("t")
+    with obs_profiler.active(profile, None):
+        return executor.execute(request, segments, **kw), profile
+
+
+def test_a_launch_finds_its_integer_scalars_on_the_device(
+        eight_segments, pooled_executor, monkeypatch):
+    """DictId bounds and doc counts go to the program as device
+    scalars, uploaded once a value: a warm launch transfers nothing."""
+    import jax
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.query import execution
+    seen = []
+    real = kernels.run_segment_kernel
+
+    def spy(padded, filt, aggs, group, select, cols, params, num_docs):
+        seen.append(tuple(params) + (num_docs,))
+        return real(padded, filt, aggs, group, select, cols, params,
+                    num_docs)
+    monkeypatch.setattr(kernels, "run_segment_kernel", spy)
+    request = compile_pql(_ONE_LAUNCH_PQL)
+    _profiled(pooled_executor, request, eight_segments)
+    first, seen[:] = list(seen), []
+    uploads = []
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda *a, **k: uploads.append(a) or
+                        real_put(*a, **k))
+    _profiled(pooled_executor, request, eight_segments)
+    assert len(first) == len(seen) == 8 and uploads == []
+    for a in first:
+        assert len(a) >= 2 and all(isinstance(p, jax.Array) for p in a)
+    # the same arrays (pool workers launch in any order)
+    assert {tuple(map(id, a)) for a in first} == \
+        {tuple(map(id, b)) for b in seen}
+    # one table entry a device: a scalar lives where the lanes do
+    here, there = jax.devices()[0], jax.devices()[1]
+    assert execution._device_scalar("int32", 7, here) is \
+        execution._device_scalar("int32", 7, here)
+    assert execution._device_scalar("int32", 7, there).devices() == {there}
+    assert all(p.devices() == {here} for p in seen[0])
+
+
+def test_a_ladders_launches_take_no_host_integer_scalar(
+        eight_segments, pooled_executor, monkeypatch):
+    """A group-by ladder's rungs launch through the same table: no
+    integer numpy scalar reaches the program, whatever else a rung
+    hands over (arrays, floats), and the answer is the host twin's."""
+    import jax
+    import numpy as np
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    seen = []
+    real = kernels.run_segment_kernel
+
+    def spy(padded, filt, aggs, group, select, cols, params, num_docs):
+        seen.append(tuple(params) + (num_docs,))
+        return real(padded, filt, aggs, group, select, cols, params,
+                    num_docs)
+    request = compile_pql("SELECT SUM(salary) FROM baseballStats "
+                          "WHERE runs > '30' GROUP BY teamID TOP 40")
+    want, _ = _profiled(ServerQueryExecutor(use_device=False), request,
+                        eight_segments)
+    monkeypatch.setattr(kernels, "run_segment_kernel", spy)
+    blk, profile = _profiled(pooled_executor, request, eight_segments)
+    assert len(seen) >= 8 and profile.paths == {"scan": 8}
+    for operands in seen:
+        assert not any(isinstance(p, np.integer) for p in operands)
+        assert isinstance(operands[-1], jax.Array)      # the doc count
+    assert blk.exceptions == []
+    assert sorted(blk.group_map) == sorted(want.group_map)
+
+
+def test_a_plan_refusing_while_running_lands_on_the_host_twin(
+        eight_segments, pooled_executor, monkeypatch):
+    from pinot_tpu.query import execution
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    from pinot_tpu.query.plan import UnsupportedOnDevice
+    request = compile_pql(_ONE_LAUNCH_PQL)
+    want, _ = _profiled(ServerQueryExecutor(use_device=False), request,
+                        eight_segments)
+    real_gather = execution.gather_operands
+
+    def refuse_the_third(plan):
+        if plan.segment is eight_segments[2]:
+            raise UnsupportedOnDevice("found while running")
+        return real_gather(plan)
+    monkeypatch.setattr(execution, "gather_operands", refuse_the_third)
+    blk, profile = _profiled(pooled_executor, request, eight_segments)
+    assert blk.exceptions == []
+    assert profile.paths == {"scan": 7, "host": 1}
+    assert blk.stats.num_segments_processed == 8
+    assert blk.agg_intermediates[1] == want.agg_intermediates[1]
+    assert blk.agg_intermediates[0] == pytest.approx(
+        want.agg_intermediates[0], rel=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # DataTable wire-format compatibility
 # ---------------------------------------------------------------------------
