@@ -41,6 +41,11 @@ from harness import (build, cells, client, compare,         # noqa: E402
 from harness.cells import load_json                         # noqa: E402
 
 REHEARSAL_ROWS = 80_000
+# what a traced run may spend after its window, each recorded in
+# `phases` under the name of what it waits for
+TRACE_WRITE_WAIT_S = 120        # trace.stop touched -> trace.done seen
+CHILDREN_STOP_WAIT_S = 60.0     # a signal to each child -> its exit
+TRACE_EXTRACT_WAIT_S = 300      # trace_extract.py: .xplane.pb -> events
 
 
 class RunFailure(Exception):
@@ -57,6 +62,17 @@ def compile_seconds(log: str) -> float:
     compiling in a stretch of the server's log."""
     return sum(float(m) for m in re.findall(
         r"Finished [^\n]*? in ([0-9.]+) sec", log))
+
+
+def trace_slice_bounds(seconds: float, config: dict) -> tuple:
+    """(seconds into the window at which the traced slice starts, its
+    length): 40% of the window, from 30% to 70%, or the `trace_slice_s`
+    seconds about its middle that the configuration states where its
+    device is too busy for that. The profiler's export took 12 s for
+    every traced second of the rows without cubes (85,000 device ops a
+    second; PERF.md section 6, PR 34), and a run has to end in 360 s."""
+    length = min(float(config.get("trace_slice_s", seconds)), 0.4 * seconds)
+    return 0.5 * seconds - length / 2, length
 
 
 def layer_metric_specs(bench: dict, cell: str) -> list:
@@ -245,19 +261,22 @@ class Run:
 
     # -- the traced slice --------------------------------------------------
     def trace_slice(self, seconds: float, state: dict) -> None:
-        """Trace the middle of the window: start at 30%, stop at 70%."""
+        """Trace the middle of the window (`trace_slice_bounds`)."""
         def touch(name):
             with open(os.path.join(self.work_dir, name), "w"):
                 pass
-        time.sleep(0.3 * seconds)
+        start, length = trace_slice_bounds(seconds, self.config)
+        time.sleep(start)
         touch("trace.start")
-        time.sleep(0.4 * seconds)
+        time.sleep(length)
         touch("trace.stop")
+        t = time.monotonic()
         done = os.path.join(self.work_dir, "trace.done")
-        deadline = time.monotonic() + 120
+        deadline = t + TRACE_WRITE_WAIT_S
         while not os.path.exists(done) and time.monotonic() < deadline:
             time.sleep(0.05)
         state["done"] = os.path.exists(done)
+        self.phase("trace_write_s", t)
 
     def read_trace(self) -> dict:
         """The slice's events, read in a child so that this process
@@ -266,19 +285,24 @@ class Run:
             started = float(fh.read())
         stopped = load_json(self.work_dir, "trace.done")["stopped"]
         out = os.path.join(self.work_dir, "trace_events.json")
-        subprocess.run(
-            [sys.executable,
-             os.path.join(BENCH_DIR, "harness", "trace_extract.py"),
-             os.path.join(self.work_dir, "profile"), out],
-            check=True, timeout=300,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
-        doc = load_json(out)
+        t = time.monotonic()
+        try:
+            subprocess.run(
+                [sys.executable,
+                 os.path.join(BENCH_DIR, "harness", "trace_extract.py"),
+                 os.path.join(self.work_dir, "profile"), out],
+                check=True, timeout=TRACE_EXTRACT_WAIT_S,
+                env=dict(os.environ, JAX_PLATFORMS="cpu"))
+            doc = load_json(out)
+        finally:
+            self.phase("trace_extract_s", t)
         if self.args.keep:
             os.makedirs(self.args.keep, exist_ok=True)
             shutil.copy(out, self.args.keep)
         # wall clock -> this process's monotonic clock
         shift = time.monotonic() - time.time()
         return {"events": doc["events"], "planes": doc["planes"],
+                "file_bytes": doc["file_bytes"],
                 "window_s": stopped - started,
                 "busy_s": trace_reduce.busy_seconds(doc["events"]),
                 "slice": (started + shift, stopped + shift)}
@@ -328,8 +352,13 @@ class Run:
             say(f"window closed: {len(window['requests'])} requests")
             if slicer is not None:
                 slicer.join()
-                if not slice_state.get("done"):
-                    raise RunFailure("the server wrote no trace")
+                if not slice_state["done"]:
+                    raise RunFailure(
+                        f"the server wrote no trace in the "
+                        f"{TRACE_WRITE_WAIT_S} s after trace.stop: the "
+                        f"profiler's export grows with the device ops of "
+                        f"the slice; a configuration whose device is this "
+                        f"busy states a shorter `trace_slice_s`")
             log_to = os.path.getsize(log)
             after = self.counters()
             with open(log, "rb") as fh:
@@ -338,7 +367,9 @@ class Run:
                     "utf-8", "replace")
 
         cluster, self.cluster = self.cluster, None
-        codes = cluster.stop(wait_s=60.0)
+        t = time.monotonic()
+        codes = cluster.stop(wait_s=CHILDREN_STOP_WAIT_S)
+        self.phase("children_stop_s", t)
         say(f"children stopped: {codes}")
         if any(c != 0 for c in codes.values()):
             raise RunFailure(f"child exit codes: {codes}")
@@ -415,6 +446,7 @@ class Run:
                   "metrics": metrics, "device": device}
         if trace is not None:
             device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
+            result["trace_file_bytes"] = trace["file_bytes"]
             result["breakdown"] = {
                 "device_ops": trace_reduce.op_totals(trace["events"]),
                 "idle_gaps": trace_reduce.idle_gaps(trace["events"])}
@@ -489,6 +521,8 @@ def main(argv=None) -> int:
     except Exception as e:                  # noqa: BLE001 - any failure
         traceback.print_exc()               # fails the run, no result
         say(f"FAILED: {type(e).__name__}: {e}")
+        if run is not None:
+            say(f"phases until then: {json.dumps(run.phases)}")
         return 1
     finally:
         if run is not None:

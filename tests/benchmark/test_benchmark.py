@@ -35,20 +35,17 @@ def last_line(proc) -> dict:
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def cells_metrics(kind: str, cell: str) -> set:
-    return {m["name"] for m in BENCH[kind]
+def cells_metrics(bench: dict, kind: str, cell: str) -> set:
+    return {m["name"] for m in bench[kind]
             if "workloads" not in m or cell in m["workloads"]}
 
 
-@pytest.mark.parametrize("cell,trace", [(c, t) for c in CELLS
-                                        for t in (1, 0)])
-def test_rehearsal_run_ends_in_the_contracts_line(cell, trace):
-    seed = 2**31 + 7
-    proc = run_cell(RUN, "--workload", cell, "--seed", str(seed),
-                    "--seconds", "3", "--trace", str(trace),
-                    "--rehearse-cpu")
-    out = last_line(proc)
+def check_contract_line(proc, out, bench, config, cell, trace):
+    """What a finished rehearsal run must have printed. Everything that
+    differs from cell to cell is read from the cell's own files: its
+    metrics from `bench`, its paths from the configuration."""
     assert RESULT_KEYS <= set(out) and out["rehearsal"] is True
+    assert out["workload"] == cell
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0 and out["answers_compared"] > 0
     assert out["device"]["platform"] == "cpu"
@@ -58,25 +55,55 @@ def test_rehearsal_run_ends_in_the_contracts_line(cell, trace):
     # stderr ends with each number compared beside its limit
     tail = proc.stderr.strip().splitlines()[-len(out["compared"]):]
     assert all(line.startswith("compared ") for line in tail)
+    per_layer = cells_metrics(bench, "per_layer", cell)
     if trace:
         # every per-layer metric that needs no chip; the device's own
         # (trace-based) metrics find nothing to read on the CPU backend
         # and are left out: never a CPU number under a device's name
-        want = cells_metrics("per_layer", cell) - {
-            m["name"] for m in BENCH["per_layer"]
-            if m["source"] == "device_trace"}
+        want = per_layer - {m["name"] for m in bench["per_layer"]
+                            if m["source"] == "device_trace"}
         assert set(out["metrics"]) == want
         assert {"busy_s", "window_s", "memory_peak_bytes"} <= \
             set(out["device"])
-        assert out["metrics"]["result_cache_hit_pct"]["value"] == 0.0
+        if "result_cache_hit_pct" in per_layer:
+            assert out["metrics"]["result_cache_hit_pct"]["value"] == 0.0
         assert "breakdown" in out
+        # the traced run's own tail, by phase, and the trace's size
+        assert {"trace_write_s", "children_stop_s", "trace_extract_s"} <= \
+            set(out["phases"])
+        assert out["trace_file_bytes"] > 0
     else:
-        assert set(out["metrics"]) == cells_metrics("end_to_end", cell)
+        assert set(out["metrics"]) == cells_metrics(bench, "end_to_end",
+                                                    cell)
         assert all(m["value"] > 0 for m in out["metrics"].values())
-    assert out["paths"]["scan"] > 0 and out["paths"]["cube"] > 0
-    assert out["paths"]["host"] == 0
-    assert not os.path.exists(os.path.join(BENCH_DIR, ".work",
+    # the same reading as run.py's `path_violations`
+    assert set(out["paths"]) == set(config["paths"])
+    for p, rule in config["paths"].items():
+        assert rule in ("some", "none")
+        assert (out["paths"][p] == 0) == (rule == "none"), (p, rule)
+
+
+def check_rehearsal(proc, root, bench_dir, cell, trace, seed):
+    """`check_contract_line` with the cell's files found as `run.py`
+    finds them, in the checkout `root`; the run's work files are gone.
+    -> (the result line, the configuration)."""
+    from harness import cells
+    bench, _entry, config, _traffic = cells.load_cell(root, bench_dir, cell)
+    out = last_line(proc)
+    check_contract_line(proc, out, bench, config, cell, trace)
+    assert not os.path.exists(os.path.join(bench_dir, ".work",
                                            f"{cell}.{seed}"))
+    return out, config
+
+
+@pytest.mark.parametrize("cell,trace", [(c, t) for c in CELLS
+                                        for t in (1, 0)])
+def test_rehearsal_run_ends_in_the_contracts_line(cell, trace):
+    seed = 2**31 + 7
+    proc = run_cell(RUN, "--workload", cell, "--seed", str(seed),
+                    "--seconds", "3", "--trace", str(trace),
+                    "--rehearse-cpu")
+    check_rehearsal(proc, REPO, BENCH_DIR, cell, trace, seed)
 
 
 def test_run_without_a_chip_fails_and_prints_no_result():
