@@ -360,6 +360,22 @@ class ServerMeter:
     # select-and-gather call, and by the stepwise numpy twin
     CUBE_DESCENTS_NATIVE = "cubeDescentsNative"
     CUBE_DESCENTS_NUMPY = "cubeDescentsNumpy"
+    # device group-by ladder (query/plan.py drive_group_execution, one
+    # mark_group_ladder call a segment through obs/profiler.py): segments
+    # driven, launches of each phase (the table's re-runs counted), kmax
+    # re-runs, segments whose filter matched nothing (no table), and the
+    # layout of each final table, by plan.group_layout's names
+    GROUP_SEGMENTS = "groupSegments"
+    GROUP_SCOUT_DISPATCHES = "groupScoutDispatches"
+    GROUP_HIST_DISPATCHES = "groupHistDispatches"
+    GROUP_TABLE_DISPATCHES = "groupTableDispatches"
+    GROUP_ESCALATIONS = "groupEscalations"
+    GROUP_EMPTY = "groupEmpty"
+    GROUP_TABLES = {"dense": "groupTablesDense",
+                    "scatter": "groupTablesScatter",
+                    "compacted": "groupTablesCompacted",
+                    "ranked": "groupTablesRanked",
+                    "sorted": "groupTablesSorted"}
 
 
 class ServerTimer:
@@ -433,6 +449,11 @@ class ServerQueryPhase:
     KERNEL_DISPATCH = "kernelDispatch"
     OUTPUT_RELEASE = "outputRelease"
     RESULT_FINISH = "resultFinish"
+    # the group-by ladder's phases, each around its kernelLaunch and
+    # kernelDispatch spans (query/plan.py drive_group_execution)
+    GROUP_SCOUT = "groupScout"
+    GROUP_HIST = "groupHist"
+    GROUP_TABLE = "groupTable"
 
 
 class ServerGauge:

@@ -331,6 +331,53 @@ def mark_cube_descents(native: bool, segments: int) -> None:
         (by_native if native else by_numpy).mark(segments)
 
 
+# -- group-by ladder counters ------------------------------------------------
+
+#: {meter name: Meter} of every registry bound, swapped whole like
+#: _CUBE_METERS
+_group_bound: "weakref.WeakSet" = weakref.WeakSet()
+_GROUP_METERS: tuple = ()
+_GROUP_METER_NAMES = (
+    ServerMeter.GROUP_SEGMENTS, ServerMeter.GROUP_SCOUT_DISPATCHES,
+    ServerMeter.GROUP_HIST_DISPATCHES, ServerMeter.GROUP_TABLE_DISPATCHES,
+    ServerMeter.GROUP_ESCALATIONS, ServerMeter.GROUP_EMPTY,
+    *ServerMeter.GROUP_TABLES.values())
+
+
+def bind_group_metrics(metrics) -> None:
+    """The group-by ladder's meters (`groupSegments`, a dispatch meter
+    a phase, `groupEscalations`, `groupEmpty`, `groupTables<Layout>`)
+    on `metrics`, at 0 from this call on."""
+    global _GROUP_METERS
+    with _compile_lock:
+        _group_bound.add(metrics)
+        _GROUP_METERS = tuple({name: m.meter(name)
+                               for name in _GROUP_METER_NAMES}
+                              for m in _group_bound)
+
+
+def mark_group_ladder(scout: int, hist: int, table: int,
+                      layout: Optional[str]) -> None:
+    """One segment went through the device group-by ladder
+    (query/plan.py `drive_group_execution`): the launches of its scout,
+    of its histogram rung and of its table (`table` - 1 of them kmax
+    re-runs), and the layout of the final table; `layout` None where
+    the filter matched nothing and no table ran. The three dispatch
+    counts add up to what `profiled_device_get` counted on the
+    segment's profile."""
+    counts = {ServerMeter.GROUP_SEGMENTS: 1,
+              ServerMeter.GROUP_SCOUT_DISPATCHES: scout,
+              ServerMeter.GROUP_HIST_DISPATCHES: hist,
+              ServerMeter.GROUP_TABLE_DISPATCHES: table,
+              ServerMeter.GROUP_ESCALATIONS: max(table - 1, 0),
+              ServerMeter.GROUP_EMPTY if layout is None
+              else ServerMeter.GROUP_TABLES[layout]: 1}
+    for meters in _GROUP_METERS:
+        for name, n in counts.items():
+            if n:
+                meters[name].mark(n)
+
+
 # -- the device profiler, one session at a time ------------------------------
 
 PROFILER_ANCHOR = "pinot.profilerAnchor"

@@ -30,7 +30,9 @@ from pinot_tpu.common import expression as expr_mod
 from pinot_tpu.common.datatype import DataType
 from pinot_tpu.common.request import (AggregationInfo, BrokerRequest,
                                       FilterOperator, FilterQueryTree)
-from pinot_tpu.obs.profiler import count_path, profiled_device_get
+from pinot_tpu.common.metrics import ServerQueryPhase
+from pinot_tpu.obs.profiler import (count_path, mark_group_ladder, obs_span,
+                                    profiled_device_get)
 from pinot_tpu.ops import kernels
 from pinot_tpu.query.aggregation import AggregationFunction, make_functions
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
@@ -96,23 +98,51 @@ def with_valid_doc_mask(spec):
     return ("and", (VALID_DOC_PRED, spec))
 
 
-def resolve_filter(tree: Optional[FilterQueryTree], segment: ImmutableSegment
-                   ) -> Tuple[tuple, List]:
+def resolve_filter(tree: Optional[FilterQueryTree], segment: ImmutableSegment,
+                   keep_covering: bool = False) -> Tuple[tuple, List]:
+    """-> (the filter's static spec, its runtime params). With
+    `keep_covering` a range that covers a column's whole dictionary
+    stays a `range_ids` predicate beside the other conjuncts of its
+    AND (see `_covering_range`); alone it folds to MATCH_ALL as ever."""
     if tree is None:
         return MATCH_ALL, []
     params: List = []
-    spec = _resolve(tree, segment, params)
+    spec = _resolve(tree, segment, params, keep_covering)
     return spec, params
 
 
-def _resolve(node: FilterQueryTree, segment: ImmutableSegment, params: List
-             ) -> tuple:
+def _covering_range(node: FilterQueryTree, segment: ImmutableSegment):
+    """(spec, params) of a RANGE leaf that `_resolve_leaf` folded to
+    MATCH_ALL because its bounds cover the segment's whole dictionary,
+    as the predicate it would be with narrower bounds; None for any
+    other leaf. A group-by's programs are specialised on the filter's
+    STRUCTURE, so `d_year BETWEEN 1992 AND 1998` over a 1992-1998
+    dictionary would otherwise make a scout, a histogram rung and the
+    group tables of a second filter spec: programs that only the
+    literals of the widest band compile (PERF.md section 6, PR 35).
+    The bounds are runtime operands: one compare a row."""
+    if node.operator != FilterOperator.RANGE or \
+            expr_mod.is_expression(node.column):
+        return None
+    ds = segment.data_source(node.column)
+    if not ds.metadata.has_dictionary or not ds.metadata.single_value:
+        return None
+    lo, hi = ds.dictionary.range_to_id_interval(
+        node.lower, node.upper, node.lower_inclusive, node.upper_inclusive)
+    return (("pred", "range_ids", node.column, "sv", None),
+            [np.int32(lo), np.int32(hi)])
+
+
+def _resolve(node: FilterQueryTree, segment: ImmutableSegment, params: List,
+             keep_covering: bool = False) -> tuple:
     if node.operator in (FilterOperator.AND, FilterOperator.OR):
         is_and = node.operator == FilterOperator.AND
         children = []
+        narrowing = False        # a conjunct that is no covering range
         for c in node.children:
             sub_params: List = []
-            spec = _resolve(c, segment, sub_params)
+            spec = _resolve(c, segment, sub_params,
+                            keep_covering and is_and)
             if spec == EMPTY:
                 if is_and:
                     return EMPTY
@@ -120,9 +150,14 @@ def _resolve(node: FilterQueryTree, segment: ImmutableSegment, params: List
             if spec == MATCH_ALL:
                 if not is_and:
                     return MATCH_ALL
+                kept = _covering_range(c, segment) if keep_covering \
+                    else None
+                if kept is not None:
+                    children.append(kept)
                 continue
+            narrowing = True
             children.append((spec, sub_params))
-        if not children:
+        if not narrowing:
             return MATCH_ALL if is_and else EMPTY
         if len(children) == 1:
             params.extend(children[0][1])
@@ -549,7 +584,10 @@ class InstancePlanMaker:
                 plan.fast_path_result = blk
                 return plan
 
-        filter_spec, params = resolve_filter(request.filter, segment)
+        # a group-by's programs follow the filter's structure: keep it
+        # the same for every literal of a query template
+        filter_spec, params = resolve_filter(
+            request.filter, segment, keep_covering=request.is_group_by)
 
         if jctx is not None and filter_spec != EMPTY:
             # the join-match predicate ANDs in FIRST (its params precede
@@ -1013,17 +1051,52 @@ def escalate_group_kmax(group_spec: tuple, padded: int):
 
 def run_with_group_escalation(run, group_spec, padded: int):
     """run(group_spec) → device outs; re-runs up the kmax ladder while
-    the compacted group kernel reports overflow. Returns the HOST outs
-    and the final spec — all of a dispatch's outputs come over in ONE
-    explicit jax.device_get (per-scalar pulls like the old
-    `int(np.asarray(outs[...]))` overflow probe stall the pipeline once
-    per output; see docs/ANALYSIS.md host-sync)."""
+    the compacted group kernel reports overflow. Returns the HOST outs,
+    the final spec and the number of runs — all of a dispatch's outputs
+    come over in ONE explicit jax.device_get (per-scalar pulls like the
+    old `int(np.asarray(outs[...]))` overflow probe stall the pipeline
+    once per output; see docs/ANALYSIS.md host-sync)."""
     outs = profiled_device_get(run(group_spec))
+    runs = 1
     while group_spec is not None and int(outs.get("group.overflow", 0)) > 0:
         group_spec = escalate_group_kmax(group_spec, padded)
         assert group_spec is not None, "overflow at full kmax is impossible"
         outs = profiled_device_get(run(group_spec))
-    return outs, group_spec
+        runs += 1
+    return outs, group_spec, runs
+
+
+def group_layout(group_spec, padded: int) -> str:
+    """The table layout `ops/kernels.py` `_group_outputs` takes for
+    this spec over `padded` rows, by the names the kernels' own cases
+    have (`contract_cases`: group_dense, group_scatter, group_compacted,
+    group_ranked) and "sorted" for `_group_outputs_compacted_sorted`.
+    A name for spans and meters; nothing is chosen by it. (An MV
+    group-by's expansion is not followed.)"""
+    _gcols, _strides, g_pad, _agg_specs, kmax = group_spec
+    if not kmax:
+        return "dense" if g_pad <= kernels.DENSE_G_LIMIT and \
+            padded <= kernels.DENSE_ROWS_LIMIT else "scatter"
+    t = max(padded // kernels.CBLOCK, 1)
+    if min(max(-(-kmax // t), 8), kernels.CBLOCK) > 256:
+        return "sorted"
+    return "ranked" if g_pad > kernels.DENSE_G_LIMIT else "compacted"
+
+
+def _run_group_table(run, group_spec, padded: int, scouts: int, hists: int):
+    """Phase B under one `groupTable` span, the first run and its kmax
+    re-runs, and the segment's one mark on the ladder's meters, after
+    `scouts` and `hists` launches of the phases before it.
+    -> (host outs, final spec)."""
+    with obs_span(ServerQueryPhase.GROUP_TABLE) as span:
+        outs, final, runs = run_with_group_escalation(run, group_spec,
+                                                      padded)
+    layout = group_layout(final, padded)
+    if span is not None:
+        span["attrs"] = {"layout": layout, "g": final[2], "runs": runs,
+                         "scouted": bool(scouts)}
+    mark_group_ladder(scouts, hists, runs, layout)
+    return outs, final
 
 
 RANK_HIST_CARD_LIMIT = int(os.environ.get(
@@ -1243,36 +1316,44 @@ def drive_group_execution(run, group_spec, padded: int, total_docs: int):
 
     Returns (outs, group_spec_for_finish); None finish spec means the
     filter matched nothing (outs still carries the stats).
+
+    Each phase runs under a span of its own (`groupScout`, `groupHist`,
+    `groupTable` with the layout, the key space and the runs) around
+    its launches and pulls, and every segment marks the ladder's meters
+    once (`mark_group_ladder`: with its table, or where the filter
+    matched nothing): what a segment took, not what it costs.
     """
     pa = adaptive_phase_a_specs(group_spec) \
         if padded <= kernels.DENSE_ROWS_LIMIT else None
-    if pa is not None:
-        # one batched device→host transfer per scout dispatch; the
-        # per-bound int() reads below are host numpy, not device pulls
+    if pa is None:
+        return _run_group_table(lambda gs: run((), gs, ()), group_spec,
+                                padded, 0, 0)
+    # one batched device→host transfer per scout dispatch; the
+    # per-bound int() reads below are host numpy, not device pulls
+    with obs_span(ServerQueryPhase.GROUP_SCOUT):
         ha = profiled_device_get(run(pa, None, ()))
-        bounds = [(int(ha[f"agg{2 * i}.min"]), int(ha[f"agg{2 * i + 1}.max"]))
-                  for i in range(len(pa) // 2)]
-        matched = int(ha["stats.num_docs_matched"])
-        scout = [("bounds", lo, hi) for lo, hi in bounds]
-        if matched > 0:
-            ph = adaptive_hist_specs(group_spec, bounds)
-            if ph is not None:
-                hh = profiled_device_get(run(ph, None, ()))
-                scout = [("present",
-                          np.nonzero(np.asarray(hh[f"agg{i}"])[: c[3]])[0])
-                         for i, c in enumerate(group_spec[0])]
-        kspec, fspec, extra, empty = adaptive_phase_b_spec(
-            group_spec, scout, matched, padded, total_docs)
-        if empty:
-            return ha, None
-        outs, final = run_with_group_escalation(
-            lambda gs: run((), gs, extra), kspec, padded)
-        if final is not kspec:            # ladder escalated kmax
-            fspec = fspec[:4] + (final[4],)
-        return outs, fspec
-    return run_with_group_escalation(lambda gs: run((), gs, ()),
-                                     group_spec, padded)
-
+    bounds = [(int(ha[f"agg{2 * i}.min"]), int(ha[f"agg{2 * i + 1}.max"]))
+              for i in range(len(pa) // 2)]
+    matched = int(ha["stats.num_docs_matched"])
+    scout = [("bounds", lo, hi) for lo, hi in bounds]
+    ph = adaptive_hist_specs(group_spec, bounds) if matched > 0 else None
+    if ph is not None:
+        with obs_span(ServerQueryPhase.GROUP_HIST):
+            hh = profiled_device_get(run(ph, None, ()))
+        scout = [("present",
+                  np.nonzero(np.asarray(hh[f"agg{i}"])[: c[3]])[0])
+                 for i, c in enumerate(group_spec[0])]
+    hist = int(ph is not None)
+    kspec, fspec, extra, empty = adaptive_phase_b_spec(
+        group_spec, scout, matched, padded, total_docs)
+    if empty:
+        mark_group_ladder(1, hist, 0, None)
+        return ha, None
+    outs, final = _run_group_table(lambda gs: run((), gs, extra), kspec,
+                                   padded, 1, hist)
+    if final is not kspec:            # ladder escalated kmax
+        fspec = fspec[:4] + (final[4],)
+    return outs, fspec
 
 
 def _agg_device_spec(f: AggregationFunction, segment: ImmutableSegment,

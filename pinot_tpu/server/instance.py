@@ -78,6 +78,9 @@ class ServerInstance:
         # cubeDescentsNative / cubeDescentsNumpy, at 0 from boot
         from pinot_tpu.obs.profiler import bind_cube_metrics
         bind_cube_metrics(self.metrics)
+        # the group-by ladder's meters, at 0 from boot
+        from pinot_tpu.obs.profiler import bind_group_metrics
+        bind_group_metrics(self.metrics)
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)
         self.data_manager = InstanceDataManager()

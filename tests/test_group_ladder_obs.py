@@ -1,0 +1,369 @@
+"""The device group-by ladder under spans and meters (PR 35).
+
+`query/plan.py` `drive_group_execution` runs a scout, where it pays a
+histogram rung, and a group table with its kmax re-runs; each phase is
+a span (`groupScout`, `groupHist`, `groupTable`) around its launches
+and pulls, and every exit marks the ladder's meters once
+(`obs/profiler.py` `mark_group_ladder`). Held here to what ran: SSB's
+13 shapes over small dbgen segments without cubes, built as the
+benchmark builds them, on the CPU. Nothing here is a measurement.
+"""
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(REPO, "benchmarks")
+for p in (REPO, BENCH_DIR):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+from pinot_tpu.common.metrics import (MetricsRegistry,      # noqa: E402
+                                      ServerMeter, ServerQueryPhase)
+from pinot_tpu.obs import profiler as obs_profiler          # noqa: E402
+from pinot_tpu.obs.tracing import TraceContext, build_trace_tree  # noqa: E402
+
+ROWS, SEGMENTS, SEED = 60_000, 2, 5
+GROUP_SPANS = (ServerQueryPhase.GROUP_SCOUT, ServerQueryPhase.GROUP_HIST,
+               ServerQueryPhase.GROUP_TABLE)
+DISPATCH_METERS = (ServerMeter.GROUP_SCOUT_DISPATCHES,
+                   ServerMeter.GROUP_HIST_DISPATCHES,
+                   ServerMeter.GROUP_TABLE_DISPATCHES)
+LADDER_METERS = (ServerMeter.GROUP_SEGMENTS, *DISPATCH_METERS,
+                 ServerMeter.GROUP_ESCALATIONS, ServerMeter.GROUP_EMPTY,
+                 *ServerMeter.GROUP_TABLES.values())
+
+
+def _walk(node, parent=None):
+    yield node, parent
+    for child in node.get("children") or ():
+        yield from _walk(child, node)
+
+
+class Ladder:
+    """Small SSB segments without cubes, an executor over them, and a
+    registry of this test's own with the ladder's meters bound."""
+
+    def __init__(self, base: str):
+        from harness import build, cells, shapes, tables
+        from pinot_tpu.segment.loader import ImmutableSegmentLoader
+        config = dict(cells.load_json(BENCH_DIR, "configs",
+                                      "ssb_flat_nocube.json"),
+                      rows=ROWS, segments=SEGMENTS)
+        self.segments = [
+            ImmutableSegmentLoader.load(build.build_segment(
+                (config, SEED, i, hi - lo, base)))
+            for i, (lo, hi) in enumerate(
+                tables.segment_bounds(ROWS, SEGMENTS))]
+        table = tables.make_table(tables.load_generator("ssb_dbgen"),
+                                  ROWS, SEGMENTS, SEED)
+        self.pools = table.pools
+        self.shapes = {s.name: s for s in shapes.load_family(
+            BENCH_DIR, "ssb", table.pools)}
+        self.metrics = MetricsRegistry("server")
+        obs_profiler.bind_group_metrics(self.metrics)
+
+    def meters(self) -> dict:
+        return {m: self.metrics.meter(m).count for m in LADDER_METERS}
+
+    def run(self, pql: str, traced: bool):
+        """-> (the reduced answer as JSON, the query's profile, the
+        trace's spans, what the ladder's meters grew by)."""
+        from pinot_tpu.pql.optimizer import BrokerRequestOptimizer
+        from pinot_tpu.pql.parser import compile_pql
+        from pinot_tpu.query.executor import ServerQueryExecutor
+        from pinot_tpu.query.plan import preprocess_request
+        from pinot_tpu.query.reduce import BrokerReduceService
+        request = preprocess_request(
+            self.segments,
+            BrokerRequestOptimizer().optimize(compile_pql(pql)))
+        profile = obs_profiler.QueryProfile("lineorder")
+        trace = TraceContext(root_name="server") if traced else None
+        before = self.meters()
+        with obs_profiler.active(profile, None):
+            block = ServerQueryExecutor().execute(request, self.segments,
+                                                  trace=trace)
+        grown = {m: n - before[m] for m, n in self.meters().items()}
+        answer = BrokerReduceService().reduce(request, [block]).to_json()
+        for key in ("timeUsedMs", "traceInfo"):
+            answer.pop(key, None)
+        return (answer, profile.to_json(),
+                trace.to_list() if traced else [], grown)
+
+    def shape(self, name: str, traced: bool = True):
+        s = self.shapes[name]
+        return self.run(s.pql(s.spec["ssb"]), traced)
+
+
+@pytest.fixture(scope="module")
+def ladder(tmp_path_factory):
+    return Ladder(str(tmp_path_factory.mktemp("ladder_segments")))
+
+
+GROUP_BYS = ["q2.1", "q2.2", "q2.3", "q3.1", "q3.2", "q3.3", "q3.4",
+             "q4.1", "q4.2", "q4.3"]
+
+
+@pytest.fixture(scope="module")
+def traced_runs(ladder):
+    """Every SSB shape once, traced: {shape: (answer, profile, spans,
+    grown meters)}."""
+    return {name: ladder.shape(name)
+            for name in ["q1.1", "q1.2", "q1.3"] + GROUP_BYS}
+
+
+def test_the_ladders_meters_read_zero_at_boot():
+    from pinot_tpu.server.instance import ServerInstance
+    server = ServerInstance("server_group_ladder")
+    try:
+        snap = server.metrics.snapshot()
+        for meter in LADDER_METERS:
+            assert snap[f"meter.{meter}.count"] == 0, meter
+    finally:
+        server.stop()
+
+
+@pytest.mark.parametrize("shape", GROUP_BYS)
+def test_a_traced_group_by_carries_the_ladders_spans(traced_runs, shape):
+    _answer, _profile, spans, grown = traced_runs[shape]
+    tree = build_trace_tree(spans)
+    plans = [n for n, _p in _walk(tree)
+             if n["name"] == ServerQueryPhase.QUERY_PLAN_EXECUTION]
+    assert len(plans) == SEGMENTS
+    n_hist = 0
+    for plan in plans:
+        phases = [c for c in plan["children"] if c["name"] in GROUP_SPANS]
+        names = [c["name"] for c in phases]
+        # a scout always; a table unless the filter matched nothing
+        assert names[0] == ServerQueryPhase.GROUP_SCOUT
+        assert names.count(ServerQueryPhase.GROUP_SCOUT) == 1
+        assert names.count(ServerQueryPhase.GROUP_TABLE) <= 1
+        n_hist += names.count(ServerQueryPhase.GROUP_HIST)
+        for phase in phases:
+            inside = [c["name"] for c in phase["children"]]
+            runs = (phase.get("attrs") or {}).get("runs", 1)
+            # each launch and the pull that waits for it, in order
+            assert inside == [ServerQueryPhase.KERNEL_LAUNCH,
+                              ServerQueryPhase.KERNEL_DISPATCH] * runs
+        # no launch of a group-by outside its phase's span
+        assert ServerQueryPhase.KERNEL_LAUNCH not in \
+            [c["name"] for c in plan["children"]]
+        assert sum(c["ms"] for c in phases) <= plan["ms"]
+        for table in phases:
+            if table["name"] != ServerQueryPhase.GROUP_TABLE:
+                continue
+            attrs = table["attrs"]
+            assert attrs["layout"] in ServerMeter.GROUP_TABLES
+            assert attrs["scouted"] is True and attrs["runs"] >= 1
+            assert attrs["g"] >= 1 and attrs["g"] & (attrs["g"] - 1) == 0
+    # the histogram rung's span exactly where the rung ran
+    assert n_hist == grown[ServerMeter.GROUP_HIST_DISPATCHES]
+
+
+def test_the_histogram_rung_runs_for_q31_and_not_for_q21(traced_runs):
+    # q3.1's five nations of a region lie scattered in the sorted
+    # dictionary of 25; q2.1's brands of one category lie side by side
+    assert traced_runs["q3.1"][3][ServerMeter.GROUP_HIST_DISPATCHES] == \
+        SEGMENTS
+    assert traced_runs["q2.1"][3][ServerMeter.GROUP_HIST_DISPATCHES] == 0
+
+
+def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
+    total = {m: 0 for m in LADDER_METERS}
+    dispatches = 0
+    for name in GROUP_BYS:
+        _answer, profile, spans, grown = traced_runs[name]
+        assert profile["paths"] == {"scan": SEGMENTS}
+        # every dispatch of a group-by is a phase of the ladder
+        assert sum(grown[m] for m in DISPATCH_METERS) == \
+            profile["kernelDispatches"]
+        # one table at most a segment, in one layout
+        tables = sum(grown[m] for m in ServerMeter.GROUP_TABLES.values())
+        assert tables + grown[ServerMeter.GROUP_EMPTY] == SEGMENTS
+        assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == \
+            tables + grown[ServerMeter.GROUP_ESCALATIONS]
+        assert tables == sum(1 for s in spans if s["name"] ==
+                             ServerQueryPhase.GROUP_TABLE)
+        dispatches += profile["kernelDispatches"]
+        for m in LADDER_METERS:
+            total[m] += grown[m]
+    assert total[ServerMeter.GROUP_SEGMENTS] == len(GROUP_BYS) * SEGMENTS
+    assert total[ServerMeter.GROUP_SCOUT_DISPATCHES] == \
+        len(GROUP_BYS) * SEGMENTS
+    assert sum(total[m] for m in DISPATCH_METERS) == dispatches
+
+
+@pytest.mark.parametrize("shape", ["q1.1", "q1.2", "q1.3"])
+def test_a_scan_without_group_by_marks_nothing(traced_runs, shape):
+    _answer, profile, spans, grown = traced_runs[shape]
+    assert profile["kernelDispatches"] == SEGMENTS
+    assert not any(grown.values())
+    assert not [s for s in spans if s["name"] in GROUP_SPANS]
+
+
+def test_a_filter_that_matches_nothing_marks_empty_and_runs_no_table(ladder):
+    # every value is in the dictionaries, so each predicate alone
+    # matches rows; no customer of the first city lives in the last
+    # nation, which only the device can tell
+    city, nation = ladder.pools["c_city"][0], ladder.pools["c_nation"][-1]
+    assert not str(city).startswith(str(nation)[:9])
+    pql = (f"SELECT SUM(lo_revenue) FROM lineorder WHERE c_city = '{city}' "
+           f"AND c_nation = '{nation}' GROUP BY d_year TOP 100")
+    answer, profile, spans, grown = ladder.run(pql, traced=True)
+    assert answer["aggregationResults"][0]["groupByResult"] == []
+    assert grown[ServerMeter.GROUP_SEGMENTS] == SEGMENTS
+    assert grown[ServerMeter.GROUP_EMPTY] == SEGMENTS
+    assert grown[ServerMeter.GROUP_SCOUT_DISPATCHES] == SEGMENTS
+    assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == 0
+    assert not any(grown[m] for m in ServerMeter.GROUP_TABLES.values())
+    assert profile["kernelDispatches"] == SEGMENTS
+    assert not [s for s in spans
+                if s["name"] == ServerQueryPhase.GROUP_TABLE]
+
+
+@pytest.mark.parametrize("shape", ["q1.2", "q2.1", "q3.1", "q3.4", "q4.3"])
+def test_tracing_changes_neither_the_answer_nor_what_is_launched(
+        ladder, traced_runs, shape):
+    answer, profile, _spans, grown = traced_runs[shape]
+    plain, plain_profile, no_spans, plain_grown = ladder.shape(
+        shape, traced=False)
+    assert no_spans == []
+    assert plain == answer
+    assert plain_profile["kernelDispatches"] == profile["kernelDispatches"]
+    assert plain_profile["deviceTransferBytes"] == \
+        profile["deviceTransferBytes"]
+    assert plain_grown == grown
+
+
+def test_an_unscouted_group_by_is_a_table_that_says_so(ladder):
+    # no filter: nothing for a scout to narrow, the table runs alone
+    answer, profile, spans, grown = ladder.run(
+        "SELECT COUNT(*) FROM lineorder GROUP BY d_year TOP 10", traced=True)
+    assert len(answer["aggregationResults"][0]["groupByResult"]) == 7
+    tables = [s for s in spans if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    assert len(tables) == SEGMENTS
+    assert all(t["attrs"]["scouted"] is False and
+               t["attrs"]["layout"] == "dense" for t in tables)
+    assert not [s for s in spans
+                if s["name"] in (ServerQueryPhase.GROUP_SCOUT,
+                                 ServerQueryPhase.GROUP_HIST)]
+    assert grown[ServerMeter.GROUP_SEGMENTS] == SEGMENTS
+    assert grown[ServerMeter.GROUP_SCOUT_DISPATCHES] == 0
+    assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == SEGMENTS == \
+        profile["kernelDispatches"]
+    assert grown[ServerMeter.GROUP_TABLES["dense"]] == SEGMENTS
+
+
+def test_a_kmax_re_run_is_counted_as_an_escalation():
+    """The ladder's own loop, with a kernel that reports overflow
+    twice: three launches under one table, two of them re-runs."""
+    from pinot_tpu.query import plan
+    reg = MetricsRegistry("server")
+    obs_profiler.bind_group_metrics(reg)
+    spec = ((("d0", "mvids", 0, 8),), (1,), 8, (), 1024)   # unscouted
+    launched = []
+
+    def run(agg_specs, group_spec, extra):
+        launched.append(group_spec[4])
+        return {"group.overflow": int(len(launched) < 3),
+                "stats.num_docs_matched": 1}
+
+    trace = TraceContext(root_name="server")
+    with obs_profiler.active(obs_profiler.QueryProfile("t"), trace):
+        _outs, final = plan.drive_group_execution(run, spec, 1 << 20, 1000)
+    assert launched == [1024, 4096, 16384] and final[4] == 16384
+    (table,) = [s for s in trace.to_list()
+                if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    assert table["attrs"] == {"layout": "compacted", "g": 8, "runs": 3,
+                              "scouted": False}
+    count = {m: reg.meter(m).count for m in LADDER_METERS}
+    assert count[ServerMeter.GROUP_TABLE_DISPATCHES] == 3
+    assert count[ServerMeter.GROUP_ESCALATIONS] == 2
+    assert count[ServerMeter.GROUP_SEGMENTS] == 1
+    assert count[ServerMeter.GROUP_TABLES["compacted"]] == 1
+
+
+def test_layout_names_are_the_kernels_own_cases():
+    """`plan.group_layout` names a spec's layout as `ops/kernels.py`
+    names the contract case that exercises it."""
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.query.plan import group_layout
+    seen = set()
+    for name, _filt, _aggs, group_spec, *_rest in kernels.contract_cases():
+        layout = name.split("_", 1)[1] if name.startswith("group_") \
+            else None
+        if layout in ServerMeter.GROUP_TABLES:
+            for bucket in kernels.CONTRACT_SHAPE_BUCKETS:
+                assert group_layout(group_spec, bucket) == layout, name
+            seen.add(layout)
+    assert seen == {"dense", "scatter", "compacted", "ranked"}
+    # past r = 256 rows a block the compacted kernel sorts instead
+    spec = ((("d0", "ids", 0, 8),), (1,), 8, (), 8192 * 512 // 4)
+    assert group_layout(spec, 8192) == "sorted"
+
+
+# -- one filter structure a query template ---------------------------------
+
+Q31 = ("SELECT SUM(lo_revenue) FROM lineorder WHERE c_region = 'ASIA' AND "
+       "s_region = 'ASIA'{years} GROUP BY c_nation, s_nation, d_year "
+       "TOP 10000")
+
+
+def _segment_plan(ladder, pql):
+    from pinot_tpu.pql.optimizer import BrokerRequestOptimizer
+    from pinot_tpu.pql.parser import compile_pql
+    from pinot_tpu.query.plan import InstancePlanMaker, preprocess_request
+    request = preprocess_request(
+        ladder.segments,
+        BrokerRequestOptimizer().optimize(compile_pql(pql)))
+    return InstancePlanMaker().make_segment_plan(ladder.segments[0], request)
+
+
+def test_a_covering_range_keeps_a_group_bys_filter_structure(ladder):
+    """dbgen's years are 1992-1998, so `d_year BETWEEN 1992 AND 1998`
+    covers the dictionary. Beside other conjuncts of a group-by it stays
+    a runtime `range_ids` predicate: the widest band of a template runs
+    the programs every other band compiled, and answers as the query
+    without the predicate does."""
+    from pinot_tpu.query import plan
+    narrow = _segment_plan(ladder, Q31.format(
+        years=" AND d_year BETWEEN 1993 AND 1997"))
+    covering = _segment_plan(ladder, Q31.format(
+        years=" AND d_year BETWEEN 1992 AND 1998"))
+    without = _segment_plan(ladder, Q31.format(years=""))
+    assert covering.filter_spec == narrow.filter_spec != without.filter_spec
+    assert covering.group_spec == narrow.group_spec
+    assert [type(p) for p in covering.params] == \
+        [type(p) for p in narrow.params]
+    card = ladder.segments[0].data_source("d_year").dictionary.cardinality
+    at = 0                      # params are the leaves', depth first
+    for leaf in covering.filter_spec[1]:
+        if leaf == ("pred", "range_ids", "d_year", "sv", None):
+            break
+        at += {"eq_id": 1, "range_ids": 2}[leaf[1]]
+    else:
+        raise AssertionError(covering.filter_spec)
+    assert [int(p) for p in covering.params[at:at + 2]] == [0, card]
+    answer, _profile, _spans, grown = ladder.run(
+        Q31.format(years=" AND d_year BETWEEN 1992 AND 1998"), False)
+    plain, *_ = ladder.run(Q31.format(years=""), False)
+    # the same groups and sums; the stats count one more filter leaf
+    assert answer["aggregationResults"] == plain["aggregationResults"]
+    assert answer["aggregationResults"][0]["groupByResult"]
+    assert answer["numDocsScanned"] == plain["numDocsScanned"]
+    assert grown[ServerMeter.GROUP_SEGMENTS] == SEGMENTS
+    # alone, and outside a group-by, it folds as it always did
+    alone = _segment_plan(
+        ladder, "SELECT SUM(lo_revenue) FROM lineorder WHERE d_year "
+        "BETWEEN 1992 AND 1998 GROUP BY d_year TOP 10")
+    assert alone.filter_spec == plan.MATCH_ALL and not alone.params
+    scalar = _segment_plan(
+        ladder, "SELECT SUM(lo_revenue) FROM lineorder WHERE d_year "
+        "BETWEEN 1992 AND 1998 AND lo_discount BETWEEN 1 AND 3")
+    assert ("pred", "range_ids", "d_year", "sv", None) != \
+        scalar.filter_spec and "d_year" not in repr(scalar.filter_spec)
+    count = _segment_plan(
+        ladder, "SELECT COUNT(*) FROM lineorder WHERE d_year BETWEEN 1992 "
+        "AND 1998")
+    assert count.fast_path_result is not None
