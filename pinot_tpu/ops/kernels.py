@@ -412,132 +412,131 @@ def _mxu_histogram(ids, mask, card_pad: int):
     return out
 
 
-def _dense_group_count(key, mask, g_pad: int):
-    """Per-group match counts — a histogram over group keys."""
-    return _mxu_histogram(key, mask, g_pad)
+def _bf16_pieces(v):
+    """float32 lane -> three bf16 lanes whose float32 sum is v, EXACTLY:
+    each piece is the top 8 significand bits of what is left (8 + 8 + 8
+    cover float32's 24), cut out by a bit mask so that no compiler pass
+    can read the cut as a removable f32 -> bf16 -> f32 round trip. A 0/1
+    one-hot times a piece is exact on the MXU at its DEFAULT precision,
+    so a float lane rides the bf16 operand instead of paying the six
+    passes of Precision.HIGHEST for an M of a few rows."""
+    pieces = []
+    for _ in range(3):
+        top = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(v, jnp.uint32)
+            & np.uint32(0xFFFF0000), jnp.float32)
+        pieces.append(top.astype(jnp.bfloat16))
+        v = v - top
+    return pieces
 
 
-def _dense_group_part_sums(part_lanes, key, mask, g_pad: int,
-                           with_count: bool = False):
-    """Exact per-group sums of 7-bit part lanes via MXU: int32 [n_parts, g].
+def _onehot_lane_sums(lanes, n_int: int, key, mask, g_pad: int, mm):
+    """Masked per-group sums of row lanes in ONE pass over the rows:
+    (int32 [n_int, g_pad], float [len(lanes) - n_int, g_pad]).
 
-    part_lanes: list of 1-D [P] lanes — per-lane [T, b] blocking avoids
-    any small-extent tile axis. Carry-accumulated int32; planner
-    guarantees padded <= DENSE_ROWS_LIMIT so 127 * rows < 2^31.
+    lanes: [P] arrays of dtype `mm` (bf16, or f64 under x64), `None` for
+    the group COUNT (the mask itself: it shares the one-hot build, which
+    dominates at dense SSB shapes). The first n_int lanes hold integers
+    of at most 7 bits: their per-block f32 cells (<= 127 * 8192 < 2^24)
+    and the int32 sum across blocks (127 * DENSE_ROWS_LIMIT < 2^31) are
+    exact. The others are float lanes, accumulated in f32 (f64).
 
-    with_count=True folds the group COUNT in as one more summed lane
-    (the mask itself), sharing the per-chunk one-hot build — at dense
-    SSB shapes the one-hots dominate, so count-plus-parts in one scan
-    runs ~2x faster than separate histogram + part-sum passes. Returns
-    (sums [n_parts, g], counts [g]) then; sums alone otherwise.
-    """
-    n_parts = len(part_lanes)
-    n_l = n_parts + (1 if with_count else 0)
-    radix = g_pad >= RADIX_G
-    gp = _radix_pad(g_pad)
+    BATCHED per-block partials, no lax.scan, whenever the operand widths
+    allow. Measured on the v5e dense floor (q3.1 big-synth, 100M rows,
+    round 3; PR 36 at 6.25M rows: scripts/dense_table_cost.py):
+    - a scan carry SERIALIZES the per-step dots: 164ms scan vs 98ms
+      batched at g=512 (and ~10x the compile time);
+    - s8 x s8 -> s32 dots are a SLOW path on this XLA stack (227ms vs
+      161ms bf16);
+    - per-lane dots paid one full MXU row stream PER LANE (the
+      g-independent ~390ms round-2 floor); folding every lane into the
+      narrow hi factor and concatenating into one operand lets ALL
+      lanes share one stream.
+    The mask multiplies into the one-hot ONCE (ohm), so the lanes need
+    no row-scale where() prep for it. Wide tables (n_l * g1 > 128, e.g.
+    un-remapped g=8192 with 6 lanes) break the batched einsum's compile
+    (the concat operand stops fusing), so they fall back to a scan with
+    a per-step concat dot (f32-exact at b <= 2^17; _tile_rows caps b at
+    2^16) - the adaptive hist rung exists to remap those into the
+    batched regime."""
+    n_l, n = len(lanes), key.shape[0]
+    facc = jnp.float64 if mm == jnp.float64 else jnp.float32
+    # g_pad == RADIX_G stays DIRECT here: the 512-wide one-hot fuses into
+    # the dot and nothing row-scale materializes, where the radix form
+    # writes its [t, B, g1] hi-folded operand a lane (PR 36, 6.25M rows,
+    # g 512: float + count 6.5 ms / 14 MB temp against 6.9 ms / 152 MB,
+    # 4 part lanes + count 6.8 ms / 151 MB against 8.3 ms / 458 MB)
+    radix = g_pad > RADIX_G
+    gp = _radix_pad(g_pad) if radix else g_pad
     g1 = gp // RADIX_LO
-    n = key.shape[0]
-    # BATCHED per-block partials — no lax.scan — whenever the operand
-    # widths allow. Three measured lessons from the v5e dense floor
-    # (q3.1 big-synth, 100M rows, round 3):
-    # - the scan carry SERIALIZED the per-step dots: 164ms scan vs 98ms
-    #   batched at g=512 (and ~10x the compile time);
-    # - s8 x s8 -> s32 dots are a SLOW path on this XLA stack (227ms vs
-    #   161ms bf16) — bf16 operands + f32 accumulation stay exact for
-    #   7-bit values because each per-block cell sums <= 127 * 8192
-    #   < 2^24;
-    # - per-lane dots paid one full MXU row stream PER LANE (the
-    #   g-independent ~390ms round-2 floor); folding every lane into
-    #   the narrow hi factor and concatenating into one operand lets
-    #   ALL lanes share one stream.
-    # The mask multiplies into the one-hot ONCE (ohm), so value lanes
-    # need no row-scale where() prep. Cross-block combine is an exact
-    # int32 tree-sum (127 * DENSE_ROWS_LIMIT < 2^31). Wide tables
-    # (n_l * g1 > 128, e.g. un-remapped g=8192 with 6 lanes) break the
-    # batched einsum's compile (the concat operand stops fusing), so
-    # they fall back to the scan-with-concat form — the adaptive hist
-    # rung exists precisely to remap those into the batched regime.
-    if radix and n_l * g1 <= RADIX_LO:
-        t = n // BLOCK
-        kb = key.reshape(t, BLOCK)
-        mb = mask.astype(jnp.bfloat16).reshape(t, BLOCK)
-        oh_hi = _cmp_onehot(kb // RADIX_LO, g1, jnp.bfloat16)
-        oh_lo = _cmp_onehot(kb % RADIX_LO, RADIX_LO, jnp.bfloat16)
-        ohm = oh_hi * mb[:, :, None]                      # [t, B, g1]
-        a = jnp.concatenate(
-            [ohm * l.reshape(t, BLOCK).astype(jnp.bfloat16)[:, :, None]
-             for l in part_lanes] + ([ohm] if with_count else []),
-            axis=2)                                       # [t, B, n_l*g1]
-        s = jnp.einsum("tbx,tbc->txc", a, oh_lo,
-                       preferred_element_type=jnp.float32)
-        out = s.astype(jnp.int32).sum(axis=0).reshape(
-            n_l, g1 * RADIX_LO)[:, :g_pad]
-    elif not radix:
-        t = n // BLOCK
-        kb = key.reshape(t, BLOCK)
-        mb = mask.astype(jnp.bfloat16).reshape(t, BLOCK)
-        oh = _cmp_onehot(kb, g_pad, jnp.bfloat16)           # [t, B, g]
-        st = jnp.stack(
-            [mb * l.reshape(t, BLOCK).astype(jnp.bfloat16)
-             for l in part_lanes] + ([mb] if with_count else []),
-            axis=1)                                       # [t, n_l, B]
-        s = jnp.einsum("tlb,tbg->tlg", st, oh,
-                       preferred_element_type=jnp.float32)
-        out = s.astype(jnp.int32).sum(axis=0)
-    else:
-        # wide-table scan fallback: per-step concat dot, f32-exact at
-        # b <= 2^17 (127 * 2^17 < 2^24); _tile_rows caps b at 2^16
-        b = _tile_rows(max(n_l * g1 // 2, RADIX_LO), n)
-        key_b = key.reshape(-1, b)
-        mb = mask.astype(jnp.bfloat16).reshape(-1, b)
-        lanes_b = tuple(l.reshape(-1, b) for l in part_lanes)
+    batched = not radix or n_l * g1 <= RADIX_LO
+    b = BLOCK if batched else _tile_rows(max(n_l * g1 // 2, RADIX_LO), n)
 
-        def body(carry, tb):
-            k, m = tb[0], tb[1]
-            cs = tb[2:]
-            oh_hi, oh_lo = _radix_onehots(k, gp, jnp.bfloat16)
-            ohm = oh_hi * m[:, None]
-            a = jnp.concatenate(
-                [ohm * c.astype(jnp.bfloat16)[:, None] for c in cs]
-                + ([ohm] if with_count else []), axis=1)
-            s = jnp.matmul(a.T, oh_lo,
-                           preferred_element_type=jnp.float32)
-            return carry + s.reshape(n_l, g1 * RADIX_LO)[
-                :, :g_pad].astype(jnp.int32), None
-
-        out, _ = jax.lax.scan(body,
-                              jnp.zeros((n_l, g_pad), jnp.int32),
-                              (key_b, mb) + lanes_b)
-    if with_count:
-        return out[:n_parts], out[n_parts]
-    return out
-
-
-def _dense_group_float_sums(vals, key, mask, g_pad: int):
-    """Per-group float sums via MXU (f32 carry; f64 under x64): [g_pad]."""
-    acc = sum_dtype()
-    mm_dtype = acc if acc == jnp.float64 else jnp.float32
-    b = _tile_rows(g_pad, key.shape[0])
-    contrib = jnp.where(mask, vals.astype(mm_dtype), 0)
-    key_b = key.reshape(-1, b)
-    cb = contrib.reshape(-1, b)
-    radix = g_pad >= RADIX_G
-    gp = _radix_pad(g_pad)
-
-    def body(carry, tb):
-        k, c = tb
+    def cells(k, m, cs):
+        """[.., b] key, mask and lanes -> [.., n_l * gp] per-block sums."""
+        cs = iter(cs)
         if radix:
-            oh_hi, oh_lo = _radix_onehots(k, gp, mm_dtype)
-            s = _radix_group_sum(oh_hi, oh_lo, c, g_pad, mm_dtype)
+            oh_hi, oh_lo = _radix_onehots(k, gp, mm)
+            ohm = oh_hi * m[..., None]                      # [.., b, g1]
+            a = jnp.concatenate(
+                [ohm if l is None else ohm * next(cs)[..., None]
+                 for l in lanes], axis=-1)                  # [.., b, n_l*g1]
+            s = jnp.einsum("...bx,...bc->...xc", a, oh_lo,
+                           preferred_element_type=facc)
         else:
-            onehot = _cmp_onehot(k, g_pad, mm_dtype)
-            s = jnp.matmul(c[None, :], onehot,
-                           preferred_element_type=mm_dtype,
-                           precision=_EXACT_F32)[0]
-        return carry + s, None
+            st = jnp.stack([m if l is None else m * next(cs)
+                            for l in lanes], axis=-2)       # [.., n_l, b]
+            s = jnp.einsum("...lb,...bg->...lg", st,
+                           _cmp_onehot(k, g_pad, mm),
+                           preferred_element_type=facc)
+        return s.reshape(s.shape[:-2] + (n_l * gp,))
 
-    out, _ = jax.lax.scan(body, jnp.zeros(g_pad, mm_dtype), (key_b, cb))
-    return out
+    xs = (key.reshape(-1, b), mask.astype(mm).reshape(-1, b),
+          tuple(l.reshape(-1, b) for l in lanes if l is not None))
+    if batched:      # exact int32 / float tree-sums across the blocks
+        s = cells(*xs)
+        out = (s[:, :n_int * gp].astype(jnp.int32).sum(axis=0),
+               s[:, n_int * gp:].sum(axis=0))
+    else:
+        def body(carry, tb):
+            s = cells(*tb)
+            return (carry[0] + s[:n_int * gp].astype(jnp.int32),
+                    carry[1] + s[n_int * gp:]), None
+
+        out, _ = jax.lax.scan(
+            body, (jnp.zeros(n_int * gp, jnp.int32),
+                   jnp.zeros((n_l - n_int) * gp, facc)), xs)
+    return tuple(o.reshape(-1, gp)[:, :g_pad] for o in out)
+
+
+def _dense_group_sums(part_lanes, val_lanes, key, mask, g_pad: int,
+                      with_count: bool = False):
+    """Every summed lane of a dense group table, and its count, in one
+    pass: (int32 [n_parts, g_pad] exact sums of the 7-bit part lanes,
+    sum_dtype() [n_vals, g_pad] sums of the float / raw value lanes,
+    int32 [g_pad] match counts or None).
+
+    Under x64 (the CPU parity tests) the value lanes stay f64 through
+    their own contraction; on the f32 device path each rides the part
+    lanes' bf16 operand as three exact pieces (_bf16_pieces), summed in
+    f32: products exact, accumulation f32, as Precision.HIGHEST gave."""
+    acc = sum_dtype()
+    ints = [l.astype(jnp.bfloat16) for l in part_lanes] + \
+        [None] * with_count
+    vals = [jnp.where(mask, v.astype(acc), 0) for v in val_lanes]
+    if acc == jnp.float64:
+        isum = _onehot_lane_sums(ints, len(ints), key, mask, g_pad,
+                                 jnp.bfloat16)[0] if ints else None
+        vsum = _onehot_lane_sums(vals, 0, key, mask, g_pad,
+                                 acc)[1] if vals else None
+    else:
+        pieces = [p for v in vals for p in _bf16_pieces(v)]
+        isum, psum = _onehot_lane_sums(ints + pieces, len(ints), key, mask,
+                                       g_pad, jnp.bfloat16)
+        vsum = psum.reshape(len(vals), 3, g_pad).sum(axis=1)
+    n_p = len(part_lanes)
+    return (None if isum is None else isum[:n_p], vsum,
+            isum[n_p] if with_count else None)
 
 
 def _dense_group_extreme(ids_or_vals, key, mask, g_pad: int, sentinel,
@@ -887,8 +886,8 @@ def _slot_sum_tables(gslot, t_slots: int, int_vals, f32_vals, count_mask):
             # would break the contract), with an all-true row mask;
             # invalid rows land in the drop slot, which is sliced off
             lanes.append(jnp.pad(count_mask, (0, kp - k)).astype(jnp.int8))
-        out = _dense_group_part_sums(lanes, gs_p, jnp.ones(kp, bool),
-                                     t_slots + 1)
+        out = _dense_group_sums(lanes, (), gs_p, jnp.ones(kp, bool),
+                                t_slots + 1)[0]
         tf = None
         if f32_vals is not None:
             tf = _slot_sum_tables(gslot, t_slots, None, f32_vals, None)[1]
@@ -1319,31 +1318,33 @@ def _group_outputs(group_spec, cols, mask, num_docs, params=None):
                                         params)
     key = _group_key(gcols, strides, g_pad, cols, params)
     dense = g_pad <= DENSE_G_LIMIT and mask.shape[0] <= DENSE_ROWS_LIMIT
-    # all part-sum aggregations + the group count share ONE fused scan
-    # (one-hot builds dominate at dense shapes; fusing halves the passes)
-    psums_specs = [(i, spec) for i, spec in enumerate(agg_specs)
-                   if spec[0] in ("sum", "avg") and
-                   isinstance(spec[3], tuple) and spec[3][0] == "psums"]
+    # every summed lane (7-bit parts of the psums aggregations, float or
+    # raw value lanes of the csums ones) and the group count share ONE
+    # pass: the one-hot builds dominate at dense shapes
+    by_strategy = {s: [(i, spec) for i, spec in enumerate(agg_specs)
+                       if spec[0] in ("sum", "avg") and
+                       isinstance(spec[3], tuple) and spec[3][0] == s]
+                   for s in ("psums", "csums")}
+    psums_specs = by_strategy["psums"] if dense else []
     outs = {}
-    if dense and psums_specs:
-        lanes, slots, start = [], {}, 0
-        for i, spec in psums_specs:
-            pl = cols[f"{spec[1]}.parts"]
-            n_p = pl.shape[0]
-            lanes.extend(pl[p] for p in range(n_p))
-            slots[i] = (start, n_p)
-            start += n_p
-        sums, count = _dense_group_part_sums(lanes, key, mask, g_pad,
-                                             with_count=True)
-        outs["group.count"] = count
-        for i, _spec in psums_specs:
-            s0, n_p = slots[i]
-            outs[f"gagg{i}.psums"] = sums[s0:s0 + n_p]
-    elif dense:
-        outs["group.count"] = _dense_group_count(key, mask, g_pad)
+    if psums_specs or by_strategy["csums"]:
+        parts = [cols[f"{spec[1]}.parts"] for _i, spec in psums_specs]
+        psums, csums, count = _dense_group_sums(
+            [pl[p] for pl in parts for p in range(pl.shape[0])],
+            [cols[f"{spec[1]}.vlane" if spec[2] == "sv"
+                  else f"{spec[1]}.raw"]
+             for _i, spec in by_strategy["csums"]],
+            key, mask, g_pad, with_count=dense)
+        start = 0
+        for (i, _spec), pl in zip(psums_specs, parts):
+            outs[f"gagg{i}.psums"] = psums[start:start + pl.shape[0]]
+            start += pl.shape[0]
+        for j, (i, _spec) in enumerate(by_strategy["csums"]):
+            outs[f"gagg{i}.csums"] = csums[j]
     else:
-        outs["group.count"] = jnp.zeros(g_pad, jnp.int32).at[key].add(
-            mask.astype(jnp.int32))
+        count = _mxu_histogram(key, mask, g_pad) if dense else None
+    outs["group.count"] = count if dense else jnp.zeros(
+        g_pad, jnp.int32).at[key].add(mask.astype(jnp.int32))
     acc = sum_dtype()
     for i, spec in enumerate(agg_specs):
         fname, col, source, extra = spec
@@ -1360,12 +1361,7 @@ def _group_outputs(group_spec, cols, mask, num_docs, params=None):
                                       .astype(jnp.int32), 0))
                         for p in range(cols[f"{col}.parts"].shape[0])])
                 # dense: already emitted by the fused pass above
-            elif strategy == "csums":
-                lane = cols[f"{col}.vlane" if source == "sv"
-                            else f"{col}.raw"]
-                outs[f"gagg{i}.csums"] = _dense_group_float_sums(
-                    lane, key, mask, g_pad)
-            else:  # scatter fallback (huge group tables)
+            elif strategy != "csums":  # scatter fallback (huge tables)
                 if source == "sv":
                     vals = cols[f"{col}.vals"][cols[f"{col}.ids"]]
                 else:
@@ -1850,6 +1846,17 @@ def contract_cases():
          {"d0.ids": (i32, (P,)), "d1.ids": (i32, (P,)),
           "m0.parts": (i8, (2, P))},
          [(i32, ()), (i32, ())])
+    # dense group-by over a value lane: a raw float sum and the count
+    # in the one pass (the no-cube q3.1 table)
+    case("group_dense_csums",
+         ("pred", "eq_id", "d0", "sv", None), [],
+         ((("d0", "ids", 0, 8), ("d1", "ids", 0, 64)), (64, 1), 512,
+          (("sum", "r0", "raw", ("csums",)),
+           ("count", "*", "sv", None)), 0),
+         None,
+         {"d0.ids": (i32, (P,)), "d1.ids": (i32, (P,)),
+          "r0.raw": (f32, (P,))},
+         [(i32, ())])
     # scatter-fallback group-by (huge key space) + dict-decode sums
     case("group_scatter", ("match_all",), [],
          ((("d0", "ids", 0, 512),), (1,), 2 * DENSE_G_LIMIT,

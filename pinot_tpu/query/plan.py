@@ -1196,9 +1196,12 @@ def _adaptive_kmax(matched: int, padded: int, total_docs: int,
     r = kernels.pow2_bucket(max(16, int(2 * mu + 8)))
     if r > 128 and g_pad <= kernels.DENSE_G_LIMIT:
         # barely-selective filter: the block-compaction einsum degrades
-        # past r=128 while the dense path's VMEM-tiled one-hot scan
-        # keeps a flat per-element rate — measured crossover on v5e
-        # (compact r<=128 beats dense g=512; compact r=256 loses)
+        # past r=128 while the dense path's one-pass one-hot table keeps
+        # a flat per-element rate. Measured on v5e for BOTH value kinds
+        # (PR 36, scripts/dense_table_cost.py: one 6.25M-row segment,
+        # g=512, 4% kept, whole programs): compact r=256 12.5 ms and
+        # r=128 11.2 ms; dense with a float/raw value lane + count
+        # 6.5 ms, dense with 4 part lanes + count 6.8 ms
         return 0
     return min(t * r, padded)
 

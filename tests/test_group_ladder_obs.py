@@ -67,7 +67,7 @@ class Ladder:
     def meters(self) -> dict:
         return {m: self.metrics.meter(m).count for m in LADDER_METERS}
 
-    def run(self, pql: str, traced: bool):
+    def run(self, pql: str, traced: bool, **executor_kw):
         """-> (the reduced answer as JSON, the query's profile, the
         trace's spans, what the ladder's meters grew by)."""
         from pinot_tpu.pql.optimizer import BrokerRequestOptimizer
@@ -82,8 +82,8 @@ class Ladder:
         trace = TraceContext(root_name="server") if traced else None
         before = self.meters()
         with obs_profiler.active(profile, None):
-            block = ServerQueryExecutor().execute(request, self.segments,
-                                                  trace=trace)
+            block = ServerQueryExecutor(**executor_kw).execute(
+                request, self.segments, trace=trace)
         grown = {m: n - before[m] for m, n in self.meters().items()}
         answer = BrokerReduceService().reduce(request, [block]).to_json()
         for key in ("timeUsedMs", "traceInfo"):
@@ -253,6 +253,33 @@ def test_an_unscouted_group_by_is_a_table_that_says_so(ladder):
     assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == SEGMENTS == \
         profile["kernelDispatches"]
     assert grown[ServerMeter.GROUP_TABLES["dense"]] == SEGMENTS
+
+
+def test_a_barely_selective_float_group_by_goes_dense_and_equals_the_host(
+        ladder):
+    """q3.1's shape under a filter that keeps over 6% of the rows (two
+    supplier regions): more than 128 rows a block, so `_adaptive_kmax`
+    sends the table to the dense layout, whose one pass sums the raw INT
+    lane `lo_revenue` as a value lane beside the count (PR 36). The
+    answer is `query/host_exec.py`'s."""
+    pql = ("SELECT SUM(lo_revenue) FROM lineorder WHERE c_region = 'ASIA' "
+           "AND s_region IN ('ASIA', 'EUROPE') GROUP BY c_nation, s_nation, "
+           "d_year TOP 10000")
+    answer, profile, spans, grown = ladder.run(pql, traced=True)
+    assert answer["numDocsScanned"] > 0.06 * answer["totalDocs"]
+    tables = [s for s in spans if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    assert len(tables) == SEGMENTS
+    assert all(t["attrs"]["layout"] == "dense" and t["attrs"]["scouted"]
+               and t["attrs"]["runs"] == 1 for t in tables)
+    assert grown[ServerMeter.GROUP_TABLES["dense"]] == SEGMENTS
+    assert profile["paths"] == {"scan": SEGMENTS}
+    host, host_profile, _spans, _grown = ladder.run(pql, traced=False,
+                                                    use_device=False)
+    assert host_profile["paths"] == {"host": SEGMENTS}
+    groups = answer["aggregationResults"][0]["groupByResult"]
+    assert len(groups) == 5 * 10 * 7
+    assert answer["aggregationResults"] == host["aggregationResults"]
+    assert answer["numDocsScanned"] == host["numDocsScanned"]
 
 
 def test_a_kmax_re_run_is_counted_as_an_escalation():

@@ -44,8 +44,8 @@ def test_dense_group_part_sums_exact(g_pad, n_parts):
     key = rng.integers(0, g_pad, n).astype(np.int32)
     mask = rng.random(n) < 0.5
     parts = rng.integers(0, 128, (n_parts, n)).astype(np.int8)  # max 127
-    out, count = kernels._dense_group_part_sums(
-        [jnp.asarray(parts[p]) for p in range(n_parts)],
+    out, _vsums, count = kernels._dense_group_sums(
+        [jnp.asarray(parts[p]) for p in range(n_parts)], (),
         jnp.asarray(key), jnp.asarray(mask), g_pad, with_count=True)
     exp = np.zeros((n_parts, g_pad), dtype=np.int64)
     for p in range(n_parts):
@@ -62,11 +62,104 @@ def test_dense_group_float_sums(g_pad):
     key = rng.integers(0, g_pad, n).astype(np.int32)
     mask = rng.random(n) < 0.5
     vals = rng.random(n).astype(np.float64) * 100
-    out = np.asarray(kernels._dense_group_float_sums(
-        jnp.asarray(vals), jnp.asarray(key), jnp.asarray(mask), g_pad))
+    _psums, out, count = kernels._dense_group_sums(
+        (), [jnp.asarray(vals)], jnp.asarray(key), jnp.asarray(mask), g_pad)
     exp = np.zeros(g_pad)
     np.add.at(exp, key[mask], vals[mask])
-    np.testing.assert_allclose(out, exp, rtol=1e-9)
+    np.testing.assert_allclose(np.asarray(out[0]), exp, rtol=1e-9)
+    assert count is None
+
+
+def _one_pass_case(g_pad, lanes, seed=6):
+    """7 blocks (an odd count, as a benchmark segment's 763), a mask
+    that keeps 4%: (operands, float64 / int64 expectations)."""
+    rng = np.random.default_rng(seed)
+    n = kernels.BLOCK * 7
+    n_parts, with_count = {"float": (0, False), "float+count": (0, True),
+                           "parts+float+count": (2, True)}[lanes]
+    key = rng.integers(0, g_pad, n).astype(np.int32)
+    mask = rng.random(n) < 0.04
+    # revenues: integers below 2^24, exact in float32
+    vals = rng.integers(90_000, 10_500_000, n).astype(np.float32)
+    parts = rng.integers(0, 128, (n_parts, n)).astype(np.int8)
+    exp_v = np.zeros(g_pad)
+    np.add.at(exp_v, key[mask], vals[mask].astype(np.float64))
+    exp_p = np.zeros((n_parts, g_pad), dtype=np.int64)
+    for p in range(n_parts):
+        np.add.at(exp_p[p], key[mask], parts[p][mask].astype(np.int64))
+    return ((parts, vals, key, mask, with_count),
+            (exp_p, exp_v, _naive_hist(key, mask, g_pad)))
+
+
+def _run_one_pass(g_pad, parts, vals, key, mask, with_count):
+    return kernels._dense_group_sums(
+        [jnp.asarray(p) for p in parts], [jnp.asarray(vals)],
+        jnp.asarray(key), jnp.asarray(mask), g_pad, with_count=with_count)
+
+
+def _check_one_pass(got, want, with_count, rtol):
+    (psums, vsums, count), (exp_p, exp_v, exp_c) = got, want
+    np.testing.assert_array_equal(np.asarray(psums), exp_p)
+    np.testing.assert_allclose(np.asarray(vsums[0], np.float64), exp_v,
+                               rtol=rtol)
+    if with_count:
+        np.testing.assert_array_equal(np.asarray(count), exp_c)
+    else:
+        assert count is None
+
+
+@pytest.mark.parametrize("lanes", ["float", "float+count",
+                                   "parts+float+count"])
+@pytest.mark.parametrize("g_pad", [64, 512, 4096])
+@pytest.mark.parametrize("x64", [True, False], ids=["f64", "f32"])
+def test_dense_group_sums_one_pass(x64, g_pad, lanes):
+    """Every summed lane and the count from ONE pass, against np.add.at:
+    the direct one-hot (g 64) and the batched radix form (g 512, 4096),
+    with float64 value lanes (x64, the CPU parity mode) and with the
+    device path's float32 ones, which ride the bf16 operand as three
+    exact pieces beside the part lanes (products exact, float32
+    accumulation: a few ulps of the group's sum)."""
+    import jax
+    operands, want = _one_pass_case(g_pad, lanes)
+    with jax.enable_x64(x64):
+        assert kernels.sum_dtype() == (jnp.float64 if x64 else jnp.float32)
+        got = _run_one_pass(g_pad, *operands)
+        assert got[1].dtype == kernels.sum_dtype()
+    _check_one_pass(got, want, operands[-1], 1e-12 if x64 else 5e-7)
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["f64", "f32"])
+def test_dense_group_sums_wide_table_fallback(x64):
+    """g_pad 8192 is 64 hi bins: 2 part lanes + count (+ 3 float pieces
+    in float32 mode) make n_l * g1 = 192 (384) > 128, the scan with a
+    per-step concat dot; still equal. Under x64 the float64 lane's own
+    contraction (1 * 64) stays batched."""
+    import jax
+    operands, want = _one_pass_case(8192, "parts+float+count", seed=8)
+    with jax.enable_x64(x64):
+        got = _run_one_pass(8192, *operands)
+    _check_one_pass(got, want, True, 1e-12 if x64 else 5e-7)
+
+
+@pytest.mark.parametrize("values", ["integers_to_2^24", "random_float32"])
+def test_bf16_pieces_reconstruct_float32_exactly(values):
+    """No bf16 rounding of a value: the three pieces add up to the
+    float32 they were cut from, bit for bit."""
+    rng = np.random.default_rng(7)
+    if values == "integers_to_2^24":
+        v = np.concatenate([rng.integers(0, 1 << 24, 200_000),
+                            [0, 1, (1 << 24) - 1, 1 << 24]]
+                           ).astype(np.float32)
+        v = np.concatenate([v, -v])
+    else:
+        v = (rng.standard_normal(200_000)
+             * 10.0 ** rng.uniform(-20, 20, 200_000)).astype(np.float32)
+    pieces = kernels._bf16_pieces(jnp.asarray(v))
+    assert [p.dtype for p in pieces] == [jnp.bfloat16] * 3
+    total = np.zeros_like(v)
+    for p in pieces:
+        total = total + np.asarray(p).astype(np.float32)
+    np.testing.assert_array_equal(total, v)
 
 
 @pytest.mark.parametrize("t_slots", [300, 8192, 16384])

@@ -303,15 +303,17 @@ exact = np.zeros(g, np.float64)
 np.add.at(exact, idx, v.astype(np.float64))
 errs.append(relerr(jax.jit(radix)(jnp.asarray(idx), jnp.asarray(v)), exact))
 
-# _dense_group_float_sums below the radix threshold (direct one-hot)
-n2, g2 = 8 * K.BLOCK, 64
-key = rng.integers(0, g2, n2).astype(np.int32)
+# _dense_group_sums: float lanes ride the bf16 operand as three exact
+# pieces, on the direct one-hot (g 64, 512) and the radix form (g 2048)
+n2 = 8 * K.BLOCK
 v2 = values(n2)
-exact = np.zeros(g2, np.float64)
-np.add.at(exact, key, v2.astype(np.float64))
-errs.append(relerr(jax.jit(lambda x, kk: K._dense_group_float_sums(
-    x, kk, jnp.ones(n2, bool), g2))(jnp.asarray(v2), jnp.asarray(key)),
-    exact))
+for g2 in (64, 512, 2048):
+    key = rng.integers(0, g2, n2).astype(np.int32)
+    exact = np.zeros(g2, np.float64)
+    np.add.at(exact, key, v2.astype(np.float64))
+    errs.append(relerr(jax.jit(lambda x, kk: K._dense_group_sums(
+        (), [x], kk, jnp.ones(n2, bool), g2)[1][0])(
+            jnp.asarray(v2), jnp.asarray(key)), exact))
 out["sum_relerrs"] = errs
 
 # IVF assignment vs the f64 nearest centroid

@@ -457,11 +457,18 @@ def test_a_launch_finds_its_integer_scalars_on_the_device(
     first, seen[:] = list(seen), []
     uploads = []
     real_put = jax.device_put
-    monkeypatch.setattr(jax, "device_put",
-                        lambda *a, **k: uploads.append(a) or
-                        real_put(*a, **k))
+    # the launch's OWN uploads: `_device_scalar` hands `jax.device_put`
+    # a numpy integer scalar. Whatever else in the process uploads
+    # meanwhile (a lane, by another test's leftover thread under
+    # `--dist loadfile`) is not this launch's and is not counted
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda *a, **k: uploads.append(a[0]) or real_put(*a, **k))
+    misses = execution._device_scalar.cache_info().misses
     _profiled(pooled_executor, request, eight_segments)
-    assert len(first) == len(seen) == 8 and uploads == []
+    assert len(first) == len(seen) == 8
+    assert [u for u in uploads if isinstance(u, np.integer)] == []
+    assert execution._device_scalar.cache_info().misses == misses
     for a in first:
         assert len(a) >= 2 and all(isinstance(p, jax.Array) for p in a)
     # the same arrays (pool workers launch in any order)
