@@ -535,7 +535,7 @@ def bench_queries(mesh, stack, cpu, reps, rows, stage: str,
             fns.clear()
             outs_h, spec_used = drive_group_execution(
                 run, group_spec, stack.padded_docs,
-                int(stack.num_docs.sum()))
+                int(stack.num_docs.sum()), plan.segment)
             # steady state = every scout dispatch (spec None:
             # phase A min/max + the conditional hist rung) plus
             # the final escalation-ladder rung

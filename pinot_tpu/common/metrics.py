@@ -376,6 +376,14 @@ class ServerMeter:
                     "compacted": "groupTablesCompacted",
                     "ranked": "groupTablesRanked",
                     "sorted": "groupTablesSorted"}
+    # the lanes a device SUM or AVG read (obs/profiler.py mark_sum_lanes,
+    # one mark an aggregation a segment the device answered, by the
+    # strategy query/plan.py _agg_device_spec gave it): integer part
+    # lanes behind a dictionary (parts, psums), a raw lane, a decoded
+    # value lane of a dictionary (vlane, csums), a dictId histogram or
+    # per-id values (hist, vals, an MV column)
+    SUM_LANES = {"parts": "sumLanesParts", "raw": "sumLanesRaw",
+                 "value": "sumLanesValue", "hist": "sumLanesHist"}
 
 
 class ServerTimer:

@@ -378,6 +378,66 @@ def mark_group_ladder(scout: int, hist: int, table: int,
                 meters[name].mark(n)
 
 
+# -- sum-lane counters -------------------------------------------------------
+
+#: {lane kind: Meter} of every registry bound, swapped whole like
+#: _CUBE_METERS
+_sum_bound: "weakref.WeakSet" = weakref.WeakSet()
+_SUM_METERS: tuple = ()
+_SUM_STRATEGIES = {"parts": "parts", "psums": "parts", "vlane": "value",
+                   "csums": "value", "hist": "hist", "vals": "hist"}
+
+
+def bind_sum_lane_metrics(metrics) -> None:
+    """Meters `sumLanesParts`, `sumLanesRaw`, `sumLanesValue` and
+    `sumLanesHist` on `metrics`, at 0 from this call on."""
+    global _SUM_METERS
+    with _compile_lock:
+        _sum_bound.add(metrics)
+        _SUM_METERS = tuple({kind: m.meter(name) for kind, name
+                             in ServerMeter.SUM_LANES.items()}
+                            for m in _sum_bound)
+
+
+def sum_lane_kind(agg_spec: tuple) -> Optional[str]:
+    """The kind of lane a device aggregation spec (`query/plan.py`
+    `_agg_device_spec`) sums over, by `ServerMeter.SUM_LANES`' keys;
+    None for what is no SUM or AVG."""
+    fname, _col, source, extra = agg_spec
+    if fname not in ("sum", "avg"):
+        return None
+    if source == "raw":
+        return "raw"
+    if source == "mv":
+        return "hist"
+    return _SUM_STRATEGIES[extra[0]]
+
+
+def mark_sum_lanes(agg_specs) -> None:
+    """The device answered one segment's `agg_specs` (a scan's, or a
+    group table's: `query/execution.py`, `query/plan.py`
+    `_run_group_table`): one mark a SUM or AVG among them, on the meter
+    of the lanes it read. What a cube or the host answered, and a table
+    that never ran, marks nothing."""
+    for spec in agg_specs:
+        kind = sum_lane_kind(spec)
+        if kind is not None:
+            for meters in _SUM_METERS:
+                meters[kind].mark()
+
+
+def sum_lane_attrs(agg_specs, segment) -> dict:
+    """Span attributes of a launch that carries `agg_specs` over
+    `segment`: `partLanes`, the one-byte slices of its integer sums
+    (`int_part_info`: 4 for SSB's lo_revenue, 3 for lo_supplycost), and
+    `valueLanes`, its raw and decoded value lanes."""
+    kinds = [(sum_lane_kind(spec), spec[1]) for spec in agg_specs]
+    return {"partLanes": sum(segment.data_source(col).int_part_info()[0]
+                             for kind, col in kinds if kind == "parts"),
+            "valueLanes": sum(kind in ("raw", "value")
+                              for kind, _col in kinds)}
+
+
 # -- the device profiler, one session at a time ------------------------------
 
 PROFILER_ANCHOR = "pinot.profilerAnchor"

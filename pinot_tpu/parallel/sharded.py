@@ -47,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from pinot_tpu.analysis.runtime import debug_transfer_guard
 from pinot_tpu.common.request import BrokerRequest
 from pinot_tpu.obs import residency
-from pinot_tpu.obs.profiler import profiled_device_get
+from pinot_tpu.obs.profiler import mark_sum_lanes, profiled_device_get
 from pinot_tpu.query import combine as combine_mod
 from pinot_tpu.query import execution
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
@@ -702,7 +702,8 @@ class ShardedQueryExecutor:
         if plan.group_spec is not None:
             spec0 = set_group_kmax(plan.group_spec, stack.padded_docs)
             outs, spec_used = drive_group_execution(
-                run, spec0, stack.padded_docs, int(stack.num_docs.sum()))
+                run, spec0, stack.padded_docs, int(stack.num_docs.sum()),
+                plan.segment)
             if spec_used is None:
                 blk.group_map = {}
             else:
@@ -710,6 +711,7 @@ class ShardedQueryExecutor:
                     execution._with_group_spec(plan, spec_used), outs, blk)
         else:
             outs = profiled_device_get(run(plan.agg_specs, None, ()))
+            mark_sum_lanes(plan.agg_specs)
             if plan.agg_specs:
                 execution._finish_aggregation(plan, outs, blk)
         matched = int(outs["stats.num_docs_matched"])

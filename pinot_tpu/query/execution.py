@@ -16,7 +16,8 @@ import numpy as np
 
 from pinot_tpu.analysis.runtime import debug_transfer_guard
 from pinot_tpu.common.metrics import ServerQueryPhase
-from pinot_tpu.obs.profiler import obs_span, profiled_device_get
+from pinot_tpu.obs.profiler import (mark_sum_lanes, obs_span,
+                                    profiled_device_get, sum_lane_attrs)
 from pinot_tpu.ops import kernels
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
 from pinot_tpu.segment.loader import ImmutableSegment
@@ -103,7 +104,10 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
         # host-sync: never per-scalar). kernelLaunch ends at the
         # asynchronous return: argument handling, jit cache look-up,
         # any compile, enqueue
-        with obs_span(ServerQueryPhase.KERNEL_LAUNCH):
+        with obs_span(ServerQueryPhase.KERNEL_LAUNCH) as span:
+            if span is not None:
+                span["attrs"] = sum_lane_attrs(
+                    group_spec[3] if group_spec else agg_specs, segment)
             params, num_docs = _scalar_operands(plan, cols, extra_params)
             return kernels.run_segment_kernel(
                 segment.padded_docs, plan.filter_spec, agg_specs,
@@ -114,7 +118,7 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
     if plan.group_spec is not None:
         outs, spec_used = drive_group_execution(run, plan.group_spec,
                                                 segment.padded_docs,
-                                                segment.num_docs)
+                                                segment.num_docs, segment)
     else:
         # profiled twin of jax.device_get: counts the dispatch and the
         # host-side bytes on the ambient query profile
@@ -134,6 +138,7 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
                 _finish_group_by(_with_group_spec(plan, spec_used), outs,
                                  blk)
         elif plan.agg_specs:
+            mark_sum_lanes(plan.agg_specs)
             _finish_aggregation(plan, outs, blk)
         _finish_selection_and_stats(plan, outs, blk, 0.0)
     blk.stats.time_used_ms = (time.perf_counter() - t0) * 1e3
@@ -204,6 +209,7 @@ def execute_segment_plans_batched(plans) -> List[IntermediateResultsBlock]:
     blocks = []
     with obs_span(ServerQueryPhase.RESULT_FINISH):
         for plan, outs in zip(plans, per_member):
+            mark_sum_lanes(plan.agg_specs)
             blk = IntermediateResultsBlock()
             if plan.agg_specs:
                 _finish_aggregation(plan, outs, blk)

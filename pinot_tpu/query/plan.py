@@ -31,8 +31,9 @@ from pinot_tpu.common.datatype import DataType
 from pinot_tpu.common.request import (AggregationInfo, BrokerRequest,
                                       FilterOperator, FilterQueryTree)
 from pinot_tpu.common.metrics import ServerQueryPhase
-from pinot_tpu.obs.profiler import (count_path, mark_group_ladder, obs_span,
-                                    profiled_device_get)
+from pinot_tpu.obs.profiler import (count_path, mark_group_ladder,
+                                    mark_sum_lanes, obs_span,
+                                    profiled_device_get, sum_lane_attrs)
 from pinot_tpu.ops import kernels
 from pinot_tpu.query.aggregation import AggregationFunction, make_functions
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
@@ -1041,12 +1042,21 @@ def set_group_kmax(group_spec: tuple, padded: int) -> tuple:
 
 
 def escalate_group_kmax(group_spec: tuple, padded: int):
-    """Next rung of the compaction ladder; None when already at full size."""
+    """Next rung of the compaction ladder: four times the slots a block,
+    kept a power of two; None when already at full size. The kernel's
+    slot count is r = ceil(kmax / blocks), so a kmax rounded up by
+    itself gives an odd r where the block count is no power of two
+    (3052 blocks of a 6.25M-row segment: r 32 -> 172), and the
+    compaction's one-hot matmul at such a width takes the TPU compiler
+    31-34 s with 7 part planes (PERF.md section 6, PR 37) where r 128
+    takes 2.7: longer than a query's deadline on a cache that lacks
+    the program."""
     gcols, strides, g_pad, agg_specs, kmax = group_spec
     if not kmax or kmax >= padded:
         return None
-    nk = min(kernels.pow2_bucket(kmax * 4), padded)
-    return (gcols, strides, g_pad, agg_specs, nk)
+    t = max(padded // kernels.CBLOCK, 1)
+    r = kernels.pow2_bucket(-(-kmax // t) * 4)
+    return (gcols, strides, g_pad, agg_specs, min(t * r, padded))
 
 
 def run_with_group_escalation(run, group_spec, padded: int):
@@ -1083,10 +1093,12 @@ def group_layout(group_spec, padded: int) -> str:
     return "ranked" if g_pad > kernels.DENSE_G_LIMIT else "compacted"
 
 
-def _run_group_table(run, group_spec, padded: int, scouts: int, hists: int):
+def _run_group_table(run, group_spec, padded: int, scouts: int, hists: int,
+                     segment):
     """Phase B under one `groupTable` span, the first run and its kmax
     re-runs, and the segment's one mark on the ladder's meters, after
-    `scouts` and `hists` launches of the phases before it.
+    `scouts` and `hists` launches of the phases before it, and on the
+    sum lanes' meters (the table is where a group-by sums).
     -> (host outs, final spec)."""
     with obs_span(ServerQueryPhase.GROUP_TABLE) as span:
         outs, final, runs = run_with_group_escalation(run, group_spec,
@@ -1094,8 +1106,10 @@ def _run_group_table(run, group_spec, padded: int, scouts: int, hists: int):
     layout = group_layout(final, padded)
     if span is not None:
         span["attrs"] = {"layout": layout, "g": final[2], "runs": runs,
-                         "scouted": bool(scouts)}
+                         "scouted": bool(scouts),
+                         **sum_lane_attrs(final[3], segment)}
     mark_group_ladder(scouts, hists, runs, layout)
+    mark_sum_lanes(final[3])
     return outs, final
 
 
@@ -1288,13 +1302,16 @@ def adaptive_phase_b_spec(group_spec, scout, matched: int, padded: int,
     return kernel_spec, finish_spec, tuple(extra), False
 
 
-def drive_group_execution(run, group_spec, padded: int, total_docs: int):
+def drive_group_execution(run, group_spec, padded: int, total_docs: int,
+                          segment):
     """Execution policy for device group-bys.
 
     `run(agg_specs, group_spec, extra_params)` dispatches the kernel and
     returns DEVICE outs (extra_params are appended after the filter
     operands); this driver pulls each dispatch's outputs host-side in
-    one explicit batched jax.device_get. Filtered dictionary-keyed
+    one explicit batched jax.device_get. `segment` is what the plan was
+    made against (its `int_part_info` names the table's part lanes on a
+    traced `groupTable`). Filtered dictionary-keyed
     group-bys take the ADAPTIVE path:
 
     - Phase A (scout): masked min/max of each group column's dictIds +
@@ -1330,7 +1347,7 @@ def drive_group_execution(run, group_spec, padded: int, total_docs: int):
         if padded <= kernels.DENSE_ROWS_LIMIT else None
     if pa is None:
         return _run_group_table(lambda gs: run((), gs, ()), group_spec,
-                                padded, 0, 0)
+                                padded, 0, 0, segment)
     # one batched device→host transfer per scout dispatch; the
     # per-bound int() reads below are host numpy, not device pulls
     with obs_span(ServerQueryPhase.GROUP_SCOUT):
@@ -1353,7 +1370,7 @@ def drive_group_execution(run, group_spec, padded: int, total_docs: int):
         mark_group_ladder(1, hist, 0, None)
         return ha, None
     outs, final = _run_group_table(lambda gs: run((), gs, extra), kspec,
-                                   padded, 1, hist)
+                                   padded, 1, hist, segment)
     if final is not kspec:            # ladder escalated kmax
         fspec = fspec[:4] + (final[4],)
     return outs, fspec

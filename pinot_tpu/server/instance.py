@@ -81,6 +81,9 @@ class ServerInstance:
         # the group-by ladder's meters, at 0 from boot
         from pinot_tpu.obs.profiler import bind_group_metrics
         bind_group_metrics(self.metrics)
+        # the sum lanes' meters, at 0 from boot
+        from pinot_tpu.obs.profiler import bind_sum_lane_metrics
+        bind_sum_lane_metrics(self.metrics)
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)
         self.data_manager = InstanceDataManager()

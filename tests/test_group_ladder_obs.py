@@ -298,17 +298,47 @@ def test_a_kmax_re_run_is_counted_as_an_escalation():
 
     trace = TraceContext(root_name="server")
     with obs_profiler.active(obs_profiler.QueryProfile("t"), trace):
-        _outs, final = plan.drive_group_execution(run, spec, 1 << 20, 1000)
+        # no aggregation, so no lane of a segment is asked for
+        _outs, final = plan.drive_group_execution(run, spec, 1 << 20, 1000,
+                                                  None)
     assert launched == [1024, 4096, 16384] and final[4] == 16384
     (table,) = [s for s in trace.to_list()
                 if s["name"] == ServerQueryPhase.GROUP_TABLE]
     assert table["attrs"] == {"layout": "compacted", "g": 8, "runs": 3,
-                              "scouted": False}
+                              "scouted": False, "partLanes": 0,
+                              "valueLanes": 0}
     count = {m: reg.meter(m).count for m in LADDER_METERS}
     assert count[ServerMeter.GROUP_TABLE_DISPATCHES] == 3
     assert count[ServerMeter.GROUP_ESCALATIONS] == 2
     assert count[ServerMeter.GROUP_SEGMENTS] == 1
     assert count[ServerMeter.GROUP_TABLES["compacted"]] == 1
+
+
+def test_a_kmax_re_run_keeps_the_slot_count_a_power_of_two():
+    """A re-run's capacity is at least four times the last, with the
+    kernel's slots a block (r = ceil(kmax / blocks)) a power of two
+    also where the block count is none: at the benchmark's 6,250,496
+    padded rows (3052 blocks) r 32 goes to 128, not to 172, whose
+    compaction program with 7 part planes takes the TPU compiler 31-34 s
+    (PERF.md section 6, PR 37: a request's deadline is 15 s)."""
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.query.plan import escalate_group_kmax, group_layout
+    for padded in (6_250_496, 12_500_992, 1 << 20, 8192):
+        blocks = max(padded // kernels.CBLOCK, 1)
+        spec = ((), (), 1024, (), blocks * 32 if padded > 8192 else 1024)
+        while True:
+            nxt = escalate_group_kmax(spec, padded)
+            if nxt is None:
+                break
+            assert nxt[:4] == spec[:4]
+            assert min(4 * spec[4], padded) <= nxt[4] <= padded
+            r = -(-nxt[4] // blocks)
+            assert r & (r - 1) == 0, (padded, nxt[4], r)
+            spec = nxt
+        assert spec[4] == padded
+    first = escalate_group_kmax(((), (), 1024, (), 3052 * 32), 6_250_496)
+    assert first[4] == 3052 * 128
+    assert group_layout(first, 6_250_496) == "compacted"
 
 
 def test_layout_names_are_the_kernels_own_cases():
