@@ -360,7 +360,7 @@ class ServerMeter:
     # select-and-gather call, and by the stepwise numpy twin
     CUBE_DESCENTS_NATIVE = "cubeDescentsNative"
     CUBE_DESCENTS_NUMPY = "cubeDescentsNumpy"
-    # device group-by ladder (query/plan.py drive_group_execution, one
+    # device group-by ladder (query/plan.py SegmentLadder, one
     # mark_group_ladder call a segment through obs/profiler.py): segments
     # driven, launches of each phase (the table's re-runs counted), kmax
     # re-runs, segments whose filter matched nothing (no table), and the
@@ -376,6 +376,16 @@ class ServerMeter:
                     "compacted": "groupTablesCompacted",
                     "ranked": "groupTablesRanked",
                     "sorted": "groupTablesSorted"}
+    # the scan walk (query/executor.py, query/plan.py walk_ladders,
+    # marked through obs/profiler.py): scan-route segments by the walk
+    # that ran them (one thread launching a query's programs ahead of a
+    # pull a rung, or a pool task a segment), and the programs a pull
+    # brought home beside the pulls themselves: their ratio is the
+    # depth the device's queue reached
+    SCAN_WALK_SEGMENTS = "scanWalkSegments"
+    SCAN_POOL_SEGMENTS = "scanPoolSegments"
+    DEVICE_PROGRAMS = "devicePrograms"
+    DEVICE_PULLS = "devicePulls"
     # the lanes a device SUM or AVG read (obs/profiler.py mark_sum_lanes,
     # one mark an aggregation a segment the device answered, by the
     # strategy query/plan.py _agg_device_spec gave it): integer part
@@ -457,8 +467,9 @@ class ServerQueryPhase:
     KERNEL_DISPATCH = "kernelDispatch"
     OUTPUT_RELEASE = "outputRelease"
     RESULT_FINISH = "resultFinish"
-    # the group-by ladder's phases, each around its kernelLaunch and
-    # kernelDispatch spans (query/plan.py drive_group_execution)
+    # the group-by ladder's phases, ONE span a phase for all of a
+    # query's segments, around their kernelLaunch spans and the phase's
+    # one kernelDispatch (query/plan.py walk_ladders)
     GROUP_SCOUT = "groupScout"
     GROUP_HIST = "groupHist"
     GROUP_TABLE = "groupTable"

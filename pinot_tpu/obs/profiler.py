@@ -74,25 +74,32 @@ _NO_SPAN = nullcontext()
 
 def obs_span(name: str, **attrs):
     """A span on the ambient trace, for `with obs_span(...) as span`.
-    With no trace on it is one thread-local read and the shared no-op
-    context (`span` is None): no generator, no clock, no allocation."""
+    With no trace on (or no `name`: a step that has no span of its own)
+    it is one thread-local read and the shared no-op context (`span` is
+    None): no generator, no clock, no allocation."""
     ctx = getattr(_tls, "ctx", None)
-    if ctx is None or ctx[1] is None or not ctx[1].enabled:
+    if ctx is None or ctx[1] is None or not ctx[1].enabled or name is None:
         return _NO_SPAN
     return ctx[1].span(name, **attrs)
 
 
-def profiled_device_get(x):
+def profiled_device_get(x, programs: int = 1):
     """`jax.device_get` with dispatch/transfer accounting.
 
-    Every driver funnels its one explicit batched device→host pull per
-    dispatch through here: the ambient profile counts the dispatch and
-    the host-side bytes, and the ambient trace gets a `kernelDispatch`
-    span (the host's wait for the device and the copy, not device
-    time). With nothing active this is jax.device_get + one
+    Every driver funnels its one explicit batched device→host pull
+    through here, with the number of `programs` whose outputs `x`
+    holds (a rung of a query's ladders: one a segment): the ambient
+    profile counts the programs as dispatches and the host-side bytes,
+    the meters `devicePrograms` / `devicePulls` count programs and the
+    one pull, and the ambient trace gets a `kernelDispatch` span (the
+    host's wait for the device and the copy, not device time). With
+    nothing active this is jax.device_get, the two marks and one
     threading.local read.
     """
     import jax
+    for by_program, by_pull in _PULL_METERS:
+        by_program.mark(programs)
+        by_pull.mark()
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
         return jax.device_get(x)
@@ -107,9 +114,9 @@ def profiled_device_get(x):
     for leaf in jax.tree_util.tree_leaves(outs):
         nbytes += int(getattr(leaf, "nbytes", 0))
     if profile is not None:
-        profile.add_dispatch(nbytes, ms)
+        profile.add_dispatch(nbytes, ms, programs)
     if span is not None:
-        span["attrs"] = {"bytes": nbytes}
+        span["attrs"] = {"bytes": nbytes, "programs": programs}
     return outs
 
 
@@ -145,9 +152,10 @@ class QueryProfile:
         self.batch_size = 1
         self._lock = threading.Lock()
 
-    def add_dispatch(self, nbytes: int, ms: float) -> None:
+    def add_dispatch(self, nbytes: int, ms: float,
+                     programs: int = 1) -> None:
         with self._lock:
-            self.dispatches += 1
+            self.dispatches += programs
             self.transfer_bytes += nbytes
             self.kernel_ms += ms
 
@@ -359,12 +367,12 @@ def bind_group_metrics(metrics) -> None:
 def mark_group_ladder(scout: int, hist: int, table: int,
                       layout: Optional[str]) -> None:
     """One segment went through the device group-by ladder
-    (query/plan.py `drive_group_execution`): the launches of its scout,
+    (query/plan.py `SegmentLadder`): the launches of its scout,
     of its histogram rung and of its table (`table` - 1 of them kmax
     re-runs), and the layout of the final table; `layout` None where
     the filter matched nothing and no table ran. The three dispatch
-    counts add up to what `profiled_device_get` counted on the
-    segment's profile."""
+    counts add up to the programs `profiled_device_get` counted on the
+    query's profile for the segment."""
     counts = {ServerMeter.GROUP_SEGMENTS: 1,
               ServerMeter.GROUP_SCOUT_DISPATCHES: scout,
               ServerMeter.GROUP_HIST_DISPATCHES: hist,
@@ -376,6 +384,40 @@ def mark_group_ladder(scout: int, hist: int, table: int,
         for name, n in counts.items():
             if n:
                 meters[name].mark(n)
+
+
+# -- scan walk counters -------------------------------------------------------
+
+#: (scanWalkSegments, scanPoolSegments) and (devicePrograms,
+#: devicePulls) meters of every registry bound, swapped whole like
+#: _CUBE_METERS
+_walk_bound: "weakref.WeakSet" = weakref.WeakSet()
+_WALK_METERS: tuple = ()
+_PULL_METERS: tuple = ()
+
+
+def bind_walk_metrics(metrics) -> None:
+    """Meters `scanWalkSegments` / `scanPoolSegments` and
+    `devicePrograms` / `devicePulls` on `metrics`, at 0 from this call
+    on."""
+    global _WALK_METERS, _PULL_METERS
+    with _compile_lock:
+        _walk_bound.add(metrics)
+        _WALK_METERS = tuple(
+            (m.meter(ServerMeter.SCAN_WALK_SEGMENTS),
+             m.meter(ServerMeter.SCAN_POOL_SEGMENTS)) for m in _walk_bound)
+        _PULL_METERS = tuple(
+            (m.meter(ServerMeter.DEVICE_PROGRAMS),
+             m.meter(ServerMeter.DEVICE_PULLS)) for m in _walk_bound)
+
+
+def mark_scan_segments(walked: bool, segments: int = 1) -> None:
+    """`segments` scan-route segments ran (query/executor.py): in the
+    walk that queues a query's programs ahead of its pulls, or each as
+    a pool task of its own (a consuming segment's frozen half, a
+    batch's member)."""
+    for by_walk, by_pool in _WALK_METERS:
+        (by_walk if walked else by_pool).mark(segments)
 
 
 # -- sum-lane counters -------------------------------------------------------
@@ -416,7 +458,7 @@ def sum_lane_kind(agg_spec: tuple) -> Optional[str]:
 def mark_sum_lanes(agg_specs) -> None:
     """The device answered one segment's `agg_specs` (a scan's, or a
     group table's: `query/execution.py`, `query/plan.py`
-    `_run_group_table`): one mark a SUM or AVG among them, on the meter
+    `SegmentLadder`): one mark a SUM or AVG among them, on the meter
     of the lanes it read. What a cube or the host answered, and a table
     that never ran, marks nothing."""
     for spec in agg_specs:

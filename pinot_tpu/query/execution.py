@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -83,27 +83,48 @@ def _scalar_operands(plan, cols, extra_params):
 
 
 def execute_segment_plan(plan) -> IntermediateResultsBlock:
-    if plan.fast_path_result is not None:
-        return plan.fast_path_result
+    """ONE plan run to its block: `execute_segment_plans` with one
+    ladder (a pull a rung); a plan that refuses while running raises."""
+    (blk,) = execute_segment_plans([plan])
+    if isinstance(blk, Exception):
+        raise blk
+    return blk
+
+
+def execute_segment_plans(plans, deadline: Optional[float] = None) -> list:
+    """The plans of ONE query's scan-route segments, walked by this
+    thread in phases (`query/plan.py` `walk_ladders`): every plan's
+    lanes gathered (a lane-cache look-up), every plan's next program
+    launched without waiting, ONE pull a rung over all of them, then
+    the exact host finishing a plan. The device has the query's
+    programs queued while the host is between a pull and the next
+    launches; a query stops for the host once a rung (one for a scan,
+    two or three for a group-by), not once a program.
+
+    -> a list aligned with `plans`: the plan's block; or the
+    `UnsupportedOnDevice` / `GroupsLimitExceeded` it refused with while
+    running (the caller's host twin answers it); or None where
+    `deadline` (time.monotonic(), checked between gathers and between
+    rungs) passed before the plan was done."""
     # PINOT_TPU_DEBUG_TRANSFERS=1 turns any implicit device→host pull in
     # the dispatch/finish path below into an error at the offending call
-    # site (the explicit batched jax.device_get per dispatch still works)
+    # site (the explicit batched jax.device_get a rung still works)
     with debug_transfer_guard():
-        return _execute_segment_plan(plan)
+        return _execute_segment_plans(plans, deadline)
 
 
-def _execute_segment_plan(plan) -> IntermediateResultsBlock:
+def _plan_ladder(plan):
+    """The plan's ladder of device programs over its gathered lanes."""
+    from pinot_tpu.query.plan import SegmentLadder
     segment = plan.segment
-    t0 = time.perf_counter()
     cols = gather_operands(plan)
-    from pinot_tpu.query.plan import drive_group_execution
 
     def run(agg_specs, group_spec, extra_params=()):
-        # returns DEVICE outs; each driver batches the device→host pull
-        # into one explicit jax.device_get per dispatch (tpulint
-        # host-sync: never per-scalar). kernelLaunch ends at the
-        # asynchronous return: argument handling, jit cache look-up,
-        # any compile, enqueue
+        # returns DEVICE outs; the walk batches the device→host pull
+        # into one explicit jax.device_get a rung (tpulint host-sync:
+        # never per-scalar). kernelLaunch ends at the asynchronous
+        # return: argument handling, jit cache look-up, any compile,
+        # enqueue
         with obs_span(ServerQueryPhase.KERNEL_LAUNCH) as span:
             if span is not None:
                 span["attrs"] = sum_lane_attrs(
@@ -113,30 +134,45 @@ def _execute_segment_plan(plan) -> IntermediateResultsBlock:
                 segment.padded_docs, plan.filter_spec, agg_specs,
                 group_spec, plan.select_spec, cols, params, num_docs)
 
+    return SegmentLadder(run, plan.agg_specs, plan.group_spec,
+                         segment.padded_docs, segment.num_docs, segment)
+
+
+def _execute_segment_plans(plans, deadline) -> list:
+    from pinot_tpu.query.plan import (GroupsLimitExceeded,
+                                      UnsupportedOnDevice, walk_ladders)
+    t0 = time.perf_counter()
+    results = [plan.fast_path_result for plan in plans]
+    ladders = {}
+    for i, plan in enumerate(plans):
+        if results[i] is not None:
+            continue
+        if deadline is not None and time.monotonic() >= deadline:
+            break
+        try:
+            ladders[i] = _plan_ladder(plan)
+        except (GroupsLimitExceeded, UnsupportedOnDevice) as exc:
+            results[i] = exc
+    walk_ladders(list(ladders.values()), deadline, keep_refusals=True)
+    for i, ladder in ladders.items():
+        if ladder.refused is not None:
+            results[i] = ladder.refused
+        elif ladder.phase is None:
+            results[i] = _finish_ladder(plans[i], ladder, t0)
+    return results
+
+
+def _finish_ladder(plan, ladder, t0: float) -> IntermediateResultsBlock:
+    """A done ladder's HOST outs -> the plan's block."""
     blk = IntermediateResultsBlock()
-    spec_used = None
-    if plan.group_spec is not None:
-        outs, spec_used = drive_group_execution(run, plan.group_spec,
-                                                segment.padded_docs,
-                                                segment.num_docs, segment)
-    else:
-        # profiled twin of jax.device_get: counts the dispatch and the
-        # host-side bytes on the ambient query profile
-        launched = run(plan.agg_specs, None, ())
-        outs = profiled_device_get(launched)
-        # dropping the last reference to the device outputs is not
-        # free (the tracing's first finding: 1.9 ms a segment on the
-        # CPU rehearsal): it happens here, under a span, not wherever
-        # the temporary would have died
-        with obs_span(ServerQueryPhase.OUTPUT_RELEASE):
-            del launched
+    outs = ladder.outs
     with obs_span(ServerQueryPhase.RESULT_FINISH):
         if plan.group_spec is not None:
-            if spec_used is None:
+            if ladder.finish_spec is None:
                 blk.group_map = {}
             else:
-                _finish_group_by(_with_group_spec(plan, spec_used), outs,
-                                 blk)
+                _finish_group_by(_with_group_spec(plan, ladder.finish_spec),
+                                 outs, blk)
         elif plan.agg_specs:
             mark_sum_lanes(plan.agg_specs)
             _finish_aggregation(plan, outs, blk)

@@ -11,17 +11,24 @@ it back as a value, `_run_route` runs it: the solo and the batched walk
 both go through the pair and end in `_finish_query`. Sharded or sequential
 is chosen in `execute`, nowhere else.
 
-Per-segment execution fans out on the scheduler's query-worker pool
-(CombineOperator parity: per-segment plans on an ExecutorService,
-CombineOperator.java:27). Device dispatches serialize on the chip anyway,
-so the workers overlap host-side planning/decoding/finishing with device
-work — the win the reference gets from planNodes.parallelStream().
+A query's device scans are ONE walk (`_walk_scans`, PR 38): one thread
+routes the segments, launches every segment's next program without
+waiting and pulls once a rung (`execution.execute_segment_plans`), so
+the device has the query's programs queued while the host is between a
+pull and the next launches, and a query stops for the host two or three
+times, not 2.09 times a segment behind a pool's hand-offs. What is no
+device scan fans out a segment a task on the scheduler's query-worker
+pool (CombineOperator parity: per-segment plans on an ExecutorService,
+CombineOperator.java:27): consuming and gated segments, host routes;
+the walk is one task of that pool beside them. Without a pool (the
+engine's default, or one segment) the same walk runs on the calling
+thread and the rest follows it in order.
 """
 from __future__ import annotations
 
 import concurrent.futures
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import List, Optional, Tuple
 
 from pinot_tpu.common.metrics import ServerQueryPhase
@@ -178,9 +185,7 @@ class ServerQueryExecutor:
             return _stamp(blk, num_pruned, t0)
 
         with trace.span(ServerQueryPhase.SEGMENT_EXECUTION):
-            run = self._run_parallel if self.segment_executor is not None \
-                and len(selected) > 1 else self._run_sequential
-            results = run(selected, request, deadline, trace)
+            results = self._run_segments(selected, request, deadline, trace)
         return _finish_query(request, selected, results, num_pruned, t0)
 
     # -- per-segment work ---------------------------------------------------
@@ -223,63 +228,86 @@ class ServerQueryExecutor:
 
     @staticmethod
     def _record_queue_wait(trace: Optional[TraceContext], t_queued: float,
-                           seg, parent_id: Optional[str] = None) -> None:
-        """`segmentQueueWait`: how long this segment's work waited for
-        its turn since `t_queued` (perf_counter): for a worker of the
-        pool (the second wave of 8 segments on 4 workers), or for the
-        segments before it in a sequential walk. A sibling of the
-        `segment` span it precedes."""
+                           parent_id: Optional[str] = None,
+                           **attrs) -> None:
+        """`segmentQueueWait`: how long a piece of a query's work waited
+        for its turn since `t_queued` (perf_counter): for a worker of
+        the pool (the scans' walk, `attrs.segments`; a segment that is
+        no device scan, `attrs.segment`), or for what ran before it on
+        the calling thread. A sibling of the spans it precedes."""
         if trace is not None and trace.enabled:
             trace.record(ServerQueryPhase.SEGMENT_QUEUE_WAIT,
                          (time.perf_counter() - t_queued) * 1e3,
-                         parent_id=parent_id,
-                         segment=getattr(seg, "segment_name", "?"))
+                         parent_id=parent_id, **attrs)
 
-    def _run_sequential(self, selected, request: BrokerRequest,
-                        deadline: Optional[float],
-                        trace: Optional[TraceContext] = None
-                        ) -> List[SegmentResult]:
-        results: List[SegmentResult] = []
-        t_queued = time.perf_counter()
-        for seg in selected:
-            if deadline is not None and time.monotonic() >= deadline:
-                break
-            self._record_queue_wait(trace, t_queued, seg)
-            results.append(self._segment_work(seg, request))
-        return results
+    def _walks(self, seg) -> bool:
+        """Whether `seg`'s route can be a device scan (`_route` says
+        whether it is): the scans' walk takes it. A consuming segment
+        (its frozen and tail halves) and one gated off the device stay
+        a task of their own."""
+        return self.use_device and not getattr(seg, "is_mutable", False) \
+            and self._on_device(seg)
 
-    def _run_parallel(self, selected, request: BrokerRequest,
+    def _run_segments(self, selected, request: BrokerRequest,
                       deadline: Optional[float],
                       trace: Optional[TraceContext] = None
                       ) -> List[SegmentResult]:
-        """CombineOperator parity: every segment plan runs as a task on
-        the scheduler's query-worker pool while this (runner) thread
-        gathers. Deadline truncation: tasks not yet started when the
-        budget expires return unexecuted (the pool's queue order makes
-        "stop submitting" and "reject on pick-up" equivalent), and the
-        gather abandons stragglers instead of waiting past the deadline.
-        """
+        """Every selected segment's result, in `selected`'s order and
+        without those the deadline cut: the scans' walk as ONE piece of
+        work, every other segment a piece of its own. With a pool
+        (CombineOperator parity) the pieces are its tasks while this
+        (runner) thread gathers; without, they run here in turn.
+        Deadline truncation: a piece not yet started when the budget
+        expires returns unexecuted (the pool's queue order makes "stop
+        submitting" and "reject on pick-up" equivalent), the walk checks
+        between segments and between rungs, and the gather abandons
+        stragglers instead of waiting past the deadline (a program that
+        compiles for 29 s must not hold the reply)."""
+        pool = self.segment_executor if len(selected) > 1 else None
+        slots: List[Optional[SegmentResult]] = [None] * len(selected)
         # worker threads don't inherit the runner's ambient profile or
         # its span stack — capture both here, re-establish per task so
-        # per-segment spans parent under segmentExecution and dispatch
+        # the pieces' spans parent under segmentExecution and dispatch
         # accounting lands on the right query's profile
         ambient = obs_profiler.current()
         parent_id = trace.current_span_id() if trace is not None else None
+        attached = trace is not None and trace.enabled and pool is not None
+        futures: List[concurrent.futures.Future] = []
 
-        t_queued = time.perf_counter()
+        def spawn(piece, **attrs) -> None:
+            t_queued = time.perf_counter()
 
-        def work(seg):
-            if deadline is not None and time.monotonic() >= deadline:
-                return None                 # budget gone before start
-            self._record_queue_wait(trace, t_queued, seg, parent_id)
-            with obs_profiler.reactivate(ambient):
-                if trace is not None and trace.enabled:
-                    with trace.attach(parent_id):
-                        return self._segment_work(seg, request)
-                return self._segment_work(seg, request)
+            def work():
+                if deadline is not None and time.monotonic() >= deadline:
+                    return                  # budget gone before start
+                self._record_queue_wait(trace, t_queued, parent_id, **attrs)
+                with obs_profiler.reactivate(ambient), \
+                        trace.attach(parent_id) if attached \
+                        else nullcontext():
+                    piece()
 
-        futures = [self.segment_executor.submit(work, seg)
-                   for seg in selected]
+            if pool is None:
+                work()
+            else:
+                # the walk hands its host routes over from its worker
+                # while the runner waits on the walk, the first future
+                futures.append(pool.submit(work))
+
+        def solo(i: int, run) -> None:
+            def piece():
+                slots[i] = run(selected[i], request)
+            spawn(piece, segment=getattr(selected[i], "segment_name", "?"))
+
+        walked, alone = [], []
+        for i, seg in enumerate(selected):
+            (walked if self._walks(seg) else alone).append(i)
+        if walked:
+            spawn(lambda: self._walk_scans(selected, walked, request,
+                                           deadline, slots, solo),
+                  segments=len(walked))
+        for i in alone:
+            solo(i, self._segment_work)
+
         abandoned = False
         for fut in futures:
             if not abandoned:
@@ -294,9 +322,49 @@ class ServerQueryExecutor:
                     # semantics)
                     abandoned = True
             fut.cancel()
-        results = [fut.result() for fut in futures
-                   if fut.done() and not fut.cancelled()]
-        return [res for res in results if res is not None]
+        return [res for res in slots if res is not None]
+
+    def _walk_scans(self, selected, walked, request: BrokerRequest,
+                    deadline: Optional[float], slots, solo) -> None:
+        """ONE thread walks the query's device scans: route every
+        segment of `walked` (a `segment` span each: a cube's descent
+        where the segment has one and it covers, else `buildQueryPlan`),
+        then run all the `scan` routes' plans in phases under one
+        `queryPlanExecution` (`execution.execute_segment_plans`: every
+        launch of a rung before its ONE pull). A per-segment cube hit is
+        answered where it is routed; a `host` route, and a plan that
+        refuses while running, goes to `solo` as a piece of its own.
+        Fills `slots`; what the deadline cut stays None."""
+        plans = {}
+        for i in walked:
+            if deadline is not None and time.monotonic() >= deadline:
+                break
+            seg = selected[i]
+            with obs_span("segment",
+                          segment=getattr(seg, "segment_name", "?")):
+                path, payload = self._route(seg, request)
+            if path == "cube":
+                slots[i] = ([payload], 0, 0)
+            elif path == "scan":
+                plans[i] = payload
+            else:
+                solo(i, self._host_segment)
+        if not plans:
+            return
+        with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION,
+                      segments=len(plans)):
+            blocks = execution.execute_segment_plans(list(plans.values()),
+                                                     deadline)
+        for i, blk in zip(plans, blocks):
+            if isinstance(blk, Exception):
+                solo(i, self._host_segment)
+            elif blk is not None:
+                obs_profiler.count_path("scan")
+                obs_profiler.mark_scan_segments(True)
+                slots[i] = ([blk], 0, 0)
+
+    def _host_segment(self, seg, request: BrokerRequest) -> SegmentResult:
+        return [self._run_route(seg, request, ("host", None))], 0, 0
 
     # -- the ladder: one segment, one request -------------------------------
     def _route(self, segment, request: BrokerRequest):
@@ -329,6 +397,7 @@ class ServerQueryExecutor:
                 with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
                     blk = payload.execute()
                 obs_profiler.count_path("scan")
+                obs_profiler.mark_scan_segments(False)
                 return blk
             except (GroupsLimitExceeded, UnsupportedOnDevice):
                 pass
@@ -337,7 +406,8 @@ class ServerQueryExecutor:
 
     def _execute_segment(self, segment: ImmutableSegment,
                          request: BrokerRequest) -> IntermediateResultsBlock:
-        # planning and running stay fused inside the pool worker
+        # ONE segment routed and run where it stands: a consuming
+        # segment's halves, a batch's member
         return self._run_route(segment, request,
                                self._route(segment, request))
 
@@ -380,7 +450,9 @@ class ServerQueryExecutor:
                     break
                 takers = [m for m in pending if id(seg) in m.selected_ids]
                 if takers:
-                    self._record_queue_wait(trace, t_queued, seg)
+                    self._record_queue_wait(
+                        trace, t_queued,
+                        segment=getattr(seg, "segment_name", "?"))
                     self._batch_segment(seg, takers)
             return [m.finish(t0) for m in members]
 
@@ -411,6 +483,7 @@ class ServerQueryExecutor:
                 blocks = execution.execute_segment_plans_batched(
                     [plan for _, plan in group])
             obs_profiler.count_path("scan", len(group))
+            obs_profiler.mark_scan_segments(False, len(group))
             for (m, _), blk in zip(group, blocks):
                 m.results.append(([blk], 0, 0))
 

@@ -1059,23 +1059,6 @@ def escalate_group_kmax(group_spec: tuple, padded: int):
     return (gcols, strides, g_pad, agg_specs, min(t * r, padded))
 
 
-def run_with_group_escalation(run, group_spec, padded: int):
-    """run(group_spec) → device outs; re-runs up the kmax ladder while
-    the compacted group kernel reports overflow. Returns the HOST outs,
-    the final spec and the number of runs — all of a dispatch's outputs
-    come over in ONE explicit jax.device_get (per-scalar pulls like the
-    old `int(np.asarray(outs[...]))` overflow probe stall the pipeline
-    once per output; see docs/ANALYSIS.md host-sync)."""
-    outs = profiled_device_get(run(group_spec))
-    runs = 1
-    while group_spec is not None and int(outs.get("group.overflow", 0)) > 0:
-        group_spec = escalate_group_kmax(group_spec, padded)
-        assert group_spec is not None, "overflow at full kmax is impossible"
-        outs = profiled_device_get(run(group_spec))
-        runs += 1
-    return outs, group_spec, runs
-
-
 def group_layout(group_spec, padded: int) -> str:
     """The table layout `ops/kernels.py` `_group_outputs` takes for
     this spec over `padded` rows, by the names the kernels' own cases
@@ -1091,26 +1074,6 @@ def group_layout(group_spec, padded: int) -> str:
     if min(max(-(-kmax // t), 8), kernels.CBLOCK) > 256:
         return "sorted"
     return "ranked" if g_pad > kernels.DENSE_G_LIMIT else "compacted"
-
-
-def _run_group_table(run, group_spec, padded: int, scouts: int, hists: int,
-                     segment):
-    """Phase B under one `groupTable` span, the first run and its kmax
-    re-runs, and the segment's one mark on the ladder's meters, after
-    `scouts` and `hists` launches of the phases before it, and on the
-    sum lanes' meters (the table is where a group-by sums).
-    -> (host outs, final spec)."""
-    with obs_span(ServerQueryPhase.GROUP_TABLE) as span:
-        outs, final, runs = run_with_group_escalation(run, group_spec,
-                                                      padded)
-    layout = group_layout(final, padded)
-    if span is not None:
-        span["attrs"] = {"layout": layout, "g": final[2], "runs": runs,
-                         "scouted": bool(scouts),
-                         **sum_lane_attrs(final[3], segment)}
-    mark_group_ladder(scouts, hists, runs, layout)
-    mark_sum_lanes(final[3])
-    return outs, final
 
 
 RANK_HIST_CARD_LIMIT = int(os.environ.get(
@@ -1302,78 +1265,225 @@ def adaptive_phase_b_spec(group_spec, scout, matched: int, padded: int,
     return kernel_spec, finish_spec, tuple(extra), False
 
 
-def drive_group_execution(run, group_spec, padded: int, total_docs: int,
-                          segment):
-    """Execution policy for device group-bys.
+#: a ladder's rungs in the order a walk takes them, each with the span
+#: its launches and its one pull run under (a scan without a group-by
+#: is one rung and has none)
+LADDER_PHASES = (("scan", None),
+                 ("scout", ServerQueryPhase.GROUP_SCOUT),
+                 ("hist", ServerQueryPhase.GROUP_HIST),
+                 ("table", ServerQueryPhase.GROUP_TABLE))
+
+
+class SegmentLadder:
+    """One segment's place on its ladder of device programs: what to
+    launch next (`launch`), given what was pulled (`accept`). The walk
+    that drives it (`walk_ladders`) owns the pulls, so one ladder, a
+    sequential walk and a query's eight segments are this one piece of
+    logic driven with 1 or N ladders.
 
     `run(agg_specs, group_spec, extra_params)` dispatches the kernel and
     returns DEVICE outs (extra_params are appended after the filter
-    operands); this driver pulls each dispatch's outputs host-side in
-    one explicit batched jax.device_get. `segment` is what the plan was
-    made against (its `int_part_info` names the table's part lanes on a
-    traced `groupTable`). Filtered dictionary-keyed
-    group-bys take the ADAPTIVE path:
+    operands). `segment` is what the plan was made against (its
+    `int_part_info` names the table's part lanes on a traced
+    `groupTable`). A plan without a `group_spec` is the one rung
+    "scan": its `agg_specs` program. Filtered dictionary-keyed group-bys
+    take the ADAPTIVE path:
 
-    - Phase A (scout): masked min/max of each group column's dictIds +
+    - "scout" (phase A): masked min/max of each group column's dictIds +
       the matched count — streaming tree reductions, about one filter
       evaluation.
-    - Phase A2 (conditional hist rung, adaptive_hist_specs): matched-id
-      histograms → exact present sets for the densifying rank remap,
-      dispatched only when the span key space would need the ranked
-      sort layout (> DENSE_G_LIMIT).
-    - Phase B: group tables over the REMAPPED key space (product of the
-      scout's active spans — or bucketed PRESENT counts where the rank
-      remap applies), with MXU block-compaction sized from the measured
-      selectivity. Small remapped spaces take the dense one-hot layout
-      (device psum combine); big ones the ranked layout.
+    - "hist" (phase A2, the conditional rung, adaptive_hist_specs):
+      matched-id histograms → exact present sets for the densifying rank
+      remap, dispatched only when the span key space would need the
+      ranked sort layout (> DENSE_G_LIMIT).
+    - "table" (phase B): group tables over the REMAPPED key space
+      (product of the scout's active spans — or bucketed PRESENT counts
+      where the rank remap applies), with MXU block-compaction sized
+      from the measured selectivity. Small remapped spaces take the
+      dense one-hot layout (device psum combine); big ones the ranked
+      layout. A table that reports `group.overflow` stays on this rung
+      with four times the slots (`escalate_group_kmax`).
 
     No sorts or row-scale scatters anywhere on the hot path — those are
     TPU's slow primitives. The one row-scale gather is the idrank
     remap's rank-vector lookup (kernels._group_key), paid only when the
     hist rung proves it collapses the key space below the offset span.
-    Non-eligible plans fall back to the compacted kernel with the kmax
-    escalation ladder.
+    Non-eligible plans go straight to the compacted table with the kmax
+    escalation rung.
 
+    `phase` is the rung to launch next, None once the ladder is done
+    (or `refused`, see `walk_ladders`); then `outs` holds the HOST outs
+    to finish from and `finish_spec` the group spec to decode them with
+    (None for a scan, and where the filter matched nothing: `outs` then
+    still carries the stats). Every
+    group-by ladder marks the ladder's meters once
+    (`mark_group_ladder`: with its table, or where the filter matched
+    nothing) and, with its table, the sum lanes' (the table is where a
+    group-by sums): what a segment took, not what it costs."""
+
+    __slots__ = ("run", "group_spec", "padded", "total_docs", "segment",
+                 "phase", "outs", "finish_spec", "refused", "hists", "runs",
+                 "_specs", "_kspec", "_fspec", "_extra", "_scout",
+                 "_matched")
+
+    def __init__(self, run, agg_specs, group_spec, padded: int,
+                 total_docs: int, segment):
+        self.run, self.group_spec, self.padded = run, group_spec, padded
+        self.total_docs, self.segment = total_docs, segment
+        self.outs = self.finish_spec = self.refused = None
+        self.hists = self.runs = 0
+        self._fspec, self._extra, self._scout = None, (), None
+        if group_spec is None:
+            self.phase, self._specs = "scan", agg_specs
+            return
+        self._specs = adaptive_phase_a_specs(group_spec) \
+            if padded <= kernels.DENSE_ROWS_LIMIT else None
+        self.phase = "table" if self._specs is None else "scout"
+        self._kspec = group_spec
+
+    def launch(self):
+        """This rung's program, queued: DEVICE outs."""
+        if self.phase == "table":
+            return self.run((), self._kspec, self._extra)
+        return self.run(self._specs, None, ())
+
+    def accept(self, outs) -> None:
+        """The HOST outs of what `launch` queued: on to the next rung
+        (the per-bound int() reads are host numpy, not device pulls)."""
+        if self.phase == "scan":
+            self._done(outs, None)
+        elif self.phase == "scout":
+            self._scout = outs
+            bounds = [(int(outs[f"agg{2 * i}.min"]),
+                       int(outs[f"agg{2 * i + 1}.max"]))
+                      for i in range(len(self._specs) // 2)]
+            self._matched = int(outs["stats.num_docs_matched"])
+            self._specs = adaptive_hist_specs(self.group_spec, bounds) \
+                if self._matched > 0 else None
+            if self._specs is not None:
+                self.phase = "hist"
+            else:
+                self._plan_table([("bounds", lo, hi) for lo, hi in bounds])
+        elif self.phase == "hist":
+            self.hists = 1
+            self._plan_table([
+                ("present", np.nonzero(np.asarray(outs[f"agg{i}"])[: c[3]])[0])
+                for i, c in enumerate(self.group_spec[0])])
+        else:
+            self.runs += 1
+            if int(outs.get("group.overflow", 0)) > 0:
+                self._kspec = escalate_group_kmax(self._kspec, self.padded)
+                assert self._kspec is not None, \
+                    "overflow at full kmax is impossible"
+                return
+            mark_group_ladder(int(self.scouted), self.hists, self.runs,
+                              group_layout(self._kspec, self.padded))
+            mark_sum_lanes(self._kspec[3])
+            # the finish spec carries the real offsets / present-id
+            # arrays, and the kmax the ladder ended on
+            self._done(outs, self._kspec if self._fspec is None
+                       else self._fspec[:4] + (self._kspec[4],))
+
+    @property
+    def scouted(self) -> bool:
+        return self._scout is not None
+
+    def table_attrs(self) -> dict:
+        """What a traced `groupTable` says of this ladder's table."""
+        return {"layout": group_layout(self._kspec, self.padded),
+                "g": self._kspec[2], "runs": self.runs}
+
+    def _plan_table(self, scout) -> None:
+        kspec, fspec, extra, empty = adaptive_phase_b_spec(
+            self.group_spec, scout, self._matched, self.padded,
+            self.total_docs)
+        if empty:
+            mark_group_ladder(1, self.hists, 0, None)
+            self._done(self._scout, None)
+        else:
+            self.phase = "table"
+            self._kspec, self._fspec, self._extra = kspec, fspec, extra
+
+    def _done(self, outs, finish_spec) -> None:
+        self.phase, self.outs, self.finish_spec = None, outs, finish_spec
+
+
+def walk_ladders(ladders, deadline: Optional[float] = None,
+                 keep_refusals: bool = False) -> None:
+    """Drive `ladders` to their ends in phases: every ladder on a rung
+    launches its program WITHOUT waiting, then ONE explicit batched
+    `jax.device_get` brings the rung's outputs home for all of them
+    (tpulint host-sync: never per-scalar, and since PR 38 never a
+    program either), so the device has a query's programs queued while
+    the host is between a pull and the next launches. A kmax re-run
+    repeats the table rung for the ladders that overflowed alone.
+
+    Each group-by rung runs under ONE span for all its ladders
+    (`groupScout`, `groupHist`, `groupTable`; `attrs.segments`, and on
+    the table `layout`, `g` and `runs` a ladder, `scouted` and the
+    first's `partLanes` / `valueLanes`) around their `kernelLaunch`es
+    (the callers' `run`) and the rung's `kernelDispatch`;
+    `outputRelease` is the drop of the rung's device outputs (not free:
+    1.9 ms a segment on the CPU rehearsal), under a span and not
+    wherever the temporaries would have died.
+
+    `deadline` (time.monotonic()) is checked between rungs: ladders not
+    done by then are left where they stand (`phase` not None). With
+    `keep_refusals` a ladder whose launch refuses with
+    `UnsupportedOnDevice` or `GroupsLimitExceeded` leaves the walk with
+    the exception as its `refused` (the caller's host twin answers it);
+    without, the refusal propagates."""
+    refused = (GroupsLimitExceeded, UnsupportedOnDevice) \
+        if keep_refusals else ()
+    for phase, span_name in LADDER_PHASES:
+        rung = at = [l for l in ladders if l.phase == phase]
+        if not at:
+            continue
+        if deadline is not None and time.monotonic() >= deadline:
+            return
+        with obs_span(span_name, segments=len(rung)) as span:
+            while at:
+                # ONE flat dict a pull, "<ladder>/<output>": what
+                # `jax.device_get` is handed stays a dict of arrays
+                launched, pulled = {}, {}
+                for i, ladder in enumerate(at):
+                    try:
+                        launched.update((f"{i}/{name}", out) for name, out
+                                        in ladder.launch().items())
+                        pulled[i] = {}
+                    except refused as exc:
+                        ladder.phase, ladder.refused = None, exc
+                if not pulled:
+                    break
+                for key, out in profiled_device_get(
+                        launched, programs=len(pulled)).items():
+                    i, _, name = key.partition("/")
+                    pulled[int(i)][name] = out
+                with obs_span(ServerQueryPhase.OUTPUT_RELEASE):
+                    del launched
+                for i, outs in pulled.items():
+                    at[i].accept(outs)
+                at = [l for l in at if l.phase == phase]
+        if span is not None and phase == "table":
+            tables = [l.table_attrs() for l in rung
+                      if l.finish_spec is not None]
+            span["attrs"] = {
+                "segments": len(rung),
+                **{k: [t[k] for t in tables]
+                   for k in ("layout", "g", "runs")},
+                "scouted": rung[0].scouted,
+                **sum_lane_attrs(rung[0]._kspec[3], rung[0].segment)}
+
+
+def drive_group_execution(run, group_spec, padded: int, total_docs: int,
+                          segment):
+    """ONE segment's group-by ladder walked to its end (`SegmentLadder`,
+    `walk_ladders` with one ladder: a pull a rung).
     Returns (outs, group_spec_for_finish); None finish spec means the
-    filter matched nothing (outs still carries the stats).
-
-    Each phase runs under a span of its own (`groupScout`, `groupHist`,
-    `groupTable` with the layout, the key space and the runs) around
-    its launches and pulls, and every segment marks the ladder's meters
-    once (`mark_group_ladder`: with its table, or where the filter
-    matched nothing): what a segment took, not what it costs.
-    """
-    pa = adaptive_phase_a_specs(group_spec) \
-        if padded <= kernels.DENSE_ROWS_LIMIT else None
-    if pa is None:
-        return _run_group_table(lambda gs: run((), gs, ()), group_spec,
-                                padded, 0, 0, segment)
-    # one batched device→host transfer per scout dispatch; the
-    # per-bound int() reads below are host numpy, not device pulls
-    with obs_span(ServerQueryPhase.GROUP_SCOUT):
-        ha = profiled_device_get(run(pa, None, ()))
-    bounds = [(int(ha[f"agg{2 * i}.min"]), int(ha[f"agg{2 * i + 1}.max"]))
-              for i in range(len(pa) // 2)]
-    matched = int(ha["stats.num_docs_matched"])
-    scout = [("bounds", lo, hi) for lo, hi in bounds]
-    ph = adaptive_hist_specs(group_spec, bounds) if matched > 0 else None
-    if ph is not None:
-        with obs_span(ServerQueryPhase.GROUP_HIST):
-            hh = profiled_device_get(run(ph, None, ()))
-        scout = [("present",
-                  np.nonzero(np.asarray(hh[f"agg{i}"])[: c[3]])[0])
-                 for i, c in enumerate(group_spec[0])]
-    hist = int(ph is not None)
-    kspec, fspec, extra, empty = adaptive_phase_b_spec(
-        group_spec, scout, matched, padded, total_docs)
-    if empty:
-        mark_group_ladder(1, hist, 0, None)
-        return ha, None
-    outs, final = _run_group_table(lambda gs: run((), gs, extra), kspec,
-                                   padded, 1, hist, segment)
-    if final is not kspec:            # ladder escalated kmax
-        fspec = fspec[:4] + (final[4],)
-    return outs, fspec
+    filter matched nothing (outs still carries the stats)."""
+    ladder = SegmentLadder(run, (), group_spec, padded, total_docs, segment)
+    walk_ladders([ladder])
+    return ladder.outs, ladder.finish_spec
 
 
 def _agg_device_spec(f: AggregationFunction, segment: ImmutableSegment,

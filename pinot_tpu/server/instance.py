@@ -84,6 +84,9 @@ class ServerInstance:
         # the sum lanes' meters, at 0 from boot
         from pinot_tpu.obs.profiler import bind_sum_lane_metrics
         bind_sum_lane_metrics(self.metrics)
+        # the scan walk's meters, at 0 from boot
+        from pinot_tpu.obs.profiler import bind_walk_metrics
+        bind_walk_metrics(self.metrics)
         from pinot_tpu.obs import residency
         residency.bind_registry(self.metrics)
         self.data_manager = InstanceDataManager()

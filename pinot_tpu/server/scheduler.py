@@ -50,6 +50,17 @@ class QueryScheduler:
     threads). They must be distinct: a runner blocks on its segment
     futures, so per-segment work scheduled back onto the runner pool
     would deadlock once every runner waits on work none can start.
+
+    What the segment pool serves since PR 38 (`query/executor.py`
+    `_run_segments`): a query's device scans are ONE task of it, the
+    walk that launches every segment's program of a rung before the
+    rung's one pull (the runner waits on it under the deadline, so a
+    program that compiles for half a minute does not hold the reply);
+    a task a segment is left for what is no device scan: a consuming
+    segment (its frozen half on the device, its tail on the host), a
+    segment gated off the device, a `host` route the walk hands over. A
+    worker never waits on the pool. Its size (4) was fitted to a task a
+    segment and has not been swept since (ROADMAP A8).
     """
 
     def __init__(self, num_workers: int = 4,

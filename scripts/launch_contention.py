@@ -11,12 +11,15 @@ warms every program, and then answers each query's eight plans
 
   (a) `pool`: on 4 pool threads, a segment a task, each task the solo
       path (`plan.execute()`: gather, launch, its own blocking pull,
-      finish), the caller gathering the futures: what `_run_parallel`
-      does;
+      finish), the caller gathering the futures: what the pool walk
+      of `query/executor.py` did up to PR 37 (`_run_parallel`);
   (b) `one_thread`: on the calling thread alone, every program
       launched before ONE pull, with this script's own steps from the
       program's `gather_operands`, `run_segment_kernel` and finishers
-      (the walk PR 33 tried in `_run_parallel` and took out again);
+      (the walk PR 33 tried and took out again; since PR 38
+      `_walk_scans` does it with the program's own
+      `execution.execute_segment_plans`, group-by ladders included:
+      `ladder_contention.py`);
 
 each with 0 and with 3 background threads that run pure Python and so
 want the lock all the time (what a server's other runner threads do

@@ -452,11 +452,13 @@ def _failing_plan_maker(where):
             if where == "plan":
                 raise UnsupportedOnDevice("refused by the test")
             plan = super().make_segment_plan(segment, request)
-
-            def boom():
-                raise GroupsLimitExceeded("found while running")
-            plan.execute = boom
+            # refuses at its first launch, whoever walks it
+            plan.params = _RefusesWhenRead(plan.params)
             return plan
+
+    class _RefusesWhenRead(list):
+        def __iter__(self):
+            raise GroupsLimitExceeded("found while running")
     return Maker()
 
 
@@ -517,48 +519,53 @@ def test_alone_and_batched_agree_on_every_route(route):
     assert batch_profile.paths == want_paths
 
 
-# pql → the profile's path totals over the five segments of
+# pql → the profile's path totals over the seven segments of
 # `_mixed_route_segments` (a cube hit counts `cube`; the consuming
 # segment's frozen part scans, its tail takes the host twin)
 _MIXED_PQLS = {
-    # a cube covers it: plain_0 and plain_2 are one-launch scans
+    # a cube covers it: the four plain segments are one-launch scans
     "cube_covered_sum": (
         "SELECT SUM(runs), COUNT(*) FROM baseballStats "
-        "WHERE teamID = 'BOS'", {"scan": 3, "cube": 1, "host": 2}),
-    # no cube holds salary: four scans; a raw FLOAT lane summed in
+        "WHERE teamID = 'BOS'", {"scan": 5, "cube": 1, "host": 2}),
+    # no cube holds salary: six scans; a raw FLOAT lane summed in
     # float block sums, so the order of the combine shows in the answer
     "float_sum": (
         "SELECT SUM(salary), COUNT(*) FROM baseballStats "
-        "WHERE runs > '40'", {"scan": 4, "host": 2}),
-    # a group-by ladder a segment
+        "WHERE runs > '40'", {"scan": 6, "host": 2}),
+    # a group-by ladder a segment: four of them walked in phases beside
+    # the cube hit, the consuming segment's and the gated one
     "group_by": (
         "SELECT SUM(hits) FROM baseballStats WHERE teamID IN "
         "('BOS', 'NYA', 'SEA') GROUP BY teamID TOP 30",
-        {"scan": 3, "cube": 1, "host": 2}),
+        {"scan": 5, "cube": 1, "host": 2}),
     "selection": (
         "SELECT playerName, runs FROM baseballStats WHERE runs > '120' "
-        "ORDER BY runs DESC, playerName LIMIT 15", {"scan": 4, "host": 2}),
+        "ORDER BY runs DESC, playerName LIMIT 15", {"scan": 6, "host": 2}),
 }
 
 
 @pytest.fixture(scope="module")
 def mixed_route_segments():
     """[plain_0, cube, consuming (frozen + tail), plain_1 (gated off
-    the device), plain_2], of unequal sizes, and the gate."""
+    the device), plain_2, plain_3, plain_4], of unequal sizes, and the
+    gate."""
     plain = [build_segment(tempfile.mkdtemp(), n=500 + 100 * i,
                            seed=70 + i, name=f"mx_{i}")[0]
-             for i in range(3)]
+             for i in range(5)]
     segments = [plain[0], _cube_segments(1)[0], _consuming_segment(),
-                plain[1], plain[2]]
+                plain[1], plain[2], plain[3], plain[4]]
     return segments, lambda seg: seg is not plain[1]
 
 
 @pytest.mark.parametrize("shape", sorted(_MIXED_PQLS))
 def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
         mixed_route_segments, shape, monkeypatch):
-    """One query whose segments take every route: the pool's tasks
-    together give what the plain sequential walk gives, combined in
-    the segments' order."""
+    """One query whose segments take every route, walked three ways:
+    on the calling thread; with a pool (the scans' walk one task, the
+    consuming and the gated segment a task each); and with a pool and
+    the walk refused, a segment a task and a pull a program (the pool
+    walk up to PR 37). All give what the first gives, combined in the
+    segments' order."""
     from pinot_tpu.obs import profiler as obs_profiler
     from pinot_tpu.obs.profiler import QueryProfile
     from pinot_tpu.query import executor as executor_mod
@@ -574,11 +581,17 @@ def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
         return real_combine(req, blocks)
     monkeypatch.setattr(executor_mod, "combine_blocks", spy_combine)
 
+    class SegmentATask(ServerQueryExecutor):
+        def _walks(self, seg):
+            return False
+
     pool = ThreadPoolExecutor(4)
     try:
-        seen, inters, paths = [], [], []
-        for kw in ({}, {"segment_executor": pool}):
-            ex = ServerQueryExecutor(**kw)
+        seen, inters, paths, dispatches = [], [], [], []
+        for cls, kw in ((ServerQueryExecutor, {}),
+                        (ServerQueryExecutor, {"segment_executor": pool}),
+                        (SegmentATask, {"segment_executor": pool})):
+            ex = cls(**kw)
             ex.device_gate = gate
             profile = QueryProfile("t")
             with obs_profiler.active(profile, None):
@@ -586,15 +599,19 @@ def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
             seen.append(_seen(request, blk))
             inters.append(blk.agg_intermediates)
             paths.append(profile.paths)
+            dispatches.append((profile.dispatches, profile.transfer_bytes))
     finally:
         pool.shutdown(wait=True)
     assert not seen[0][3], seen[0][3]
-    assert seen[0] == seen[1]
-    assert inters[0] == inters[1]          # exactly: the same float sums
-    assert paths[0] == paths[1] == want_paths
-    # six blocks (the consuming segment gives two), in `selected`'s order
-    assert len(combined) == 2 and combined[0] == combined[1]
-    assert len(combined[0]) == 6
+    assert seen[0] == seen[1] == seen[2]
+    assert inters[0] == inters[1] == inters[2]   # the same float sums
+    assert paths[0] == paths[1] == paths[2] == want_paths
+    # the same programs and the same bytes, however they were pulled
+    assert dispatches[0] == dispatches[1] == dispatches[2]
+    # eight blocks (the consuming segment gives two), in `selected`'s
+    # order
+    assert len(combined) == 3 and combined[0] == combined[1] == combined[2]
+    assert len(combined[0]) == 8
 
 
 @pytest.mark.parametrize("case", ["missing_table", "expired_deadline"])

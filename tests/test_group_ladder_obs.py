@@ -1,9 +1,11 @@
-"""The device group-by ladder under spans and meters (PR 35).
+"""The device group-by ladder under spans and meters (PR 35), walked
+in phases (PR 38).
 
-`query/plan.py` `drive_group_execution` runs a scout, where it pays a
-histogram rung, and a group table with its kmax re-runs; each phase is
-a span (`groupScout`, `groupHist`, `groupTable`) around its launches
-and pulls, and every exit marks the ladder's meters once
+`query/plan.py` `walk_ladders` drives a query's `SegmentLadder`s: a
+scout, where it pays a histogram rung, and a group table with its kmax
+re-runs; each phase is ONE span for all the query's segments
+(`groupScout`, `groupHist`, `groupTable`) around their launches and the
+phase's one pull, and every ladder marks the ladder's meters once
 (`obs/profiler.py` `mark_group_ladder`). Held here to what ran: SSB's
 13 shapes over small dbgen segments without cubes, built as the
 benchmark builds them, on the CPU. Nothing here is a measurement.
@@ -30,9 +32,11 @@ GROUP_SPANS = (ServerQueryPhase.GROUP_SCOUT, ServerQueryPhase.GROUP_HIST,
 DISPATCH_METERS = (ServerMeter.GROUP_SCOUT_DISPATCHES,
                    ServerMeter.GROUP_HIST_DISPATCHES,
                    ServerMeter.GROUP_TABLE_DISPATCHES)
+WALK_METERS = (ServerMeter.SCAN_WALK_SEGMENTS, ServerMeter.SCAN_POOL_SEGMENTS,
+               ServerMeter.DEVICE_PROGRAMS, ServerMeter.DEVICE_PULLS)
 LADDER_METERS = (ServerMeter.GROUP_SEGMENTS, *DISPATCH_METERS,
                  ServerMeter.GROUP_ESCALATIONS, ServerMeter.GROUP_EMPTY,
-                 *ServerMeter.GROUP_TABLES.values())
+                 *ServerMeter.GROUP_TABLES.values(), *WALK_METERS)
 
 
 def _walk(node, parent=None):
@@ -45,24 +49,26 @@ class Ladder:
     """Small SSB segments without cubes, an executor over them, and a
     registry of this test's own with the ladder's meters bound."""
 
-    def __init__(self, base: str):
+    def __init__(self, base: str, rows: int = ROWS,
+                 segments: int = SEGMENTS):
         from harness import build, cells, shapes, tables
         from pinot_tpu.segment.loader import ImmutableSegmentLoader
         config = dict(cells.load_json(BENCH_DIR, "configs",
                                       "ssb_flat_nocube.json"),
-                      rows=ROWS, segments=SEGMENTS)
+                      rows=rows, segments=segments)
         self.segments = [
             ImmutableSegmentLoader.load(build.build_segment(
                 (config, SEED, i, hi - lo, base)))
             for i, (lo, hi) in enumerate(
-                tables.segment_bounds(ROWS, SEGMENTS))]
-        table = tables.make_table(tables.load_generator("ssb_dbgen"),
-                                  ROWS, SEGMENTS, SEED)
-        self.pools = table.pools
+                tables.segment_bounds(rows, segments))]
+        self.table = tables.make_table(tables.load_generator("ssb_dbgen"),
+                                       rows, segments, SEED)
+        self.pools = self.table.pools
         self.shapes = {s.name: s for s in shapes.load_family(
-            BENCH_DIR, "ssb", table.pools)}
+            BENCH_DIR, "ssb", self.pools)}
         self.metrics = MetricsRegistry("server")
         obs_profiler.bind_group_metrics(self.metrics)
+        obs_profiler.bind_walk_metrics(self.metrics)
 
     def meters(self) -> dict:
         return {m: self.metrics.meter(m).count for m in LADDER_METERS}
@@ -124,41 +130,74 @@ def test_the_ladders_meters_read_zero_at_boot():
         server.stop()
 
 
+def _phases(spans):
+    """(the walk's `queryPlanExecution` node, its group phase spans)."""
+    tree = build_trace_tree(spans)
+    (plan,) = [n for n, _p in _walk(tree)
+               if n["name"] == ServerQueryPhase.QUERY_PLAN_EXECUTION]
+    return plan, [c for c in plan["children"] if c["name"] in GROUP_SPANS]
+
+
 @pytest.mark.parametrize("shape", GROUP_BYS)
 def test_a_traced_group_by_carries_the_ladders_spans(traced_runs, shape):
-    _answer, _profile, spans, grown = traced_runs[shape]
-    tree = build_trace_tree(spans)
-    plans = [n for n, _p in _walk(tree)
-             if n["name"] == ServerQueryPhase.QUERY_PLAN_EXECUTION]
-    assert len(plans) == SEGMENTS
-    n_hist = 0
-    for plan in plans:
-        phases = [c for c in plan["children"] if c["name"] in GROUP_SPANS]
-        names = [c["name"] for c in phases]
-        # a scout always; a table unless the filter matched nothing
-        assert names[0] == ServerQueryPhase.GROUP_SCOUT
-        assert names.count(ServerQueryPhase.GROUP_SCOUT) == 1
-        assert names.count(ServerQueryPhase.GROUP_TABLE) <= 1
-        n_hist += names.count(ServerQueryPhase.GROUP_HIST)
-        for phase in phases:
-            inside = [c["name"] for c in phase["children"]]
-            runs = (phase.get("attrs") or {}).get("runs", 1)
-            # each launch and the pull that waits for it, in order
-            assert inside == [ServerQueryPhase.KERNEL_LAUNCH,
-                              ServerQueryPhase.KERNEL_DISPATCH] * runs
-        # no launch of a group-by outside its phase's span
-        assert ServerQueryPhase.KERNEL_LAUNCH not in \
-            [c["name"] for c in plan["children"]]
-        assert sum(c["ms"] for c in phases) <= plan["ms"]
-        for table in phases:
-            if table["name"] != ServerQueryPhase.GROUP_TABLE:
-                continue
-            attrs = table["attrs"]
-            assert attrs["layout"] in ServerMeter.GROUP_TABLES
-            assert attrs["scouted"] is True and attrs["runs"] >= 1
-            assert attrs["g"] >= 1 and attrs["g"] & (attrs["g"] - 1) == 0
-    # the histogram rung's span exactly where the rung ran
-    assert n_hist == grown[ServerMeter.GROUP_HIST_DISPATCHES]
+    _answer, profile, spans, grown = traced_runs[shape]
+    # ONE walk a query: the phases nest under its queryPlanExecution
+    plan, phases = _phases(spans)
+    assert plan["attrs"] == {"segments": SEGMENTS}
+    names = [c["name"] for c in phases]
+    # a scout always; a table unless every filter matched nothing; the
+    # histogram rung's span exactly where the rung ran
+    assert names[0] == ServerQueryPhase.GROUP_SCOUT
+    assert names.count(ServerQueryPhase.GROUP_SCOUT) == 1
+    assert names.count(ServerQueryPhase.GROUP_TABLE) <= 1
+    assert names.count(ServerQueryPhase.GROUP_HIST) == \
+        bool(grown[ServerMeter.GROUP_HIST_DISPATCHES])
+    launches = 0
+    for phase in phases:
+        inside = [c["name"] for c in phase["children"]]
+        n = phase["attrs"]["segments"]
+        runs = max(phase["attrs"].get("runs", [1]))
+        # every launch of the phase, then its one pull (and the drop of
+        # the device outputs); a re-run is a launch and a pull more
+        assert inside[:n + 2] == [ServerQueryPhase.KERNEL_LAUNCH] * n + \
+            [ServerQueryPhase.KERNEL_DISPATCH,
+             ServerQueryPhase.OUTPUT_RELEASE]
+        assert inside.count(ServerQueryPhase.KERNEL_DISPATCH) == runs
+        launches += inside.count(ServerQueryPhase.KERNEL_LAUNCH)
+        pull = phase["children"][n]
+        assert pull["attrs"]["programs"] == n and pull["attrs"]["bytes"] > 0
+    assert launches == profile["kernelDispatches"]
+    # no launch of a group-by outside its phase's span
+    assert ServerQueryPhase.KERNEL_LAUNCH not in \
+        [c["name"] for c in plan["children"]]
+    assert sum(c["ms"] for c in phases) <= plan["ms"]
+    for table in phases:
+        if table["name"] != ServerQueryPhase.GROUP_TABLE:
+            continue
+        attrs = table["attrs"]
+        assert len(attrs["layout"]) == len(attrs["g"]) == \
+            len(attrs["runs"]) == attrs["segments"]
+        assert set(attrs["layout"]) <= set(ServerMeter.GROUP_TABLES)
+        assert attrs["scouted"] is True and min(attrs["runs"]) >= 1
+        assert all(g >= 1 and g & (g - 1) == 0 for g in attrs["g"])
+
+
+@pytest.mark.parametrize("shape", ["q1.1"] + GROUP_BYS)
+def test_a_phase_is_one_pull_over_all_its_programs(traced_runs, shape):
+    """`devicePulls` counts the phases a query stopped for, and
+    `devicePrograms` what they brought home: the profile's
+    `kernelDispatches`, which still counts PROGRAMS."""
+    _answer, profile, spans, grown = traced_runs[shape]
+    _plan, phases = _phases(spans)
+    re_runs = grown[ServerMeter.GROUP_ESCALATIONS]
+    assert grown[ServerMeter.DEVICE_PULLS] == \
+        (len(phases) + bool(re_runs) if shape != "q1.1" else 1)
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == \
+        profile["kernelDispatches"]
+    assert grown[ServerMeter.DEVICE_PULLS] == sum(
+        1 for s in spans if s["name"] == ServerQueryPhase.KERNEL_DISPATCH)
+    assert grown[ServerMeter.SCAN_WALK_SEGMENTS] == SEGMENTS
+    assert grown[ServerMeter.SCAN_POOL_SEGMENTS] == 0
 
 
 def test_the_histogram_rung_runs_for_q31_and_not_for_q21(traced_runs):
@@ -183,8 +222,8 @@ def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
         assert tables + grown[ServerMeter.GROUP_EMPTY] == SEGMENTS
         assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == \
             tables + grown[ServerMeter.GROUP_ESCALATIONS]
-        assert tables == sum(1 for s in spans if s["name"] ==
-                             ServerQueryPhase.GROUP_TABLE)
+        assert tables == sum(s["attrs"]["segments"] for s in spans
+                             if s["name"] == ServerQueryPhase.GROUP_TABLE)
         dispatches += profile["kernelDispatches"]
         for m in LADDER_METERS:
             total[m] += grown[m]
@@ -198,7 +237,7 @@ def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
 def test_a_scan_without_group_by_marks_nothing(traced_runs, shape):
     _answer, profile, spans, grown = traced_runs[shape]
     assert profile["kernelDispatches"] == SEGMENTS
-    assert not any(grown.values())
+    assert not any(n for m, n in grown.items() if m not in WALK_METERS)
     assert not [s for s in spans if s["name"] in GROUP_SPANS]
 
 
@@ -241,10 +280,10 @@ def test_an_unscouted_group_by_is_a_table_that_says_so(ladder):
     answer, profile, spans, grown = ladder.run(
         "SELECT COUNT(*) FROM lineorder GROUP BY d_year TOP 10", traced=True)
     assert len(answer["aggregationResults"][0]["groupByResult"]) == 7
-    tables = [s for s in spans if s["name"] == ServerQueryPhase.GROUP_TABLE]
-    assert len(tables) == SEGMENTS
-    assert all(t["attrs"]["scouted"] is False and
-               t["attrs"]["layout"] == "dense" for t in tables)
+    (table,) = [s for s in spans
+                if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    assert table["attrs"]["scouted"] is False and \
+        table["attrs"]["layout"] == ["dense"] * SEGMENTS
     assert not [s for s in spans
                 if s["name"] in (ServerQueryPhase.GROUP_SCOUT,
                                  ServerQueryPhase.GROUP_HIST)]
@@ -267,10 +306,10 @@ def test_a_barely_selective_float_group_by_goes_dense_and_equals_the_host(
            "d_year TOP 10000")
     answer, profile, spans, grown = ladder.run(pql, traced=True)
     assert answer["numDocsScanned"] > 0.06 * answer["totalDocs"]
-    tables = [s for s in spans if s["name"] == ServerQueryPhase.GROUP_TABLE]
-    assert len(tables) == SEGMENTS
-    assert all(t["attrs"]["layout"] == "dense" and t["attrs"]["scouted"]
-               and t["attrs"]["runs"] == 1 for t in tables)
+    (table,) = [s for s in spans
+                if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    assert table["attrs"]["layout"] == ["dense"] * SEGMENTS and \
+        table["attrs"]["scouted"] and table["attrs"]["runs"] == [1, 1]
     assert grown[ServerMeter.GROUP_TABLES["dense"]] == SEGMENTS
     assert profile["paths"] == {"scan": SEGMENTS}
     host, host_profile, _spans, _grown = ladder.run(pql, traced=False,
@@ -280,6 +319,136 @@ def test_a_barely_selective_float_group_by_goes_dense_and_equals_the_host(
     assert len(groups) == 5 * 10 * 7
     assert answer["aggregationResults"] == host["aggregationResults"]
     assert answer["numDocsScanned"] == host["numDocsScanned"]
+
+
+# -- eight segments a query: what one phase does for all of them -------------
+
+EIGHT = 8
+
+
+@pytest.fixture(scope="module")
+def ladder8(tmp_path_factory):
+    return Ladder(str(tmp_path_factory.mktemp("ladder_segments8")),
+                  rows=80_000, segments=EIGHT)
+
+
+def _nation_and_city_some_segments_lack(ladder):
+    """(c_nation, s_city, segments they meet in): each value occurs in
+    every segment (so no planner folds the predicate away), the pair in
+    some segments only, which only the device can tell."""
+    import numpy as np
+    nations, cities = ladder.pools["c_nation"], ladder.pools["s_city"]
+    ids = [seg[0] for seg in ladder.table.segments]
+    for n in range(len(nations)):
+        for c in range(len(cities)):
+            both = [int(np.count_nonzero((i["c_nation"] == n) &
+                                         (i["s_city"] == c))) for i in ids]
+            each = all(np.any(i["c_nation"] == n) and np.any(i["s_city"] == c)
+                       for i in ids)
+            if each and 2 <= sum(b == 0 for b in both) <= EIGHT - 2:
+                return nations[n], cities[c], [b > 0 for b in both]
+    raise AssertionError("no such pair in these rows")
+
+
+def test_a_segment_that_matches_nothing_runs_no_table_beside_siblings_that_do(
+        ladder8):
+    nation, city, meets = _nation_and_city_some_segments_lack(ladder8)
+    pql = (f"SELECT SUM(lo_revenue) FROM lineorder WHERE c_nation = "
+           f"'{nation}' AND s_city = '{city}' GROUP BY d_year TOP 100")
+    answer, profile, spans, grown = ladder8.run(pql, traced=True)
+    host, *_ = ladder8.run(pql, traced=False, use_device=False)
+    assert answer["aggregationResults"] == host["aggregationResults"]
+    assert answer["aggregationResults"][0]["groupByResult"]
+    tables, empty = sum(meets), EIGHT - sum(meets)
+    # the ladder's meters once a segment, whichever way it left
+    assert grown[ServerMeter.GROUP_SEGMENTS] == EIGHT
+    assert grown[ServerMeter.GROUP_SCOUT_DISPATCHES] == EIGHT
+    assert grown[ServerMeter.GROUP_EMPTY] == empty
+    assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == tables
+    assert sum(grown[m] for m in ServerMeter.GROUP_TABLES.values()) == tables
+    # two phases, two pulls; the table's phase holds the siblings alone
+    assert grown[ServerMeter.DEVICE_PULLS] == 2
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == EIGHT + tables == \
+        profile["kernelDispatches"]
+    _plan, (scout, table) = _phases(spans)
+    assert scout["attrs"] == {"segments": EIGHT}
+    assert table["attrs"]["segments"] == tables == \
+        len(table["attrs"]["layout"])
+    assert [c["name"] for c in table["children"]].count(
+        ServerQueryPhase.KERNEL_LAUNCH) == tables
+    assert profile["paths"] == {"scan": EIGHT}
+
+
+def test_a_forced_re_run_of_one_segment_of_eight_re_runs_it_alone(
+        ladder8, monkeypatch):
+    """The third segment's first table reports overflow (forced here:
+    about one table in 8,000 does): the table's phase launches that
+    segment's next rung alone and pulls once more; the answer is what
+    the walk without the re-run gives."""
+    import jax.numpy as jnp
+    from pinot_tpu.ops import kernels
+    shape = ladder8.shapes["q2.2"]
+    pql = shape.pql(shape.spec["ssb"])
+    want, _profile, _spans, plain = ladder8.run(pql, traced=False)
+    assert plain[ServerMeter.GROUP_TABLES["compacted"]] == EIGHT
+    real, tables = kernels.run_segment_kernel, []
+
+    def overflow_once(padded, filt, aggs, group_spec, *rest):
+        outs = real(padded, filt, aggs, group_spec, *rest)
+        if group_spec is not None:
+            tables.append(group_spec[4])
+            if len(tables) == 3:
+                outs = dict(outs, **{"group.overflow": jnp.int32(1)})
+        return outs
+    monkeypatch.setattr(kernels, "run_segment_kernel", overflow_once)
+    answer, profile, spans, grown = ladder8.run(pql, traced=True)
+    assert answer == want
+    # eight tables, then the third segment's at four times the slots
+    assert len(tables) == EIGHT + 1 and tables[-1] >= 4 * tables[2]
+    assert grown[ServerMeter.GROUP_ESCALATIONS] == 1
+    assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == EIGHT + 1
+    assert grown[ServerMeter.GROUP_SEGMENTS] == EIGHT
+    assert grown[ServerMeter.DEVICE_PULLS] == 3
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == 2 * EIGHT + 1 == \
+        profile["kernelDispatches"]
+    _plan, (_scout, table) = _phases(spans)
+    assert table["attrs"]["runs"] == [1, 1, 2, 1, 1, 1, 1, 1]
+    assert [c["name"] for c in table["children"]] == \
+        [ServerQueryPhase.KERNEL_LAUNCH] * EIGHT + \
+        [ServerQueryPhase.KERNEL_DISPATCH, ServerQueryPhase.OUTPUT_RELEASE,
+         ServerQueryPhase.KERNEL_LAUNCH, ServerQueryPhase.KERNEL_DISPATCH,
+         ServerQueryPhase.OUTPUT_RELEASE]
+    assert [c["attrs"]["programs"] for c in table["children"]
+            if c["name"] == ServerQueryPhase.KERNEL_DISPATCH] == [EIGHT, 1]
+
+
+@pytest.mark.parametrize("shape", ["q1.2", "q2.2", "q3.1", "q4.1"])
+def test_eight_segments_in_phases_answer_as_eight_walked_alone(
+        ladder8, shape, monkeypatch):
+    """The walk over a query's eight ladders against each ladder
+    walked alone (`_walks` refused: a segment a piece, a pull a
+    program, what a pool task did before PR 38): the same answer, the
+    same programs, the same bytes, the same marks on the ladder's
+    meters; only the pulls differ."""
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    s = ladder8.shapes[shape]
+    pql = s.pql(s.spec["ssb"])
+    answer, profile, _spans, grown = ladder8.run(pql, traced=False)
+    monkeypatch.setattr(ServerQueryExecutor, "_walks",
+                        lambda self, seg: False)
+    alone, alone_profile, _s, alone_grown = ladder8.run(pql, traced=False)
+    assert answer == alone
+    for key in ("kernelDispatches", "deviceTransferBytes", "paths",
+                "docsScanned", "segmentsMatched"):
+        assert profile[key] == alone_profile[key], key
+    assert {m: n for m, n in grown.items() if m not in WALK_METERS} == \
+        {m: n for m, n in alone_grown.items() if m not in WALK_METERS}
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == \
+        alone_grown[ServerMeter.DEVICE_PROGRAMS] == \
+        alone_grown[ServerMeter.DEVICE_PULLS]
+    assert grown[ServerMeter.DEVICE_PULLS] <= 3
+    assert (grown[ServerMeter.SCAN_WALK_SEGMENTS],
+            alone_grown[ServerMeter.SCAN_POOL_SEGMENTS]) == (EIGHT, EIGHT)
 
 
 def test_a_kmax_re_run_is_counted_as_an_escalation():
@@ -304,9 +473,9 @@ def test_a_kmax_re_run_is_counted_as_an_escalation():
     assert launched == [1024, 4096, 16384] and final[4] == 16384
     (table,) = [s for s in trace.to_list()
                 if s["name"] == ServerQueryPhase.GROUP_TABLE]
-    assert table["attrs"] == {"layout": "compacted", "g": 8, "runs": 3,
-                              "scouted": False, "partLanes": 0,
-                              "valueLanes": 0}
+    assert table["attrs"] == {"segments": 1, "layout": ["compacted"],
+                              "g": [8], "runs": [3], "scouted": False,
+                              "partLanes": 0, "valueLanes": 0}
     count = {m: reg.meter(m).count for m in LADDER_METERS}
     assert count[ServerMeter.GROUP_TABLE_DISPATCHES] == 3
     assert count[ServerMeter.GROUP_ESCALATIONS] == 2

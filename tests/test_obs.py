@@ -496,27 +496,36 @@ def test_start_us_across_the_broker_server_merge(obs_cluster):
         # is the broker's dispatch span and the child the server's root
         assert node["startUs"] >= parent["startUs"] - 1000, (node, parent)
         assert _end_us(node) <= _end_us(parent) + 1000, (node, parent)
-    # every executed segment has one queue wait, a sibling of its span
+    # the scans' walk waits once for its thread; every segment it
+    # routes has a span, siblings of the one queryPlanExecution
     for node, _p in nodes:
         if node["name"] != "segmentExecution":
             continue
         kids = [c["name"] for c in node["children"]]
-        assert kids.count("segmentQueueWait") == kids.count("segment") == 2
-        segs = {c["attrs"]["segment"] for c in node["children"]}
+        assert kids.count("segment") == 2
+        assert kids.count("segmentQueueWait") == \
+            kids.count("queryPlanExecution") == 1
+        segs = {c["attrs"]["segment"] for c in node["children"]
+                if c["name"] == "segment"}
         assert len(segs) == 2
-    # the five spans tile queryPlanExecution on the aggregation path
+        (wait,) = [c for c in node["children"]
+                   if c["name"] == "segmentQueueWait"]
+        assert wait["attrs"] == {"segments": 2}
+    # the walk's spans tile queryPlanExecution on the aggregation path:
+    # a gather and a launch a segment, ONE pull, a finish a segment
     for node, _p in nodes:
         if node["name"] != "queryPlanExecution":
             continue
         kids = [c["name"] for c in node["children"]]
-        assert kids == ["operandGather", "kernelLaunch", "kernelDispatch",
-                        "outputRelease", "resultFinish"]
+        assert kids == ["operandGather"] * 2 + ["kernelLaunch"] * 2 + \
+            ["kernelDispatch", "outputRelease"] + ["resultFinish"] * 2
         # ... to within 5% or 0.2 ms, whichever is more
         inside = sum(c["ms"] for c in node["children"])
         assert node["ms"] - inside <= max(0.05 * node["ms"], 0.2)
         starts = [c["startUs"] for c in node["children"]]
         assert starts == sorted(starts)
-        assert node["children"][2]["attrs"]["bytes"] > 0
+        assert node["children"][4]["attrs"]["bytes"] > 0
+        assert node["children"][4]["attrs"]["programs"] == 2
     # traced responses say which path answered, beside the tree
     assert resp.profile_info["paths"] == {"scan": 4}
     assert resp.to_json()["profileInfo"]["kernelDispatches"] == 4
@@ -618,9 +627,12 @@ def test_star_tree_execute_span_says_hit_or_miss(star_tree_segments, batch):
     assert sorted(s["attrs"].get("segment", "") for s in st) == \
         ["", "st_0", "st_1"]
     names = [s["name"] for s in spans]
-    for name in ("segmentQueueWait", "operandGather", "kernelLaunch",
-                 "kernelDispatch", "outputRelease", "resultFinish"):
+    for name in ("operandGather", "kernelLaunch", "resultFinish"):
         assert names.count(name) == 2, (name, names)
+    # the solo walk launches both segments before its one pull; the
+    # batched walk runs a segment after the other
+    for name in ("segmentQueueWait", "kernelDispatch", "outputRelease"):
+        assert names.count(name) == (2 if batch else 1), (name, names)
 
 
 def _http(port, path, body=None):

@@ -64,8 +64,10 @@ def answer(seg, pql):
     assert profile.to_json()["paths"] == {"scan": 1}
     body = BrokerReduceService().reduce(request, [block]).to_json()
     assert not body["exceptions"]
-    return body, [s["attrs"]["layout"] for s in trace.to_list()
-                  if s["name"] == ServerQueryPhase.GROUP_TABLE]
+    # a `groupTable` span a query, a layout a segment that ran a table
+    return body, [layout for s in trace.to_list()
+                  if s["name"] == ServerQueryPhase.GROUP_TABLE
+                  for layout in s["attrs"]["layout"]]
 
 
 def python_sum(cols, mask, group=None):

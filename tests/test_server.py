@@ -207,13 +207,19 @@ def test_server_reports_missing_segments(server_with_data):
     assert dt.to_block().agg_intermediates[0] == 2000
 
 
+_LADDER_PQL = ("SELECT SUM(runs) FROM baseballStats "
+               "WHERE yearID >= 1999 GROUP BY teamID TOP 5")
+
+
 @pytest.mark.parametrize("walk,pql", [
     # one launch a segment
     ("one_launch", "SELECT SUM(runs) FROM baseballStats "
                    "WHERE yearID >= 1999"),
     # a group-by ladder a segment: two or three launches
-    ("ladder", "SELECT SUM(runs) FROM baseballStats "
-               "WHERE yearID >= 1999 GROUP BY teamID TOP 5")])
+    ("ladder", _LADDER_PQL),
+    # the fault only in phase B: every scout launched and pulled, the
+    # first table's launch fails
+    ("ladder_table", _LADDER_PQL)])
 def test_device_fault_surfaces_and_never_reaches_the_host_twin(
         server_with_data, monkeypatch, walk, pql):
     """Only the planner's own verdicts (UnsupportedOnDevice,
@@ -223,8 +229,12 @@ def test_device_fault_surfaces_and_never_reaches_the_host_twin(
     from pinot_tpu.ops import kernels
     from pinot_tpu.query import host_exec
     server, _ = server_with_data
+    real, scouts = kernels.run_segment_kernel, []
 
-    def device_fault(*a, **k):
+    def device_fault(padded, filt, aggs, group_spec, *rest):
+        if walk == "ladder_table" and group_spec is None:
+            scouts.append(aggs)
+            return real(padded, filt, aggs, group_spec, *rest)
         raise RuntimeError("RESOURCE_EXHAUSTED: injected device fault")
 
     def host_twin(*a, **k):
@@ -237,6 +247,60 @@ def test_device_fault_surfaces_and_never_reaches_the_host_twin(
     assert any("RESOURCE_EXHAUSTED" in e for e in dt.exceptions), \
         dt.exceptions
     assert dt.num_rows() == 0
+    assert len(scouts) == (3 if walk == "ladder_table" else 0)
+
+
+@pytest.mark.parametrize("pooled", [True, False])
+def test_a_deadline_between_phases_truncates_and_waits_for_nothing(
+        monkeypatch, pooled):
+    """The budget runs out while the scans' walk waits for its scouts:
+    no table is launched, the reply carries the truncation exception
+    and the segments that finished (with a pool: the one gated to the
+    host, a task of its own), and the runner does not wait for the
+    walk."""
+    import concurrent.futures
+    import time
+    from pinot_tpu.ops import kernels
+    from pinot_tpu.query import plan as plan_mod
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    base = tempfile.mkdtemp()
+    segs = [build_segment(f"{base}/seg{i}", n=600, seed=80 + i,
+                          name=f"dl_{i}")[0] for i in range(3)]
+    request = compile_pql(_LADDER_PQL)
+    real_get, real_run = plan_mod.profiled_device_get, \
+        kernels.run_segment_kernel
+    tables = []
+
+    def slow_pull(x, programs=1):
+        time.sleep(0.6)
+        return real_get(x, programs)
+
+    def spy(padded, filt, aggs, group_spec, *rest):
+        if group_spec is not None:
+            tables.append(group_spec)
+        return real_run(padded, filt, aggs, group_spec, *rest)
+    pool = concurrent.futures.ThreadPoolExecutor(4) if pooled else None
+    try:
+        ex = ServerQueryExecutor(segment_executor=pool)
+        ex.device_gate = lambda seg: seg is not segs[1]
+        monkeypatch.setattr(kernels, "run_segment_kernel", spy)
+        ex.execute(request, segs)               # programs compiled
+        assert len(tables) == 2
+        monkeypatch.setattr(plan_mod, "profiled_device_get", slow_pull)
+        t0 = time.monotonic()
+        blk = ex.execute(request, segs, deadline=t0 + 0.3)
+        waited = time.monotonic() - t0
+    finally:
+        if pool is not None:
+            pool.shutdown(wait=True)            # the abandoned walk ends
+    done = 1 if pooled else 0
+    assert any(f"truncated at {done}/3" in e for e in blk.exceptions), \
+        blk.exceptions
+    assert blk.stats.num_segments_processed == done
+    # the walk stopped between its phases: no table after the deadline
+    assert len(tables) == 2
+    # the runner left at the deadline, before the scouts' pull came home
+    assert waited < (0.55 if pooled else 1.5)
 
 
 def test_server_unknown_table(server_with_data):

@@ -164,7 +164,9 @@ def test_a_shape_marks_one_meter_a_sum_a_segment_the_device_answered(
     _answer, profile, spans, grown = rig.shape(config_name, name)
     assert profile["paths"] == {"scan": SEGMENTS}
     tables = spans_named(spans, ServerQueryPhase.GROUP_TABLE)
-    summed = len(tables) if shape.spec["group_by"] else SEGMENTS
+    # a table's phase is one span over the segments that ran one
+    summed = sum(t["attrs"]["segments"] for t in tables) \
+        if shape.spec["group_by"] else SEGMENTS
     expected = dict.fromkeys(KINDS, 0)
     for col in aggregates:
         expected[EXPECTED_KIND[config_name, col]] += summed
@@ -202,7 +204,8 @@ def test_a_shape_marks_one_meter_a_sum_a_segment_the_device_answered(
 def test_a_q4_shaped_group_by_marks_two_a_segment(rig):
     for name in ("q4.1", "q4.2", "q4.3"):
         _a, _p, spans, grown = rig.shape("ssb_flat_nocube_dict", name)
-        tables = len(spans_named(spans, ServerQueryPhase.GROUP_TABLE))
+        tables = sum(t["attrs"]["segments"] for t in spans_named(
+            spans, ServerQueryPhase.GROUP_TABLE))
         assert tables > 0
         assert grown == {"parts": 2 * tables, "raw": 0, "value": 0,
                          "hist": 0}
@@ -247,7 +250,8 @@ def test_what_the_device_did_not_sum_marks_nothing(rig):
              "'UNITED KI1' AND s_city = 'UNITED KI5' GROUP BY d_year")
     answer, profile, spans, grown = rig.run(segments, empty)
     assert profile["paths"] == {"scan": SEGMENTS}
-    assert len(spans_named(spans, ServerQueryPhase.GROUP_SCOUT)) == SEGMENTS
+    (scout,) = spans_named(spans, ServerQueryPhase.GROUP_SCOUT)
+    assert scout["attrs"] == {"segments": SEGMENTS}
     assert not spans_named(spans, ServerQueryPhase.GROUP_TABLE)
     assert not answer["aggregationResults"][0]["groupByResult"]
     assert grown == none
