@@ -246,14 +246,28 @@ def _check_batched_kernels(buckets, x64: bool) -> List[str]:
 
 def _materialize_tree(spec, padded: int):
     """Materialize a pytree of (dtype, shape) leaves (extra kernel
-    cases): tuples whose first element is a string are leaves."""
+    cases): tuples whose first element is a string are leaves, dicts
+    stay dicts; a dim "P" is the padded doc count, "P/<k>" a k-th of it
+    (a stack's folded lanes)."""
     import numpy as np
+    if isinstance(spec, dict):
+        return {k: _materialize_tree(v, padded) for k, v in spec.items()}
     if isinstance(spec, tuple) and len(spec) == 2 and \
             isinstance(spec[0], str):
         dtype, shape = spec
-        shape = tuple(padded if s == "P" else s for s in shape)
+        shape = tuple(padded // int(s[2:] or 1)
+                      if isinstance(s, str) and s.startswith("P") else s
+                      for s in shape)
         return np.zeros(shape, dtype=np.dtype(dtype))
     return tuple(_materialize_tree(s, padded) for s in spec)
+
+
+def fill_static(args, padded: int):
+    """An extra case's static args with every "P", nested ones too (a
+    stacked case's lane shapes), the padded doc count."""
+    if isinstance(args, tuple):
+        return tuple(fill_static(a, padded) for a in args)
+    return padded if args == "P" else args
 
 
 def _check_extra_kernels(buckets, x64: bool) -> List[str]:
@@ -268,7 +282,7 @@ def _check_extra_kernels(buckets, x64: bool) -> List[str]:
     for name, builder, static_args, arg_specs in \
             kernels.extra_contract_cases():
         for padded in buckets:
-            args = tuple(padded if a == "P" else a for a in static_args)
+            args = fill_static(static_args, padded)
             try:
                 k1 = builder(*args)
                 k2 = builder(*args)
@@ -348,7 +362,7 @@ def compile_kernel_surface() -> dict:
     for name, builder, static_args, arg_specs in \
             kernels.extra_contract_cases():
         for padded in buckets:
-            args = tuple(padded if a == "P" else a for a in static_args)
+            args = fill_static(static_args, padded)
             todo.append((name, padded, builder(*args),
                          _materialize_tree(arg_specs, padded)))
 

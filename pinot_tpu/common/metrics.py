@@ -386,6 +386,12 @@ class ServerMeter:
     SCAN_POOL_SEGMENTS = "scanPoolSegments"
     DEVICE_PROGRAMS = "devicePrograms"
     DEVICE_PULLS = "devicePulls"
+    # the walk's stacked launches (query/execution.py, marked through
+    # obs/profiler.py mark_stacked_launch): one mark a launch of ONE
+    # program over members of a table's stack, the members it ran and
+    # 1; their ratio is the segments a stacked program serves
+    WALK_STACKED_SEGMENTS = "walkStackedSegments"
+    WALK_STACKED_PROGRAMS = "walkStackedPrograms"
     # the lanes a device SUM or AVG read (obs/profiler.py mark_sum_lanes,
     # one mark an aggregation a segment the device answered, by the
     # strategy query/plan.py _agg_device_spec gave it): integer part

@@ -297,10 +297,11 @@ _GROUP_METER_NAMES = (
     ServerMeter.GROUP_HIST_DISPATCHES, ServerMeter.GROUP_TABLE_DISPATCHES,
     ServerMeter.GROUP_ESCALATIONS, ServerMeter.GROUP_EMPTY,
     *ServerMeter.GROUP_TABLES.values())
-#: (scanWalkSegments, scanPoolSegments) and (devicePrograms,
-#: devicePulls) a registry
+#: (scanWalkSegments, scanPoolSegments), (devicePrograms, devicePulls)
+#: and (walkStackedSegments, walkStackedPrograms) a registry
 _WALK_METERS: tuple = ()
 _PULL_METERS: tuple = ()
+_STACK_METERS: tuple = ()
 #: (walkHostCpu, walkOffCpu) a registry
 _WALK_TIMERS: tuple = ()
 #: {lane kind: Meter} a registry, the sum lanes'
@@ -320,8 +321,10 @@ def bind_server_metrics(metrics) -> None:
     - meters `cubeDescentsNative` / `cubeDescentsNumpy`
       (`mark_cube_descents`), the group-by ladder's (`mark_group_ladder`),
       the scans' walk's `scanWalkSegments` / `scanPoolSegments`
-      (`mark_scan_segments`) and `devicePrograms` / `devicePulls`
-      (`profiled_device_get`), the sum lanes' (`mark_sum_lanes`);
+      (`mark_scan_segments`), `devicePrograms` / `devicePulls`
+      (`profiled_device_get`) and `walkStackedSegments` /
+      `walkStackedPrograms` (`mark_stacked_launch`), the sum lanes'
+      (`mark_sum_lanes`);
     - timers `walkHostCpu` / `walkOffCpu` (`walk_clock`) and `gcPause`
       (a `gc.callbacks` hook: every collection's pause; a generation >= 1
       collection and every compile also go into the ring of recent
@@ -329,7 +332,8 @@ def bind_server_metrics(metrics) -> None:
 
     The listeners and the hook are process-global and installed once."""
     global _listening, _COMPILE_METERS, _CUBE_METERS, _GROUP_METERS, \
-        _WALK_METERS, _PULL_METERS, _WALK_TIMERS, _SUM_METERS, _GC_TIMERS
+        _WALK_METERS, _PULL_METERS, _STACK_METERS, _WALK_TIMERS, \
+        _SUM_METERS, _GC_TIMERS
     with _bind_lock:
         _server_bound.add(metrics)
         bound = list(_server_bound)
@@ -349,6 +353,9 @@ def bind_server_metrics(metrics) -> None:
         _PULL_METERS = tuple(
             (m.meter(ServerMeter.DEVICE_PROGRAMS),
              m.meter(ServerMeter.DEVICE_PULLS)) for m in bound)
+        _STACK_METERS = tuple(
+            (m.meter(ServerMeter.WALK_STACKED_SEGMENTS),
+             m.meter(ServerMeter.WALK_STACKED_PROGRAMS)) for m in bound)
         _WALK_TIMERS = tuple((m.timer(ServerTimer.WALK_HOST_CPU),
                               m.timer(ServerTimer.WALK_OFF_CPU))
                              for m in bound)
@@ -499,6 +506,15 @@ def mark_scan_segments(walked: bool, segments: int = 1) -> None:
     batch's member)."""
     for by_walk, by_pool in _WALK_METERS:
         (by_walk if walked else by_pool).mark(segments)
+
+
+def mark_stacked_launch(segments: int) -> None:
+    """The walk launched ONE program over `segments` members of a
+    table's stack (`query/execution.py`): `walkStackedSegments` grows by
+    the members, `walkStackedPrograms` by 1."""
+    for by_segment, by_program in _STACK_METERS:
+        by_segment.mark(segments)
+        by_program.mark()
 
 
 # -- sum-lane counters -------------------------------------------------------

@@ -44,9 +44,11 @@ from pinot_tpu.common.metrics import CommonGauge, ServerMeter
 #: sharded executor's mesh-stacked lanes (incl. its num_docs vector);
 #: join: stage-2 probe structures built from exchanged dim blocks;
 #: window: stage-2 window operand columns; exchange: published stage-1
-#: DataTable bytes held by an ExchangeManager.
+#: DataTable bytes held by an ExchangeManager; lanestack: a table's
+#: segment lanes stacked for the scans' walk (segment/loader.py
+#: `SegmentStack`), a second copy of the scan lanes it stacks.
 KINDS = ("scan", "vdoc", "vector", "hll", "stack", "join", "window",
-         "exchange")
+         "exchange", "lanestack")
 
 
 class ResidencyLedger:

@@ -1761,6 +1761,151 @@ def run_segment_kernel_batched(padded: int, filter_spec, agg_specs,
 
 
 # ---------------------------------------------------------------------------
+# One table's segments in one program (the scans' walk, query/execution.py):
+# ONE segment's closure looped over members of a resident stack of the
+# table's lanes (segment/loader.py `SegmentStack`). A lane of S segments
+# is stacked on a new leading axis of `batch_bucket(S)` rows, a member
+# one index of it (so a table's programs change only where its count of
+# segments crosses a power of two); a rank-1 lane of a length that 128
+# divides is folded to [B, L / 128, 128] (`STACK_FOLD`), so a member is
+# a whole block of tiles whose bytes are one segment's lane, read where
+# it lies (a dynamic slice fused into its consumers, no copy), and the
+# TPU compiler sees an aligned index (for a TPU v5e, a flat [S * L]
+# lane read at row * L compiled a scan three to four times slower).
+# Members run one after another (`lax.fori_loop`, trip count `n_live`):
+# the HLO holds ONE body, so compile time and temp bytes are
+# one segment's, dead rows of the bucket are never computed, and the
+# device runs the members as it would run as many queued per-segment
+# programs.
+# ---------------------------------------------------------------------------
+
+#: the minor width a stacked rank-1 lane is folded to
+STACK_FOLD = 128
+
+
+def stacked_member_lane(stacked, row, shape: Tuple[int, ...]):
+    """Member `row`'s lane, of `shape`, out of a stacked lane."""
+    return jax.lax.dynamic_index_in_dim(stacked, row,
+                                        keepdims=False).reshape(shape)
+
+
+@functools.lru_cache(maxsize=256)
+def build_stacked_segment_kernel(padded: int, filter_spec, agg_specs,
+                                 group_spec, select_spec, s_bucket: int,
+                                 shapes: Tuple, slots: Tuple):
+    """Unjitted stacked kernel: `kernel(cols, packed, leaves, n_live)`.
+
+    `cols` are stacked lanes, `shapes` ((key, a member's shape), ...)
+    what each is to one segment; `packed` is ONE int32 [s_bucket, 2 + k]
+    array, a row a member: the member's row in the stack, its doc
+    count, then its k integer scalar params; `leaves` are the other
+    params, each [s_bucket, ...]; `slots` says, param by param in plan
+    order, the dtype an integer scalar is read back as from `packed`
+    (None: the next of `leaves`). Members 0 .. n_live - 1 each run
+    `build_segment_kernel`'s closure; every output gains a leading axis
+    of `s_bucket`."""
+    base = build_segment_kernel(padded, filter_spec, agg_specs, group_spec,
+                                select_spec)
+    member_shapes = dict(shapes)
+
+    def member(cols, packed, leaves, i):
+        row = packed[i, 0]
+        lanes = {k: stacked_member_lane(v, row, member_shapes[k])
+                 for k, v in cols.items()}
+        rest, col, params = iter(leaves), 2, []
+        for slot in slots:
+            if slot is None:
+                params.append(next(rest)[i])
+            else:
+                params.append(packed[i, col].astype(slot))
+                col += 1
+        return base(lanes, tuple(params), packed[i, 1])
+
+    def kernel(cols, packed, leaves, n_live):
+        shapes = jax.eval_shape(member, cols, packed, leaves, 0)
+        init = {k: jnp.zeros((s_bucket, *s.shape), s.dtype)
+                for k, s in shapes.items()}
+
+        def body(i, acc):
+            outs = member(cols, packed, leaves, i)
+            return {k: jax.lax.dynamic_update_index_in_dim(
+                acc[k], outs[k], i, 0) for k in acc}
+
+        return jax.lax.fori_loop(0, n_live, body, init)
+
+    return named_kernel(kernel,
+                        f"{scan_family(group_spec, select_spec)}_stacked")
+
+
+#: integer scalar params read back from the packed int32 array: every
+#: value of these dtypes is an int32 too
+PACKED_SCALAR_DTYPES = frozenset(
+    np.dtype(t) for t in (np.int8, np.int16, np.int32, np.uint8, np.uint16))
+
+
+def packed_scalar_dtype(p) -> Optional[str]:
+    """The dtype an integer scalar param `p` is packed as (its own), or
+    None where it is an operand of its own (an array, a float, a wider
+    integer)."""
+    if isinstance(p, np.integer) and p.dtype in PACKED_SCALAR_DTYPES:
+        return p.dtype.name
+    return None
+
+
+@functools.lru_cache(maxsize=64)
+def _live_count(n: int):
+    """`n_live` on the device, uploaded once a value."""
+    return jax.device_put(np.int32(n))
+
+
+def run_stacked_segment_kernel(padded: int, filter_spec, agg_specs,
+                               group_spec, select_spec, cols, shapes: Tuple,
+                               n_stack: int, members):
+    """ONE launch over `members`, [(row in the stack, doc count,
+    params)] of one spec over the stacked lanes `cols` of a stack of
+    `n_stack` segments, `batch_bucket(n_stack)` rows each (`shapes`:
+    each lane's shape to one segment):
+    the integer scalars of every member and its row and doc count are
+    ONE int32 upload, every other param leaf one upload of its
+    members' values; every output has a leading row a member (in
+    `members`' order) of `batch_bucket(n_stack)`, rows past the members
+    never computed."""
+    s_bucket = batch_bucket(n_stack)
+    slots = tuple(packed_scalar_dtype(p) for p in members[0][2])
+    packed = np.zeros((s_bucket, 2 + sum(s is not None for s in slots)),
+                      np.int32)
+    leaves = [[] for s in slots if s is None]
+    for i, (row, num_docs, params) in enumerate(members):
+        ints = [int(p) for p, s in zip(params, slots) if s is not None]
+        packed[i] = (row, num_docs, *ints)
+        for leaf, p in zip(leaves, (p for p, s in zip(params, slots)
+                                    if s is None)):
+            leaf.append(np.asarray(p))
+    stacked = []
+    for leaf in leaves:
+        arr = np.zeros((s_bucket, *leaf[0].shape), leaf[0].dtype)
+        arr[: len(leaf)] = leaf
+        stacked.append(jax.device_put(arr))
+    fn = get_stacked_segment_kernel(padded, filter_spec,
+                                    tuple(agg_specs or ()), group_spec,
+                                    select_spec, s_bucket, shapes, slots)
+    return fn(cols, jax.device_put(packed), tuple(stacked),
+              _live_count(len(members)))
+
+
+@functools.lru_cache(maxsize=256)
+def get_stacked_segment_kernel(padded: int, filter_spec, agg_specs,
+                               group_spec, select_spec, s_bucket: int,
+                               shapes: Tuple = (), slots: Tuple = ()):
+    """Compile (once per static signature) the stacked program: its key
+    is the spec, the bucket and the lanes' shapes, never how many
+    members a launch runs (`n_live`, a runtime operand)."""
+    return jax.jit(build_stacked_segment_kernel(
+        padded, filter_spec, agg_specs, group_spec, select_spec, s_bucket,
+        shapes, slots))
+
+
+# ---------------------------------------------------------------------------
 # Kernel contract registry (consumed by analysis/contracts.py --deep)
 #
 # Every kernel family the planner can emit is registered here as a
@@ -2005,7 +2150,35 @@ def extra_contract_cases():
     from pinot_tpu.ops import ivf_kernels  # lazy: avoids import cycle
     P = "P"
     i32, f32, bl = "int32", "float32", "bool"
+    stacked_group = (
+        (("d0", "ids", 0, 8), ("d1", "ids", 0, 8)), (8, 1), 64,
+        (("sum", "m0", "sv", ("psums", 2)),
+         ("sum", "v0", "sv", ("vlane",))), 1024)
     return [
+        # the walk's stacked program: a compacted group table over two
+        # members of a stack of two, its eq_id operand packed (int32)
+        # and the doc counts and rows beside it
+        ("stacked_group_compacted", build_stacked_segment_kernel,
+         (P, ("pred", "eq_id", "d0", "sv", None), (), stacked_group, None,
+          2, (("d0.ids", (P,)), ("d1.ids", (P,)), ("m0.parts", (2, P)),
+              ("v0.vlane", (P,))), ("int32",)),
+         ({"d0.ids": (i32, (2, "P/128", 128)),
+           "d1.ids": (i32, (2, "P/128", 128)),
+           "m0.parts": ("int8", (2, 2, P)),
+           "v0.vlane": (f32, (2, "P/128", 128))},
+          (i32, (2, 3)), (), (i32, ()))),
+        # ... and a Q1.x-shaped scan whose IN list is a stacked leaf
+        ("stacked_scan_agg", build_stacked_segment_kernel,
+         (P, ("and", (("pred", "in_ids", "d0", "sv", 4),
+                      ("pred", "range_ids", "d1", "sv", None))),
+          (("sum", "m0", "sv", ("parts", 2)), ("count", "*", "sv", None)),
+          None, None, 2,
+          (("d0.ids", (P,)), ("d1.ids", (P,)), ("m0.parts", (2, P))),
+          (None, "int32", "int32")),
+         ({"d0.ids": (i32, (2, "P/128", 128)),
+           "d1.ids": (i32, (2, "P/128", 128)),
+           "m0.parts": ("int8", (2, 2, P))},
+          (i32, (2, 4)), ((i32, (2, 4)),), (i32, ()))),
         ("window_rank", build_window_kernel, (P, 2, 0),
          ((i32, (P,)), ((i32, (P,)), (i32, (P,))), (), (i32, ()))),
         ("window_rank_sum", build_window_kernel, (P, 1, 2),

@@ -16,8 +16,9 @@ import numpy as np
 
 from pinot_tpu.analysis.runtime import debug_transfer_guard
 from pinot_tpu.common.metrics import ServerQueryPhase
-from pinot_tpu.obs.profiler import (mark_sum_lanes, obs_span,
-                                    profiled_device_get, sum_lane_attrs)
+from pinot_tpu.obs.profiler import (mark_stacked_launch, mark_sum_lanes,
+                                    obs_span, profiled_device_get,
+                                    sum_lane_attrs)
 from pinot_tpu.ops import kernels
 from pinot_tpu.query.blocks import ExecutionStats, IntermediateResultsBlock
 from pinot_tpu.segment.loader import ImmutableSegment
@@ -82,42 +83,130 @@ def _scalar_operands(plan, cols, extra_params):
             _device_scalar("int32", plan.segment.num_docs, device))
 
 
-def execute_segment_plan(plan) -> IntermediateResultsBlock:
-    """ONE plan run to its block: `execute_segment_plans` with one
-    ladder (a pull a rung); a plan that refuses while running raises."""
-    (blk,) = execute_segment_plans([plan])
+def execute_segment_plan(plan, stacks=None) -> IntermediateResultsBlock:
+    """ONE plan run to its block: its ladder walked alone (a pull a
+    rung); a plan that refuses while running raises. A segment a walk
+    has stacked (in `stacks`, the server's `segment/loader.py`
+    `LaneStacks`) runs the walk's programs over the stack, a launch of
+    one member (so a pool task or a batch's member meets no program
+    the walks have not compiled); any other over its own lanes."""
+    stack = None if stacks is None else stacks.holding(plan.segment)
+    with debug_transfer_guard():
+        (blk,) = _execute_segment_plans(
+            [plan], None,
+            {} if stack is None else {plan.segment.padded_docs: stack})
     if isinstance(blk, Exception):
         raise blk
     return blk
 
 
-def execute_segment_plans(plans, deadline: Optional[float] = None) -> list:
+def execute_segment_plans(plans, deadline: Optional[float] = None,
+                          stacks=None) -> list:
     """The plans of ONE query's scan-route segments, walked by this
     thread in phases (`query/plan.py` `walk_ladders`): every plan's
-    lanes gathered (a lane-cache look-up), every plan's next program
-    launched without waiting, ONE pull a rung over all of them, then
-    the exact host finishing a plan. The device has the query's
-    programs queued while the host is between a pull and the next
-    launches; a query stops for the host once a rung (one for a scan,
-    two or three for a group-by), not once a program.
+    lanes gathered (a look-up), every rung's programs launched without
+    waiting, ONE pull a rung over all of them, then the exact host
+    finishing a plan. The device has the query's programs queued while
+    the host is between a pull and the next launches; a query stops
+    for the host once a rung (one for a scan, two or three for a
+    group-by), not once a program.
+
+    Plans over members of their table's stack (`stacks`, the server's
+    `segment/loader.py` `LaneStacks`: the table's immutable segments of
+    one padded size) read the stacked lanes, and those whose next program has one spec
+    are ONE launch of one program looped over them
+    (`kernels.get_stacked_segment_kernel`); any other plan is a launch
+    of its segment's own program over its own lanes.
 
     -> a list aligned with `plans`: the plan's block; or the
     `UnsupportedOnDevice` / `GroupsLimitExceeded` it refused with while
     running (the caller's host twin answers it); or None where
     `deadline` (time.monotonic(), checked between gathers and between
     rungs) passed before the plan was done."""
+    found = {} if stacks is None else stacks.for_segments(
+        [p.segment for p in plans if p.fast_path_result is None])
     # PINOT_TPU_DEBUG_TRANSFERS=1 turns any implicit device→host pull in
     # the dispatch/finish path below into an error at the offending call
     # site (the explicit batched jax.device_get a rung still works)
     with debug_transfer_guard():
-        return _execute_segment_plans(plans, deadline)
+        return _execute_segment_plans(plans, deadline, found)
 
 
-def _plan_ladder(plan):
-    """The plan's ladder of device programs over its gathered lanes."""
+class StackedPlace:
+    """A ladder's place in its table's stack: the plan, its row and the
+    stacked lanes it reads. Ladders whose next programs share `key` are
+    one launch (`launch`)."""
+
+    __slots__ = ("stack", "row", "plan", "cols", "shapes")
+
+    def __init__(self, stack, row: int, plan, cols, shapes):
+        self.stack, self.row, self.plan = stack, row, plan
+        self.cols, self.shapes = cols, shapes
+
+    def key(self, program) -> Optional[tuple]:
+        """The launch's identity for `program` (a ladder's
+        `(agg_specs, group_spec, extra_params)`); None where a param is
+        no numpy value (its type could not be kept through a stack)."""
+        agg_specs, group_spec, extra = program
+        plan = self.plan
+        params = []
+        for p in (*plan.params, *extra):
+            if not isinstance(p, (np.ndarray, np.generic)):
+                return None
+            params.append((kernels.packed_scalar_dtype(p), p.dtype.str,
+                           p.shape))
+        return (id(self.stack), plan.filter_spec, tuple(agg_specs or ()),
+                group_spec, plan.select_spec, plan.needed_cols,
+                tuple(params))
+
+    @staticmethod
+    def launch(members) -> Dict[str, object]:
+        """ONE program over `members`, [(place, program)] of one key:
+        DEVICE outs, each with a leading row a member (in `members`'
+        order) of the stack's bucket."""
+        first, (agg_specs, group_spec, _extra) = members[0]
+        plan, stack = first.plan, first.stack
+        with obs_span(ServerQueryPhase.KERNEL_LAUNCH) as span:
+            if span is not None:
+                span["attrs"] = dict(sum_lane_attrs(
+                    group_spec[3] if group_spec else agg_specs,
+                    plan.segment), segments=len(members))
+            mark_stacked_launch(len(members))
+            return kernels.run_stacked_segment_kernel(
+                stack.padded, plan.filter_spec, agg_specs, group_spec,
+                plan.select_spec, first.cols, first.shapes, stack.size,
+                [(place.row, place.plan.segment.num_docs,
+                  (*place.plan.params, *program[2]))
+                 for place, program in members])
+
+
+def _stacked_place(plan, stack) -> Optional[StackedPlace]:
+    """`plan`'s place in `stack`, None where its segment is no member
+    or a lane it reads cannot be stacked."""
+    row = stack.row(plan.segment)
+    if row is None:
+        return None
+    with obs_span(ServerQueryPhase.OPERAND_GATHER):
+        cols, shapes = {}, []
+        for col, kind in plan.needed_cols:
+            lane = stack.lane(col, kind, row)
+            if lane is None:
+                return None
+            key = f"{col}.{kind}"
+            cols[key] = lane[0]
+            shapes.append((key, lane[1]))
+    return StackedPlace(stack, row, plan, cols, tuple(sorted(shapes)))
+
+
+def _plan_ladder(plan, stacks):
+    """The plan's ladder of device programs: over its place in its
+    stack (of `stacks`, by padded size) where it has one, else over its
+    own gathered lanes."""
     from pinot_tpu.query.plan import SegmentLadder
     segment = plan.segment
-    cols = gather_operands(plan)
+    stack = stacks.get(segment.padded_docs)
+    place = _stacked_place(plan, stack) if stack is not None else None
+    cols = gather_operands(plan) if place is None else None
 
     def run(agg_specs, group_spec, extra_params=()):
         # returns DEVICE outs; the walk batches the device→host pull
@@ -125,6 +214,9 @@ def _plan_ladder(plan):
         # never per-scalar). kernelLaunch ends at the asynchronous
         # return: argument handling, jit cache look-up, any compile,
         # enqueue
+        nonlocal cols
+        if cols is None:        # a stacked ladder launched alone
+            cols = gather_operands(plan)
         with obs_span(ServerQueryPhase.KERNEL_LAUNCH) as span:
             if span is not None:
                 span["attrs"] = sum_lane_attrs(
@@ -135,10 +227,11 @@ def _plan_ladder(plan):
                 group_spec, plan.select_spec, cols, params, num_docs)
 
     return SegmentLadder(run, plan.agg_specs, plan.group_spec,
-                         segment.padded_docs, segment.num_docs, segment)
+                         segment.padded_docs, segment.num_docs, segment,
+                         place)
 
 
-def _execute_segment_plans(plans, deadline) -> list:
+def _execute_segment_plans(plans, deadline, stacks) -> list:
     from pinot_tpu.query.plan import (GroupsLimitExceeded,
                                       UnsupportedOnDevice, walk_ladders)
     t0 = time.perf_counter()
@@ -150,7 +243,7 @@ def _execute_segment_plans(plans, deadline) -> list:
         if deadline is not None and time.monotonic() >= deadline:
             break
         try:
-            ladders[i] = _plan_ladder(plan)
+            ladders[i] = _plan_ladder(plan, stacks)
         except (GroupsLimitExceeded, UnsupportedOnDevice) as exc:
             results[i] = exc
     walk_ladders(list(ladders.values()), deadline, keep_refusals=True)
@@ -209,7 +302,8 @@ def _finish_selection_and_stats(plan, outs, blk, elapsed_ms: float) -> None:
         time_used_ms=elapsed_ms)
 
 
-def execute_segment_plans_batched(plans) -> List[IntermediateResultsBlock]:
+def execute_segment_plans_batched(plans, stacks=None
+                                  ) -> List[IntermediateResultsBlock]:
     """One device dispatch serves N plans over ONE segment.
 
     Callers guarantee every plan shares a batch_signature (equal
@@ -219,10 +313,11 @@ def execute_segment_plans_batched(plans) -> List[IntermediateResultsBlock]:
     the outputs are sliced back per member and fed through the same
     host finishers the sequential path uses — which is why batched and
     sequential results agree bit-for-bit on every path the coalescer
-    admits (pinned by the contract tier).
+    admits (pinned by the contract tier). A plan alone runs as
+    `execute_segment_plan` runs it (over its stack in `stacks`).
     """
     if len(plans) == 1:
-        return [execute_segment_plan(plans[0])]
+        return [execute_segment_plan(plans[0], stacks)]
     lead = plans[0]
     segment = lead.segment
     t0 = time.perf_counter()

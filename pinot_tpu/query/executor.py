@@ -43,7 +43,7 @@ from pinot_tpu.query.plan import (GroupsLimitExceeded, InstancePlanMaker,
                                   UnsupportedOnDevice, batch_signature,
                                   upsert_mask_active)
 from pinot_tpu.query.pruner import SegmentPrunerService
-from pinot_tpu.segment.loader import ImmutableSegment
+from pinot_tpu.segment.loader import ImmutableSegment, LaneStacks
 
 
 #: ONE logical segment's work: (blocks, extra_parts, extra_matched) — a
@@ -125,6 +125,9 @@ class ServerQueryExecutor:
         # parallel/sharded.py ShardedQueryExecutor (a mesh), or None:
         # multi-segment queries try its combine first (`execute`)
         self.sharded = sharded
+        # this executor's stacks of its tables' lanes, which the scans'
+        # walk and a segment run alone read (segment/loader.py)
+        self.lane_stacks = LaneStacks()
         # residency gates (server/residency_manager.py): device_gate
         # routes host/disk-tier segments through host_exec instead of
         # the device kernels; mutable_gate blocks frozen-snapshot
@@ -356,8 +359,8 @@ class ServerQueryExecutor:
         # `walkOffCpu`), traced or not
         with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION,
                       segments=len(plans)), obs_profiler.walk_clock():
-            blocks = execution.execute_segment_plans(list(plans.values()),
-                                                     deadline)
+            blocks = execution.execute_segment_plans(
+                list(plans.values()), deadline, self.lane_stacks)
         for i, blk in zip(plans, blocks):
             if isinstance(blk, Exception):
                 solo(i, self._host_segment)
@@ -398,7 +401,7 @@ class ServerQueryExecutor:
         if path == "scan":
             try:
                 with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
-                    blk = payload.execute()
+                    blk = payload.execute(self.lane_stacks)
                 obs_profiler.count_path("scan")
                 obs_profiler.mark_scan_segments(False)
                 return blk
@@ -484,7 +487,7 @@ class ServerQueryExecutor:
         for group in groups.values():
             with obs_span(ServerQueryPhase.QUERY_PLAN_EXECUTION):
                 blocks = execution.execute_segment_plans_batched(
-                    [plan for _, plan in group])
+                    [plan for _, plan in group], self.lane_stacks)
             obs_profiler.count_path("scan", len(group))
             obs_profiler.mark_scan_segments(False, len(group))
             for (m, _), blk in zip(group, blocks):

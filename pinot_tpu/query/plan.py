@@ -466,9 +466,11 @@ class SegmentPlan:
     select_display: Optional[int] = None   # display cols (rest: order-only)
     fast_path_result: Optional[IntermediateResultsBlock] = None
 
-    def execute(self) -> IntermediateResultsBlock:
+    def execute(self, stacks=None) -> IntermediateResultsBlock:
+        """The plan run alone (`execution.execute_segment_plan`; over
+        its stack where `stacks`, a server's `LaneStacks`, holds one)."""
         from pinot_tpu.query import execution
-        return execution.execute_segment_plan(self)
+        return execution.execute_segment_plan(self, stacks)
 
 
 def batch_signature(plan: SegmentPlan) -> Optional[tuple]:
@@ -1276,14 +1278,14 @@ LADDER_PHASES = (("scan", None),
 
 class SegmentLadder:
     """One segment's place on its ladder of device programs: what to
-    launch next (`launch`), given what was pulled (`accept`). The walk
-    that drives it (`walk_ladders`) owns the pulls, so one ladder, a
-    sequential walk and a query's eight segments are this one piece of
-    logic driven with 1 or N ladders.
+    launch next (`program`), given what was pulled (`accept`). The walk
+    that drives it (`walk_ladders`) owns the launches and the pulls, so
+    one ladder, a sequential walk and a query's eight segments are this
+    one piece of logic driven with 1 or N ladders.
 
-    `run(agg_specs, group_spec, extra_params)` dispatches the kernel and
-    returns DEVICE outs (extra_params are appended after the filter
-    operands). `segment` is what the plan was made against (its
+    `run(agg_specs, group_spec, extra_params)`, a `program()`,
+    dispatches the kernel over the segment alone and returns DEVICE
+    outs (extra_params are appended after the filter operands). `segment` is what the plan was made against (its
     `int_part_info` names the table's part lanes on a traced
     `groupTable`). A plan without a `group_spec` is the one rung
     "scan": its `agg_specs` program. Filtered dictionary-keyed group-bys
@@ -1311,6 +1313,11 @@ class SegmentLadder:
     Non-eligible plans go straight to the compacted table with the kmax
     escalation rung.
 
+    `place` (a `query/execution.py` `StackedPlace`, or None) is the
+    ladder's row in its table's stack: the walk launches the next
+    programs of ladders whose places share a key as ONE program
+    (`place.launch`), and a ladder without one by `run`.
+
     `phase` is the rung to launch next, None once the ladder is done
     (or `refused`, see `walk_ladders`); then `outs` holds the HOST outs
     to finish from and `finish_spec` the group spec to decode them with
@@ -1322,14 +1329,15 @@ class SegmentLadder:
     group-by sums): what a segment took, not what it costs."""
 
     __slots__ = ("run", "group_spec", "padded", "total_docs", "segment",
-                 "phase", "outs", "finish_spec", "refused", "hists", "runs",
-                 "_specs", "_kspec", "_fspec", "_extra", "_scout",
-                 "_matched")
+                 "place", "phase", "outs", "finish_spec", "refused",
+                 "hists", "runs", "_specs", "_kspec", "_fspec", "_extra",
+                 "_scout", "_matched")
 
     def __init__(self, run, agg_specs, group_spec, padded: int,
-                 total_docs: int, segment):
+                 total_docs: int, segment, place=None):
         self.run, self.group_spec, self.padded = run, group_spec, padded
         self.total_docs, self.segment = total_docs, segment
+        self.place = place
         self.outs = self.finish_spec = self.refused = None
         self.hists = self.runs = 0
         self._fspec, self._extra, self._scout = None, (), None
@@ -1341,14 +1349,15 @@ class SegmentLadder:
         self.phase = "table" if self._specs is None else "scout"
         self._kspec = group_spec
 
-    def launch(self):
-        """This rung's program, queued: DEVICE outs."""
+    def program(self) -> tuple:
+        """This rung's program: `(agg_specs, group_spec, extra_params)`,
+        what `run` takes."""
         if self.phase == "table":
-            return self.run((), self._kspec, self._extra)
-        return self.run(self._specs, None, ())
+            return (), self._kspec, self._extra
+        return self._specs, None, ()
 
     def accept(self, outs) -> None:
-        """The HOST outs of what `launch` queued: on to the next rung
+        """The HOST outs of the rung's program: on to the next rung
         (the per-bound int() reads are host numpy, not device pulls)."""
         if self.phase == "scan":
             self._done(outs, None)
@@ -1431,6 +1440,12 @@ def walk_ladders(ladders, deadline: Optional[float] = None,
     histogram rung's specs, the table's plan, the kmax check), one a
     pull.
 
+    Ladders with a stacked `place` whose next programs share its key
+    are ONE launch (`place.launch`), one entry of the pull an output
+    with a row a ladder, split on the host; any other ladder is a
+    launch of its own (`run`). A kmax re-run is such a group of the
+    ladders that overflowed, with their new table spec.
+
     `deadline` (time.monotonic()) is checked between rungs: ladders not
     done by then are left where they stand (`phase` not None). With
     `keep_refusals` a ladder whose launch refuses with
@@ -1447,26 +1462,41 @@ def walk_ladders(ladders, deadline: Optional[float] = None,
             return
         with obs_span(span_name, segments=len(rung)) as span:
             while at:
-                # ONE flat dict a pull, "<ladder>/<output>": what
+                # ONE flat dict a pull, "<launch>/<output>": what
                 # `jax.device_get` is handed stays a dict of arrays
-                launched, pulled = {}, {}
-                for i, ladder in enumerate(at):
+                launched, pulled, rows = {}, {}, {}
+                for n, group in enumerate(_launch_groups(at, refused)):
+                    stacked = group[0][2] is not None
                     try:
-                        launched.update((f"{i}/{name}", out) for name, out
-                                        in ladder.launch().items())
-                        pulled[i] = {}
+                        if stacked:
+                            outs = at[group[0][0]].place.launch(
+                                [(at[i].place, program)
+                                 for i, program, _key in group])
+                        else:
+                            (i, program, _key), = group
+                            outs = at[i].run(*program)
+                        launched.update((f"{n}/{name}", out)
+                                        for name, out in outs.items())
+                        rows[n] = ([i for i, _p, _k in group], stacked)
+                        pulled.update((i, {}) for i in rows[n][0])
                     except refused as exc:
-                        ladder.phase, ladder.refused = None, exc
+                        for i, _p, _k in group:
+                            at[i].phase, at[i].refused = None, exc
                 if not pulled:
                     break
-                outs = profiled_device_get(launched, programs=len(pulled))
+                outs = profiled_device_get(launched, programs=len(rows))
                 with obs_span(ServerQueryPhase.OUTPUT_RELEASE):
                     del launched
                 with obs_span(ServerQueryPhase.RUNG_ACCEPT,
                               segments=len(pulled)):
                     for key, out in outs.items():
-                        i, _, name = key.partition("/")
-                        pulled[int(i)][name] = out
+                        n, _, name = key.partition("/")
+                        group, stacked = rows[int(n)]
+                        if stacked:
+                            for r, i in enumerate(group):
+                                pulled[i][name] = out[r, ...]
+                        else:
+                            pulled[group[0]][name] = out
                     for i, ladder_outs in pulled.items():
                         at[i].accept(ladder_outs)
                 at = [l for l in at if l.phase == phase]
@@ -1479,6 +1509,26 @@ def walk_ladders(ladders, deadline: Optional[float] = None,
                    for k in ("layout", "g", "runs")},
                 "scouted": rung[0].scouted,
                 **sum_lane_attrs(rung[0]._kspec[3], rung[0].segment)}
+
+
+def _launch_groups(ladders, refused=()) -> list:
+    """`ladders`' next launches: [[(index, program, key)]], one list a
+    launch. Ladders whose stacked places give one key are one launch;
+    a ladder without a place, or whose program has no key, is one of
+    its own (key None). A ladder whose params refuse (`refused`) while
+    its key is read leaves the walk as a refused launch does."""
+    groups = {}
+    for i, ladder in enumerate(ladders):
+        program = ladder.program()
+        try:
+            key = ladder.place.key(program) if ladder.place is not None \
+                else None
+        except refused as exc:
+            ladder.phase, ladder.refused = None, exc
+            continue
+        groups.setdefault(("alone", i) if key is None else key,
+                          []).append((i, program, key))
+    return list(groups.values())
 
 
 def drive_group_execution(run, group_spec, padded: int, total_docs: int,

@@ -10,10 +10,11 @@ sees static shapes (SURVEY.md §7 — padded power-of-two blocks instead of mmap
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import weakref
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -334,22 +335,25 @@ class DataSource:
         out[: len(ids)] = ids
         return out
 
-    def device_lane(self, kind: str):
+    def device_lane(self, kind: str, mark: bool = True):
         """The device lane of `kind` (a row of `LANES`), from the lane
         cache: a hit is one look-up and does no host work. Only a miss
         builds the padded host operand of `kind` (needed once, for the
-        upload; never kept)."""
+        upload; never kept). `mark` False leaves the lane cache's meters
+        to the caller (a stack, which marks a walk's own look-ups)."""
         key, ledger_kind = self.LANES[kind]
         lane = self._dev.get(key)
         if lane is not None:
-            residency.mark_lane_cache(hit=True)
+            if mark:
+                residency.mark_lane_cache(hit=True)
             return lane
         # built OUTSIDE _lane_lock: raw_values, int_part_info and the
         # HLL tables take that same non-reentrant lock. Published under
         # it, first published wins: a losing racer drops its operand
         # and neither uploads nor registers a duplicate
         host_array = self.host_operand(kind)
-        residency.mark_lane_cache(hit=False)
+        if mark:
+            residency.mark_lane_cache(hit=False)
         seg = self._segment
         with self._lane_lock:
             if self._dev_finalizer is None:
@@ -369,6 +373,10 @@ class DataSource:
                     if seg is not None else "",
                     kind=ledger_kind)
             return self._dev[key]
+
+    def lane_resident(self, kind: str) -> bool:
+        """Whether the device lane of `kind` is in the lane cache."""
+        return self.LANES[kind][0] in self._dev
 
     def release_device(self) -> None:
         """Drop every device lane and its ledger entries (segment drop/
@@ -466,6 +474,10 @@ class ImmutableSegment:
         self.valid_doc_ids = None
         self._valid_dev = None   # (bitmap version, padded device lane)
         self._valid_finalizer = None         # set on first vdoc upload
+        # the stacks of lanes this segment is a member of (a server's
+        # `LaneStacks`): dropped with its device lanes
+        self.lane_stacks: "weakref.WeakSet[SegmentStack]" = \
+            weakref.WeakSet()
 
     @property
     def segment_name(self) -> str:
@@ -585,6 +597,10 @@ class ImmutableSegment:
         device→host demotion step. Re-access re-uploads lazily."""
         self._valid_dev = None  # tpulint: disable=concurrency -- the residency manager drains query pins before releasing; worst case a racing reader re-uploads one lane
         residency.LEDGER.release(f"seg:{id(self)}:vdoc")
+        with _MEMBERSHIP_LOCK:
+            stacks = list(self.lane_stacks)
+        for stack in stacks:
+            stack.drop()
         for ds in self._data_sources.values():
             ds.release_device()
 
@@ -609,6 +625,279 @@ class ImmutableSegment:
     def destroy(self) -> None:
         self._valid_dev = None  # tpulint: disable=concurrency -- destroy runs after the refcounted release of the last query; worst case a racing reader re-uploads one lane
         self.release_device_lanes()
+
+
+# ---------------------------------------------------------------------------
+# A table's lanes stacked on the device, for the scans' walk
+# ---------------------------------------------------------------------------
+
+#: the share of the device's memory a stack leaves free: its programs'
+#: temporaries (a member's dense group table takes up to 1 GB) and the
+#: lanes later queries upload need room beside it
+STACK_HEADROOM = 0.25
+
+
+def device_fits(nbytes: int) -> bool:
+    """Whether `nbytes` more leave `STACK_HEADROOM` of the device's
+    memory free, by the backend's own statistics (True where it keeps
+    none: the CPU backend)."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return not limit or \
+        stats.get("bytes_in_use", 0) + nbytes <= limit * (1 - STACK_HEADROOM)
+
+
+def stack_lanes(lanes, bucket: int):
+    """One device array of the segments' `lanes` (one kind of one
+    column, a lane a segment), or None where they differ in dtype or
+    shape: stacked on a new leading axis of `bucket` rows (the rows past
+    the lanes repeat the first, and no member reads them), a rank-1 lane
+    of a length `ops/kernels.py` `STACK_FOLD` divides folded to
+    [bucket, L / 128, 128], as `stacked_member_lane` reads a member
+    back. One program a (bucket, shape, dtype), whatever the count of
+    lanes; no host work."""
+    first = lanes[0]
+    if any(l.dtype != first.dtype or l.shape != first.shape
+           for l in lanes[1:]):
+        return None
+    return _stacker()(tuple(lanes) + (first,) * (bucket - len(lanes)))
+
+
+@functools.lru_cache(maxsize=1)
+def _stacker():
+    """The jitted stack of `stack_lanes` (made on first use: the loader
+    imports without touching the device)."""
+    import jax
+    import jax.numpy as jnp
+    from pinot_tpu.ops.kernels import STACK_FOLD
+
+    def stack(lanes):
+        stacked = jnp.stack(lanes)
+        if lanes[0].ndim == 1 and lanes[0].shape[0] % STACK_FOLD == 0:
+            stacked = stacked.reshape(len(lanes), -1, STACK_FOLD)
+        return stacked
+    return jax.jit(stack)
+
+
+class SegmentStack:
+    """The immutable segments of one table that share `padded` rows,
+    in a fixed order (a member's `row`), and their lanes stacked on the
+    device: ONE array a (column, kind) of `bucket` rows (`batch_bucket`
+    of the members: the programs that read it change only where the
+    count of members crosses a power of two), built on first use from
+    the members' own device lanes (`stack_lanes`) once `admit` lets its
+    bytes take the device, and counted by the residency ledger under
+    owners of its own (kind `lanestack`). A second copy of what it
+    stacks: the members' lanes stay, for every path that runs a segment
+    without the stack.
+
+    A stack never changes: a member added or replaced makes the next
+    stack (`LaneStacks.for_segments`), and a member's lanes released
+    (demoted, destroyed) drop it; a walk that holds it keeps what it
+    read. Members are held weakly: one dropped without a release (never
+    destroyed) keeps its row, which no walk reads, until the stack's
+    next change, so the programs a table runs do not follow when the
+    collector runs."""
+
+    def __init__(self, table: str, padded: int, members, admit):
+        from pinot_tpu.ops.kernels import batch_bucket
+        self.table, self.padded = table, padded
+        self.size = len(members)
+        self.bucket = batch_bucket(self.size)
+        self._admit = admit
+        self._members = tuple(weakref.ref(s) for s in members)
+        self._rows = {id(s): i for i, s in enumerate(members)}
+        self._lanes: Dict[tuple, object] = {}
+        self._lock = threading.Lock()
+        self.dropped = False
+        self._owner = f"lanestack:{id(self)}:"
+        with _MEMBERSHIP_LOCK:
+            for s in members:
+                s.lane_stacks.add(self)
+
+    def row(self, segment) -> Optional[int]:
+        """`segment`'s row in the stack, None if it is no member."""
+        i = self._rows.get(id(segment))
+        if i is None or self._members[i]() is not segment:
+            return None
+        return i
+
+    def members(self) -> list:
+        return [ref() for ref in self._members]
+
+    def lane(self, column: str, kind: str, row: int):
+        """(the stacked lane of (`column`, `kind`), a member's shape),
+        or None where the members' lanes cannot be one array or the
+        device cannot hold it. The look-up of member `row` marks the
+        lane cache's meters once, as its own lane's would: a miss where
+        building the stack built that lane."""
+        key = (column, kind)
+        lane = self._lanes.get(key, _UNBUILT)
+        if lane is _UNBUILT:
+            with self._lock:
+                if key not in self._lanes:
+                    built = self._build(column, kind)
+                    if self.dropped:
+                        return built and built[:2]
+                    if built is not None:
+                        residency.LEDGER.register(
+                            f"{self._owner}{column}.{kind}",
+                            table=self.table, segment="", kind="lanestack",
+                            nbytes=int(built[0].nbytes))
+                    self._lanes[key] = built
+                lane = self._lanes[key]
+        if lane is not None:
+            built_rows = lane[2]
+            residency.mark_lane_cache(hit=row not in built_rows)
+            built_rows.discard(row)
+            return lane[:2]
+        return None
+
+    def _build(self, column: str, kind: str):
+        """(stacked lane, a member's shape, the rows whose own lane had
+        to be built for it), or None: a lane some member cannot give,
+        bytes `admit` refuses, or an allocation the device refuses. A
+        member dropped without a release gives a row no walk reads."""
+        lanes, built = [], set()
+        try:
+            for row, segment in enumerate(self.members()):
+                if segment is None:
+                    lanes.append(None)
+                    continue
+                ds = segment.data_source(column)
+                if not ds.lane_resident(kind):
+                    built.add(row)
+                lanes.append(ds.device_lane(kind, mark=False))
+        except Exception:  # noqa: BLE001 — a lane some member cannot
+            return None    # give is no stack: its ladders run alone
+        alive = next((l for l in lanes if l is not None), None)
+        if alive is None or not self._admit(self.bucket * int(alive.nbytes)):
+            return None
+        try:
+            stacked = stack_lanes([alive if l is None else l for l in lanes],
+                                  self.bucket)
+        except Exception:  # noqa: BLE001 — the device refused the bytes
+            return None    # (RESOURCE_EXHAUSTED): the ladders run alone
+        return None if stacked is None else \
+            (stacked, tuple(alive.shape), built)
+
+    def drop(self) -> None:
+        """Drop the stacked lanes and their ledger entries; a walk that
+        holds the stack builds what it still asks for unledgered and
+        unkept."""
+        with self._lock:
+            self.dropped = True
+            self._lanes.clear()
+        residency.LEDGER.release_prefix(self._owner)
+
+
+_UNBUILT = object()
+#: guards the segments' `lane_stacks` sets: a stack joins them on one
+#: thread while a release reads them on another
+_MEMBERSHIP_LOCK = threading.Lock()
+
+
+def stackable(segment) -> bool:
+    """Whether `segment` may be a member of a stack: an immutable
+    segment without an upsert liveness lane."""
+    return isinstance(segment, ImmutableSegment) and \
+        not getattr(segment, "is_mutable", False) and \
+        segment.valid_doc_ids is None
+
+
+def _columns_of(segment) -> tuple:
+    """What a segment's columns are, for the stack it may share: name,
+    type, dictionary or not, single or multi value (kept on the
+    segment: an immutable segment's columns never change)."""
+    key = segment.__dict__.get("_stack_columns")
+    if key is None:
+        key = segment.__dict__["_stack_columns"] = tuple(sorted(
+            (name, str(cm.data_type), cm.has_dictionary, cm.single_value)
+            for name, cm in segment.metadata.columns.items()))
+    return key
+
+
+class LaneStacks:
+    """One server's stacks of its tables' lanes, held by its query
+    executor (`query/executor.py`): per (table, padded rows, columns)
+    the `SegmentStack` of the immutable segments without a `vdoc` lane
+    that its walks have met. Servers of one process keep theirs apart,
+    so no server replaces another's members.
+
+    A stack's lanes are admitted before they are built: within `fits`
+    (the server's device budget, `residency_manager.admits_derived`,
+    where it has one) and leaving the device `STACK_HEADROOM` free
+    (`device_fits`); a refused lane is no stack, and its ladders run
+    over their own lanes. `evict_all` (the residency manager's pressure
+    hook) drops every stack: the cheapest HBM to reclaim."""
+
+    def __init__(self, fits: Optional[Callable[[int], bool]] = None):
+        self.fits = fits
+        self._stacks: Dict[tuple, SegmentStack] = {}
+        self._lock = threading.Lock()
+
+    def admit(self, nbytes: int) -> bool:
+        return (self.fits is None or self.fits(nbytes)) and \
+            device_fits(nbytes)
+
+    def for_segments(self, segments) -> Dict[int, SegmentStack]:
+        """{padded rows: stack} for `segments` (a query's, of one
+        table): the table's stack of each padded size (and set of
+        columns), holding every stackable one of `segments` of that
+        size. A segment a stack lacks joins it, one of the same name
+        replaces its predecessor, and either makes a new stack (the old
+        one's lanes dropped). A size that fewer than two segments share
+        has none."""
+        by_size: Dict[tuple, list] = {}
+        for s in segments:
+            if stackable(s):
+                by_size.setdefault((s.padded_docs, _columns_of(s)),
+                                   []).append(s)
+        out, dropped = {}, []
+        with self._lock:
+            for (padded, columns), joining in by_size.items():
+                key = (joining[0].metadata.table_name or "", padded, columns)
+                stack = self._stacks.get(key)
+                if stack is None or stack.dropped or \
+                        any(stack.row(s) is None for s in joining):
+                    members = [] if stack is None or stack.dropped else \
+                        [m for m in stack.members() if m is not None]
+                    for s in joining:
+                        if not any(m is s for m in members):
+                            members = [m for m in members if m.segment_name
+                                       != s.segment_name] + [s]
+                    if stack is not None:
+                        dropped.append(stack)
+                    stack = self._stacks[key] = SegmentStack(
+                        key[0], padded, members, self.admit)
+                if stack.size >= 2:
+                    out[padded] = stack
+        for stack in dropped:
+            stack.drop()
+        return out
+
+    def holding(self, segment) -> Optional[SegmentStack]:
+        """The stack that holds `segment` already (a walk made it), or
+        None: a look-up that makes and joins nothing, for a segment run
+        alone (a pool task, a batch's member), which then runs the
+        walk's programs."""
+        if not stackable(segment):
+            return None
+        stack = self._stacks.get((segment.metadata.table_name or "",
+                                  segment.padded_docs, _columns_of(segment)))
+        if stack is None or stack.dropped or stack.size < 2 or \
+                stack.row(segment) is None:
+            return None
+        return stack
+
+    def evict_all(self) -> None:
+        """Drop every stack; the next walk makes and admits them anew."""
+        with self._lock:
+            stacks = list(self._stacks.values())
+            self._stacks.clear()
+        for stack in stacks:
+            stack.drop()
 
 
 class ImmutableSegmentLoader:

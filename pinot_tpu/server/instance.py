@@ -107,6 +107,10 @@ class ServerInstance:
                 self.executor.sharded.evict_segment)
             self.residency.add_pressure_hook(
                 self.executor.sharded.evict_all)
+        # the walk's stacks of lanes are copies too (segment/loader.py
+        # `LaneStacks`), dropped before any segment is demoted
+        self.residency.add_pressure_hook(
+            self.executor.executor.lane_stacks.evict_all)
         self.residency.add_pressure_hook(self._release_mutable_snapshots)
         # admission control + CRC-exact result cache (hits bypass the
         # admission queue — the degradation valve under overload)

@@ -72,6 +72,7 @@ class InstanceQueryExecutor:
         self.residency = residency if residency is not None \
             else residency_manager.MANAGER
         self.executor.device_gate = self.residency.device_allowed
+        self.executor.lane_stacks.fits = self.residency.admits_derived
         self.executor.mutable_gate = self.residency.mutable_device_allowed
         self.default_timeout_ms = default_timeout_ms
         self.metrics = metrics or MetricsRegistry("server")

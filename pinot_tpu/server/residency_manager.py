@@ -287,6 +287,13 @@ class ResidencyManager:
         return LEDGER.total_bytes() < self.budget_bytes
 
     # -- admission / eviction ----------------------------------------------
+    def admits_derived(self, nbytes: int) -> bool:
+        """May a derived copy of resident lanes (a table's stack, which
+        rebuilds from them) take `nbytes` of HBM? Within the budget as
+        it stands: nothing is evicted for it."""
+        return self.budget_bytes is None or \
+            LEDGER.total_bytes() + nbytes <= self.budget_bytes
+
     def _admit_device(self, entry: _Entry) -> bool:
         """May `entry` occupy HBM? Judged against the LEDGER total (the
         ground truth that includes stacks/join/window/exchange bytes),

@@ -19,10 +19,13 @@ walks' blocks compared), and then answers the deck
        the pool walk of scan routes that `query/executor.py` had up to
        PR 37;
   (ii) `walk`: ONE task of the same pool, the caller waiting on it,
-       which runs `execution.execute_segment_plans`: every segment's
-       program of a rung launched before the rung's one pull (what
-       `_walk_scans` does since PR 38; a checkout without it skips the
-       variant);
+       which walks the ladders a segment program each: every segment's
+       program of a rung launched before the rung's one pull (a
+       checkout without `execute_segment_plans` skips the variant);
+  (iii) `stacked`: the same task over the table's stack of lanes, ONE
+       program a rung and spec over the segments (what `_walk_scans`
+       does where `segment/loader.py` `LaneStacks` exists; a checkout
+       without it skips the variant);
 
 each with 1 and with 4 caller threads (the server's runner threads: 4
 clients keep 4 queries in flight), all callers sharing the ONE pool.
@@ -120,22 +123,42 @@ def shape_plans(segments, shapes, names, seed: int, queries: int):
     return [query for turn in zip(*by_shape) for query in turn]
 
 
+def answer_gap(a, b) -> float:
+    """The widest relative gap between two answers' floats; keys,
+    integers and structure must be equal (else an AssertionError)."""
+    if isinstance(a, float) or isinstance(b, float):
+        return abs(a - b) / max(abs(a), abs(b), 1e-300) if a != b else 0.0
+    if isinstance(a, dict):
+        assert a.keys() == b.keys(), "group keys differ"
+        return max((answer_gap(a[k], b[k]) for k in a), default=0.0)
+    if isinstance(a, (list, tuple)):
+        assert len(a) == len(b), "lengths differ"
+        return max((answer_gap(x, y) for x, y in zip(a, b)), default=0.0)
+    assert a == b, (a, b)
+    return 0.0
+
+
 def pooled(pool, plans):
     """A segment a task on the pool, each the solo path."""
     futures = [pool.submit(plan.execute) for plan in plans]
     return [f.result() for f in futures]
 
 
-def walked(pool, plans):
+def walked(pool, plans, stacks=None):
     """One task of the pool: every launch of a rung before its pull,
     under the walk's clock (`walkHostCpu` / `walkOffCpu`), as
-    `_walk_scans` runs it."""
+    `_walk_scans` runs it: over the table's stack of lanes in `stacks`
+    (a server's `segment/loader.py` `LaneStacks`), a program a rung and
+    spec, or (None) a segment program a ladder over each segment's own
+    lanes."""
     from pinot_tpu.obs.profiler import walk_clock
     from pinot_tpu.query import execution
 
     def walk():
         with walk_clock():
-            return execution.execute_segment_plans(plans)
+            if stacks is None:
+                return execution.execute_segment_plans(plans)
+            return execution.execute_segment_plans(plans, stacks=stacks)
 
     return pool.submit(walk).result()
 
@@ -184,22 +207,30 @@ class Counted:
         from pinot_tpu.ops import kernels
         self._jax, self._kernels = jax, kernels
         self._get, self._run = jax.device_get, kernels.run_segment_kernel
+        self._stacked = getattr(kernels, "run_stacked_segment_kernel", None)
         programs, pulls = itertools.count(), itertools.count()
         self._counters = programs, pulls
 
-        def run(*a, **k):
-            next(programs)
-            return self._run(*a, **k)
+        def counted(launch):
+            def run(*a, **k):
+                next(programs)
+                return launch(*a, **k)
+            return run
 
         def get(x):
             next(pulls)
             return self._get(x)
 
-        kernels.run_segment_kernel, jax.device_get = run, get
+        kernels.run_segment_kernel = counted(self._run)
+        jax.device_get = get
+        if self._stacked is not None:
+            kernels.run_stacked_segment_kernel = counted(self._stacked)
         return self
 
     def __exit__(self, *exc):
         self._kernels.run_segment_kernel = self._run
+        if self._stacked is not None:
+            self._kernels.run_stacked_segment_kernel = self._stacked
         self._jax.device_get = self._get
         self.programs, self.pulls = (next(c) for c in self._counters)
 
@@ -535,10 +566,10 @@ def main(argv=None) -> int:
         dirs = build.build_all(config, args.seed, work, REPO, args.workers)
         import jax
         from pinot_tpu.query import execution
-        from pinot_tpu.segment.loader import ImmutableSegmentLoader
+        from pinot_tpu.segment import loader
         from pinot_tpu.utils.device import configure_compile_cache
         configure_compile_cache()
-        segments = [ImmutableSegmentLoader.load(d) for d in dirs]
+        segments = [loader.ImmutableSegmentLoader.load(d) for d in dirs]
         device = jax.devices()[0]
         print(f"{config['rows']} rows, {len(segments)} segments: built "
               f"and loaded in {time.perf_counter() - t0:.1f} s; device "
@@ -550,17 +581,27 @@ def main(argv=None) -> int:
         walks = {"pool": lambda plans: pooled(pool, plans)}
         if hasattr(execution, "execute_segment_plans"):
             walks["walk"] = lambda plans: walked(pool, plans)
+        if hasattr(loader, "LaneStacks"):
+            stacks = loader.LaneStacks()
+            walks["stacked"] = lambda plans: walked(pool, plans, stacks)
         # every lane uploaded, every program compiled, and the walks'
-        # answers the same
+        # answers the same: integers and keys to the digit, float sums to
+        # their rounding (a stacked program's members may round a block
+        # sum otherwise than their segment's own program on a TPU)
         t0 = time.perf_counter()
+        float_gap = 0.0
         for _name, plans in deck:
             answers = [[(b.group_map, b.agg_intermediates) for b in w(plans)]
                        for w in walks.values()]
-            assert all(a == answers[0] for a in answers), _name
+            for a in answers[1:]:
+                float_gap = max(float_gap, answer_gap(a, answers[0]))
+            assert float_gap < 1e-6, (_name, float_gap)
         print(f"{len(deck)} queries answered once by each walk in "
-              f"{time.perf_counter() - t0:.1f} s", file=sys.stderr)
+              f"{time.perf_counter() - t0:.1f} s; widest float gap "
+              f"{float_gap:.3g}", file=sys.stderr)
         result = {"config": args.config, "rows": config["rows"],
                   "segments": len(segments), "seed": args.seed,
+                  "float_gap": float_gap,
                   "host_cpus": os.cpu_count(),
                   "device": f"{device.platform} {device.device_kind}",
                   "shapes": list(SHAPES), "walks": {}}

@@ -122,3 +122,23 @@ def build_shared_segments(base: str, n_segs: int = 8, n: int = 2048,
                   else sum((c[k] for c in all_cols), []))
               for k in all_cols[0]}
     return segs, merged
+
+
+def spy_dead_rows(monkeypatch) -> list:
+    """Spy on the walk's stacked launches (`ops/kernels.py`
+    `run_stacked_segment_kernel`): the list returned gains, a launch,
+    the bytes of its outputs' rows that no member ran. A stacked
+    program's every output has a row for each of its stack's bucket, so
+    what a walk pulls is its members' rows and these."""
+    from pinot_tpu.ops import kernels
+    real, dead = kernels.run_stacked_segment_kernel, []
+
+    def spy(*args):
+        outs = real(*args)
+        n_stack, members = args[-2], args[-1]
+        bucket = kernels.batch_bucket(n_stack)
+        dead.append(sum(int(v.nbytes) // bucket * (bucket - len(members))
+                        for v in outs.values()))
+        return outs
+    monkeypatch.setattr(kernels, "run_stacked_segment_kernel", spy)
+    return dead

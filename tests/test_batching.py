@@ -22,7 +22,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
-from fixtures import build_segment
+from fixtures import build_segment, spy_dead_rows
 
 from pinot_tpu.common.datatable import DataTable, RESULT_CACHE_HIT_KEY
 from pinot_tpu.common.metrics import ServerMeter, ServerTimer
@@ -585,6 +585,7 @@ def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
         def _walks(self, seg):
             return False
 
+    dead = spy_dead_rows(monkeypatch)
     pool = ThreadPoolExecutor(4)
     try:
         seen, inters, paths, dispatches = [], [], [], []
@@ -594,20 +595,29 @@ def test_parallel_walk_equals_the_sequential_one_on_mixed_routes(
             ex = cls(**kw)
             ex.device_gate = gate
             profile = QueryProfile("t")
+            del dead[:]
             with obs_profiler.active(profile, None):
                 blk = ex.execute(request, segments)
             seen.append(_seen(request, blk))
             inters.append(blk.agg_intermediates)
             paths.append(profile.paths)
-            dispatches.append((profile.dispatches, profile.transfer_bytes))
+            dispatches.append((profile.dispatches, profile.transfer_bytes,
+                               len(dead), sum(dead)))
     finally:
         pool.shutdown(wait=True)
     assert not seen[0][3], seen[0][3]
     assert seen[0] == seen[1] == seen[2]
     assert inters[0] == inters[1] == inters[2]   # the same float sums
     assert paths[0] == paths[1] == paths[2] == want_paths
-    # the same programs and the same bytes, however they were pulled
-    assert dispatches[0] == dispatches[1] == dispatches[2]
+    # the same programs and the same bytes on the calling thread or a
+    # pool's; a segment a task runs its own program where the walk runs
+    # one over a stack's members of a spec, and pulls the same bytes
+    # but for the stacked outputs' rows no member ran
+    assert dispatches[0] == dispatches[1]
+    (programs, nbytes, stacked, dead_bytes), \
+        (alone_programs, alone_bytes, none, _) = dispatches[1:]
+    assert none == 0 and 0 < stacked <= programs <= alone_programs
+    assert nbytes == alone_bytes + dead_bytes
     # eight blocks (the consuming segment gives two), in `selected`'s
     # order
     assert len(combined) == 3 and combined[0] == combined[1] == combined[2]

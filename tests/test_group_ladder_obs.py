@@ -6,7 +6,9 @@ scout, where it pays a histogram rung, and a group table with its kmax
 re-runs; each phase is ONE span for all the query's segments
 (`groupScout`, `groupHist`, `groupTable`) around their launches and the
 phase's one pull, and every ladder marks the ladder's meters once
-(`obs/profiler.py` `mark_group_ladder`). Held here to what ran: SSB's
+(`obs/profiler.py` `mark_group_ladder`). The segments of a stack whose
+next programs share a spec are one launch of one program: a phase's
+launches are programs, its ladders' meters still segments. Held here to what ran: SSB's
 13 shapes over small dbgen segments without cubes, built as the
 benchmark builds them, on the CPU. Nothing here is a measurement.
 """
@@ -34,7 +36,9 @@ DISPATCH_METERS = (ServerMeter.GROUP_SCOUT_DISPATCHES,
                    ServerMeter.GROUP_HIST_DISPATCHES,
                    ServerMeter.GROUP_TABLE_DISPATCHES)
 WALK_METERS = (ServerMeter.SCAN_WALK_SEGMENTS, ServerMeter.SCAN_POOL_SEGMENTS,
-               ServerMeter.DEVICE_PROGRAMS, ServerMeter.DEVICE_PULLS)
+               ServerMeter.DEVICE_PROGRAMS, ServerMeter.DEVICE_PULLS,
+               ServerMeter.WALK_STACKED_SEGMENTS,
+               ServerMeter.WALK_STACKED_PROGRAMS)
 LADDER_METERS = (ServerMeter.GROUP_SEGMENTS, *DISPATCH_METERS,
                  ServerMeter.GROUP_ESCALATIONS, ServerMeter.GROUP_EMPTY,
                  *ServerMeter.GROUP_TABLES.values(), *WALK_METERS)
@@ -146,15 +150,20 @@ def test_a_traced_group_by_carries_the_ladders_spans(traced_runs, shape):
         inside = [c["name"] for c in phase["children"]]
         n = phase["attrs"]["segments"]
         runs = max(phase["attrs"].get("runs", [1]))
-        # every launch of the phase, then its one pull (and the drop of
-        # the device outputs); a re-run is a launch and a pull more
-        assert inside[:n + 2] == [ServerQueryPhase.KERNEL_LAUNCH] * n + \
+        # every launch of the phase (a program over the segments of one
+        # spec), then its one pull (and the drop of the device outputs);
+        # a re-run is a launch and a pull more
+        k = inside.index(ServerQueryPhase.KERNEL_DISPATCH)
+        assert 1 <= k <= n
+        assert inside[:k + 2] == [ServerQueryPhase.KERNEL_LAUNCH] * k + \
             [ServerQueryPhase.KERNEL_DISPATCH,
              ServerQueryPhase.OUTPUT_RELEASE]
+        assert sum(c["attrs"]["segments"]
+                   for c in phase["children"][:k]) == n
         assert inside.count(ServerQueryPhase.KERNEL_DISPATCH) == runs
         launches += inside.count(ServerQueryPhase.KERNEL_LAUNCH)
-        pull = phase["children"][n]
-        assert pull["attrs"]["programs"] == n and pull["attrs"]["bytes"] > 0
+        pull = phase["children"][k]
+        assert pull["attrs"]["programs"] == k and pull["attrs"]["bytes"] > 0
     assert launches == profile["kernelDispatches"]
     # no launch of a group-by outside its phase's span
     assert ServerQueryPhase.KERNEL_LAUNCH not in \
@@ -219,8 +228,11 @@ def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
     for name in GROUP_BYS:
         _answer, profile, spans, grown = traced_runs[name]
         assert profile["paths"] == {"scan": SEGMENTS}
-        # every dispatch of a group-by is a phase of the ladder
+        # every rung a segment took is a phase of the ladder, run as a
+        # member of one of the query's stacked programs
         assert sum(grown[m] for m in DISPATCH_METERS) == \
+            grown[ServerMeter.WALK_STACKED_SEGMENTS]
+        assert grown[ServerMeter.WALK_STACKED_PROGRAMS] == \
             profile["kernelDispatches"]
         # one table at most a segment, in one layout
         tables = sum(grown[m] for m in ServerMeter.GROUP_TABLES.values())
@@ -229,7 +241,7 @@ def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
             tables + grown[ServerMeter.GROUP_ESCALATIONS]
         assert tables == sum(s["attrs"]["segments"] for s in spans
                              if s["name"] == ServerQueryPhase.GROUP_TABLE)
-        dispatches += profile["kernelDispatches"]
+        dispatches += grown[ServerMeter.WALK_STACKED_SEGMENTS]
         for m in LADDER_METERS:
             total[m] += grown[m]
     assert total[ServerMeter.GROUP_SEGMENTS] == len(GROUP_BYS) * SEGMENTS
@@ -241,7 +253,8 @@ def test_meters_add_up_to_what_the_profiles_counted(traced_runs):
 @pytest.mark.parametrize("shape", ["q1.1", "q1.2", "q1.3"])
 def test_a_scan_without_group_by_marks_nothing(traced_runs, shape):
     _answer, profile, spans, grown = traced_runs[shape]
-    assert profile["kernelDispatches"] == SEGMENTS
+    assert profile["kernelDispatches"] == 1
+    assert grown[ServerMeter.WALK_STACKED_SEGMENTS] == SEGMENTS
     assert not any(n for m, n in grown.items() if m not in WALK_METERS)
     assert not [s for s in spans if s["name"] in GROUP_SPANS]
 
@@ -261,7 +274,8 @@ def test_a_filter_that_matches_nothing_marks_empty_and_runs_no_table(ladder):
     assert grown[ServerMeter.GROUP_SCOUT_DISPATCHES] == SEGMENTS
     assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == 0
     assert not any(grown[m] for m in ServerMeter.GROUP_TABLES.values())
-    assert profile["kernelDispatches"] == SEGMENTS
+    assert profile["kernelDispatches"] == 1
+    assert grown[ServerMeter.WALK_STACKED_SEGMENTS] == SEGMENTS
     assert not [s for s in spans
                 if s["name"] == ServerQueryPhase.GROUP_TABLE]
 
@@ -295,7 +309,8 @@ def test_an_unscouted_group_by_is_a_table_that_says_so(ladder):
     assert grown[ServerMeter.GROUP_SEGMENTS] == SEGMENTS
     assert grown[ServerMeter.GROUP_SCOUT_DISPATCHES] == 0
     assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == SEGMENTS == \
-        profile["kernelDispatches"]
+        grown[ServerMeter.WALK_STACKED_SEGMENTS]
+    assert profile["kernelDispatches"] == 1
     assert grown[ServerMeter.GROUP_TABLES["dense"]] == SEGMENTS
 
 
@@ -371,55 +386,65 @@ def test_a_segment_that_matches_nothing_runs_no_table_beside_siblings_that_do(
     assert grown[ServerMeter.GROUP_EMPTY] == empty
     assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == tables
     assert sum(grown[m] for m in ServerMeter.GROUP_TABLES.values()) == tables
-    # two phases, two pulls; the table's phase holds the siblings alone
+    # two phases, two pulls; the table's phase holds the siblings alone,
+    # each phase's segments members of its stacked programs
     assert grown[ServerMeter.DEVICE_PULLS] == 2
-    assert grown[ServerMeter.DEVICE_PROGRAMS] == EIGHT + tables == \
+    assert grown[ServerMeter.WALK_STACKED_SEGMENTS] == EIGHT + tables
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == \
+        grown[ServerMeter.WALK_STACKED_PROGRAMS] == \
         profile["kernelDispatches"]
     _plan, (scout, table) = _phases(spans)
     assert scout["attrs"] == {"segments": EIGHT}
     assert table["attrs"]["segments"] == tables == \
         len(table["attrs"]["layout"])
-    assert [c["name"] for c in table["children"]].count(
-        ServerQueryPhase.KERNEL_LAUNCH) == tables
+    assert sum(c["attrs"]["segments"] for c in table["children"]
+               if c["name"] == ServerQueryPhase.KERNEL_LAUNCH) == tables
     assert profile["paths"] == {"scan": EIGHT}
 
 
 def test_a_forced_re_run_of_one_segment_of_eight_re_runs_it_alone(
         ladder8, monkeypatch):
-    """The third segment's first table reports overflow (forced here:
+    """The third member's first table reports overflow (forced here:
     about one table in 8,000 does): the table's phase launches that
     segment's next rung alone and pulls once more; the answer is what
     the walk without the re-run gives."""
-    import jax.numpy as jnp
     from pinot_tpu.ops import kernels
     shape = ladder8.shapes["q2.2"]
     pql = shape.pql(shape.spec["ssb"])
     want, _profile, _spans, plain = ladder8.run(pql, traced=False)
     assert plain[ServerMeter.GROUP_TABLES["compacted"]] == EIGHT
-    real, tables = kernels.run_segment_kernel, []
+    real, tables = kernels.run_stacked_segment_kernel, []
 
     def overflow_once(padded, filt, aggs, group_spec, *rest):
         outs = real(padded, filt, aggs, group_spec, *rest)
         if group_spec is not None:
-            tables.append(group_spec[4])
-            if len(tables) == 3:
-                outs = dict(outs, **{"group.overflow": jnp.int32(1)})
+            tables.append((group_spec[4], len(rest[-1])))
+            if len(tables) == 1:
+                outs = dict(outs, **{"group.overflow":
+                                     outs["group.overflow"].at[2].set(1)})
         return outs
-    monkeypatch.setattr(kernels, "run_segment_kernel", overflow_once)
+    monkeypatch.setattr(kernels, "run_stacked_segment_kernel",
+                        overflow_once)
     answer, profile, spans, grown = ladder8.run(pql, traced=True)
     assert answer == want
-    # eight tables, then the third segment's at four times the slots
-    assert len(tables) == EIGHT + 1 and tables[-1] >= 4 * tables[2]
+    # eight tables in the phase's programs, then the one segment's at
+    # four times the slots, a program of its own
+    (kmax, _n), *first, (kmax2, alone) = tables
+    assert _n + sum(n for _k, n in first) == EIGHT
+    assert alone == 1 and kmax2 >= 4 * kmax
+    k = 1 + len(first)
     assert grown[ServerMeter.GROUP_ESCALATIONS] == 1
     assert grown[ServerMeter.GROUP_TABLE_DISPATCHES] == EIGHT + 1
     assert grown[ServerMeter.GROUP_SEGMENTS] == EIGHT
     assert grown[ServerMeter.DEVICE_PULLS] == 3
-    assert grown[ServerMeter.DEVICE_PROGRAMS] == 2 * EIGHT + 1 == \
+    assert grown[ServerMeter.DEVICE_PROGRAMS] == \
+        grown[ServerMeter.WALK_STACKED_PROGRAMS] == \
         profile["kernelDispatches"]
+    assert grown[ServerMeter.WALK_STACKED_SEGMENTS] == 2 * EIGHT + 1
     _plan, (_scout, table) = _phases(spans)
-    assert table["attrs"]["runs"] == [1, 1, 2, 1, 1, 1, 1, 1]
+    assert sorted(table["attrs"]["runs"]) == [1] * (EIGHT - 1) + [2]
     assert [c["name"] for c in table["children"]] == \
-        [ServerQueryPhase.KERNEL_LAUNCH] * EIGHT + \
+        [ServerQueryPhase.KERNEL_LAUNCH] * k + \
         [ServerQueryPhase.KERNEL_DISPATCH, ServerQueryPhase.OUTPUT_RELEASE,
          ServerQueryPhase.RUNG_ACCEPT,
          ServerQueryPhase.KERNEL_LAUNCH, ServerQueryPhase.KERNEL_DISPATCH,
@@ -427,7 +452,7 @@ def test_a_forced_re_run_of_one_segment_of_eight_re_runs_it_alone(
     assert [c["attrs"]["segments"] for c in table["children"]
             if c["name"] == ServerQueryPhase.RUNG_ACCEPT] == [EIGHT, 1]
     assert [c["attrs"]["programs"] for c in table["children"]
-            if c["name"] == ServerQueryPhase.KERNEL_DISPATCH] == [EIGHT, 1]
+            if c["name"] == ServerQueryPhase.KERNEL_DISPATCH] == [k, 1]
 
 
 @pytest.mark.parametrize("shape", ["q1.2", "q2.2", "q3.1", "q4.1"])
@@ -436,24 +461,35 @@ def test_eight_segments_in_phases_answer_as_eight_walked_alone(
     """The walk over a query's eight ladders against each ladder
     walked alone (`_walks` refused: a segment a piece, a pull a
     program, what a pool task did before PR 38): the same answer, the
-    same programs, the same bytes, the same marks on the ladder's
-    meters; only the pulls differ."""
+    same marks on the ladder's meters, a segment program alone for each
+    member of the walk's stacked programs, and the same bytes but for
+    the stacked outputs' rows no member ran; the pulls differ."""
+    from fixtures import spy_dead_rows
     from pinot_tpu.query.executor import ServerQueryExecutor
     s = ladder8.shapes[shape]
     pql = s.pql(s.spec["ssb"])
+    dead = spy_dead_rows(monkeypatch)
     answer, profile, _spans, grown = ladder8.run(pql, traced=False)
     monkeypatch.setattr(ServerQueryExecutor, "_walks",
                         lambda self, seg: False)
+    n_launches = len(dead)
     alone, alone_profile, _s, alone_grown = ladder8.run(pql, traced=False)
     assert answer == alone
-    for key in ("kernelDispatches", "deviceTransferBytes", "paths",
-                "docsScanned", "segmentsMatched"):
+    for key in ("paths", "docsScanned", "segmentsMatched"):
         assert profile[key] == alone_profile[key], key
     assert {m: n for m, n in grown.items() if m not in WALK_METERS} == \
         {m: n for m, n in alone_grown.items() if m not in WALK_METERS}
+    assert len(dead) == n_launches == profile["kernelDispatches"]
+    assert profile["deviceTransferBytes"] == \
+        alone_profile["deviceTransferBytes"] + sum(dead)
     assert grown[ServerMeter.DEVICE_PROGRAMS] == \
+        grown[ServerMeter.WALK_STACKED_PROGRAMS] == \
+        profile["kernelDispatches"]
+    assert grown[ServerMeter.WALK_STACKED_SEGMENTS] == \
         alone_grown[ServerMeter.DEVICE_PROGRAMS] == \
-        alone_grown[ServerMeter.DEVICE_PULLS]
+        alone_grown[ServerMeter.DEVICE_PULLS] == \
+        alone_profile["kernelDispatches"]
+    assert alone_grown[ServerMeter.WALK_STACKED_SEGMENTS] == 0
     assert grown[ServerMeter.DEVICE_PULLS] <= 3
     assert (grown[ServerMeter.SCAN_WALK_SEGMENTS],
             alone_grown[ServerMeter.SCAN_POOL_SEGMENTS]) == (EIGHT, EIGHT)

@@ -187,6 +187,7 @@ BOOT_FAMILIES = {
                    "sumLanesHist"), (), ()),
     "scan_walk": (("scanWalkSegments", "scanPoolSegments",
                    "devicePrograms", "devicePulls"), (), ()),
+    "walk_stack": (("walkStackedSegments", "walkStackedPrograms"), (), ()),
     "walk_clock": ((), ("walkHostCpu", "walkOffCpu"), ()),
     # the collector runs from boot on: its timer exists, its count
     # is whatever the process has collected since

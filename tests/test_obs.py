@@ -615,19 +615,21 @@ def test_start_us_across_the_broker_server_merge(obs_cluster):
                    if c["name"] == "segmentQueueWait"]
         assert wait["attrs"] == {"segments": 2}
     # the walk's spans tile queryPlanExecution on the aggregation path:
-    # a gather and a launch a segment, ONE pull, a finish a segment, the
-    # drop of what the ladders pulled
+    # a gather a segment, ONE launch over the server's two segments (a
+    # stack), ONE pull, a finish a segment, the drop of what the ladders
+    # pulled
     for node, _p in nodes:
         if node["name"] != "queryPlanExecution":
             continue
         kids = [c["name"] for c in node["children"]]
-        assert kids == ["operandGather"] * 2 + ["kernelLaunch"] * 2 + \
+        assert kids == ["operandGather"] * 2 + ["kernelLaunch"] + \
             ["kernelDispatch", "outputRelease", "rungAccept"] + \
             ["resultFinish"] * 2 + ["outputRelease"]
         starts = [c["startUs"] for c in node["children"]]
         assert starts == sorted(starts)
-        assert node["children"][4]["attrs"]["bytes"] > 0
-        assert node["children"][4]["attrs"]["programs"] == 2
+        assert node["children"][2]["attrs"]["segments"] == 2
+        assert node["children"][3]["attrs"]["bytes"] > 0
+        assert node["children"][3]["attrs"]["programs"] == 1
     # ... and cover its wall time
     def walks_tiled(tree):
         for node, _p in _walk(tree):
@@ -641,7 +643,7 @@ def test_start_us_across_the_broker_server_merge(obs_cluster):
                            ).trace_tree, walks_tiled)
     # traced responses say which path answered, beside the tree
     assert resp.profile_info["paths"] == {"scan": 4}
-    assert resp.to_json()["profileInfo"]["kernelDispatches"] == 4
+    assert resp.to_json()["profileInfo"]["kernelDispatches"] == 2
     plain = obs_cluster.query("SELECT SUM(runs) FROM baseballStats "
                               "WHERE runs > 11")
     assert plain.profile_info is None
@@ -678,8 +680,12 @@ def test_a_group_by_walks_phases_are_tiled_with_rung_accept(obs_cluster):
             for phase in phases:
                 n = phase["attrs"]["segments"]
                 kids = [c["name"] for c in phase["children"]]
-                assert kids == ["kernelLaunch"] * n + [
+                # a launch a spec over the stack's members
+                k = kids.count("kernelLaunch")
+                assert 1 <= k <= n and kids == ["kernelLaunch"] * k + [
                     "kernelDispatch", "outputRelease", "rungAccept"]
+                assert sum(c["attrs"]["segments"] for c in
+                           phase["children"][:k]) == n
                 assert phase["children"][-1]["attrs"] == {"segments": n}
                 _assert_tiled(phase, boundary_ms=0.05)
 
@@ -841,12 +847,14 @@ def test_star_tree_execute_span_says_hit_or_miss(star_tree_segments, batch):
     assert sorted(s["attrs"].get("segment", "") for s in st) == \
         ["", "st_0", "st_1"]
     names = [s["name"] for s in spans]
-    for name in ("operandGather", "kernelLaunch", "resultFinish"):
+    for name in ("operandGather", "resultFinish"):
         assert names.count(name) == 2, (name, names)
-    # the solo walk launches both segments before its one pull; the
-    # batched walk runs a segment after the other; a walk drops its
-    # rung's outputs, then its ladders
+    # the solo walk launches both segments as one program over their
+    # stack before its one pull; the batched walk runs a segment after
+    # the other, a program each; a walk drops its rung's outputs, then
+    # its ladders
     walks = 2 if batch else 1
+    assert names.count("kernelLaunch") == walks, names
     for name in ("segmentQueueWait", "kernelDispatch"):
         assert names.count(name) == walks, (name, names)
     assert names.count("outputRelease") == 2 * walks, names

@@ -229,19 +229,28 @@ def test_device_fault_surfaces_and_never_reaches_the_host_twin(
     from pinot_tpu.ops import kernels
     from pinot_tpu.query import host_exec
     server, _ = server_with_data
-    real, scouts = kernels.run_segment_kernel, []
+    reals, scouts = {}, []
 
-    def device_fault(padded, filt, aggs, group_spec, *rest):
-        if walk == "ladder_table" and group_spec is None:
-            scouts.append(aggs)
-            return real(padded, filt, aggs, group_spec, *rest)
-        raise RuntimeError("RESOURCE_EXHAUSTED: injected device fault")
+    def faulty(launch):
+        def device_fault(padded, filt, aggs, group_spec, *rest):
+            if walk == "ladder_table" and group_spec is None:
+                # a stacked launch's last argument is its members
+                scouts.extend([aggs] * (len(rest[-1]) if launch ==
+                                        "run_stacked_segment_kernel"
+                                        else 1))
+                return reals[launch](padded, filt, aggs, group_spec,
+                                     *rest)
+            raise RuntimeError("RESOURCE_EXHAUSTED: injected device fault")
+        return device_fault
 
     def host_twin(*a, **k):
         raise AssertionError("a device fault fell through to host_exec")
 
-    # every launch goes through it
-    monkeypatch.setattr(kernels, "run_segment_kernel", device_fault)
+    # every launch goes through one of them: a segment's own program,
+    # or one over the members of the table's stack
+    for launch in ("run_segment_kernel", "run_stacked_segment_kernel"):
+        reals[launch] = getattr(kernels, launch)
+        monkeypatch.setattr(kernels, launch, faulty(launch))
     monkeypatch.setattr(host_exec, "execute_host", host_twin)
     dt = _query_server(server, pql)
     assert any("RESOURCE_EXHAUSTED" in e for e in dt.exceptions), \
@@ -268,7 +277,7 @@ def test_a_deadline_between_phases_truncates_and_waits_for_nothing(
                           name=f"dl_{i}")[0] for i in range(3)]
     request = compile_pql(_LADDER_PQL)
     real_get, real_run = plan_mod.profiled_device_get, \
-        kernels.run_segment_kernel
+        kernels.run_stacked_segment_kernel
     tables = []
 
     def slow_pull(x, programs=1):
@@ -276,14 +285,15 @@ def test_a_deadline_between_phases_truncates_and_waits_for_nothing(
         return real_get(x, programs)
 
     def spy(padded, filt, aggs, group_spec, *rest):
+        # the two device segments are one stack: a table a member
         if group_spec is not None:
-            tables.append(group_spec)
+            tables.extend([group_spec] * len(rest[-1]))
         return real_run(padded, filt, aggs, group_spec, *rest)
     pool = concurrent.futures.ThreadPoolExecutor(4) if pooled else None
     try:
         ex = ServerQueryExecutor(segment_executor=pool)
         ex.device_gate = lambda seg: seg is not segs[1]
-        monkeypatch.setattr(kernels, "run_segment_kernel", spy)
+        monkeypatch.setattr(kernels, "run_stacked_segment_kernel", spy)
         ex.execute(request, segs)               # programs compiled
         assert len(tables) == 2
         monkeypatch.setattr(plan_mod, "profiled_device_get", slow_pull)
